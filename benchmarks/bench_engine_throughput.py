@@ -90,6 +90,7 @@ import numpy as np
 from benchmarks.common import row
 from repro.configs import tiny_config
 from repro.core.hardware_model import V5E_EDGE
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import generate
 from repro.models.api import build_model
 from repro.serving.engine import Engine, Request, derive_policy
@@ -591,6 +592,7 @@ def main():
     # parse_known_args: benchmarks/run.py invokes main() with its own tag
     # arguments still on sys.argv
     args, _ = ap.parse_known_args()
+    enable_compile_cache()
 
     cfg = tiny_config(ARCH)
     model = build_model(cfg)

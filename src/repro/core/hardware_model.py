@@ -58,6 +58,24 @@ V5E_2POD = Hardware("v5e-2pod512", chips=512,
 HARDWARES: Dict[str, Hardware] = {h.name: h for h in
                                   (V5E_EDGE, V5E_POD, V5E_2POD)}
 
+# The one place a physical device is priced: ``device_kind`` as JAX reports
+# it -> the per-chip HARDWARES entry. Peaks for TPU v5e from the Google Cloud
+# "TPU v5e" documentation (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s).
+DEVICE_KINDS: Dict[str, str] = {"TPU v5 lite": V5E_EDGE.name}
+
+
+def hardware_for_device(device) -> Hardware:
+    """The HARDWARES entry for a JAX device. A kind missing from
+    DEVICE_KINDS is an error: pricing an unchecked device with another
+    chip's peaks would make every roofline number wrong."""
+    kind = device.device_kind
+    if kind not in DEVICE_KINDS:
+        raise ValueError(
+            f"no hardware model for device kind {kind!r} "
+            f"(platform {device.platform}); known kinds: "
+            f"{sorted(DEVICE_KINDS)}")
+    return HARDWARES[DEVICE_KINDS[kind]]
+
 
 def mxu_pad(dim, tile: int = 128):
     """Effective dim after MXU tile padding — why the NAS searcher learns to

@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handle layout (rank-3 activations, padding to block multiples), backend
-dispatch (interpret=True off-TPU so CPU tests execute the kernel body), and
-the weight-quantization caching used by the serving path.
+dispatch (compiled on TPU; interpret=True on the CPU so tests execute the
+kernel body), and the weight-quantization caching used by the serving path.
 """
 from __future__ import annotations
 
@@ -20,7 +20,13 @@ F32 = jnp.float32
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Pallas kernels compile for the TPU and run in interpret mode on the
+    CPU (tests only); any other backend has no lowering for them."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise NotImplementedError(
+            f"Pallas TPU kernels cannot run on backend {backend!r}")
+    return backend == "cpu"
 
 
 def _pad_to(x, m, axis):
@@ -107,7 +113,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, cap=0.0,
 def _paged_mode(mode: str) -> str:
     """Resolve the paged-attention dispatch once for all four entry points:
     "auto" lowers to the Pallas page-walk kernel on TPU and the pure-JAX
-    block walk elsewhere. The choice is backend-global and shape-free, so
+    block walk on the CPU. The choice is backend-global and shape-free, so
     the same dispatch works inside shard_map-partitioned programs — the
     sharded engine (serving/engine/sharded.py) traces these walks per shard
     with a local kv-head slice of the pool."""
@@ -122,10 +128,10 @@ def paged_attention(q, pool_k, pool_v, page_table, positions, *,
                     window=0, cap=0.0, mode: str = "auto") -> jax.Array:
     """Paged-attention decode: q (B,H,hd) against the page pool.
 
-    mode: "auto" -> Pallas kernel on TPU, pure-JAX block walk elsewhere;
-    "pallas" forces the kernel (interpret mode off-TPU — slow, tests only);
-    "ref" forces the block walk. Both walk pages and never materialize the
-    dense chronological KV view."""
+    mode: "auto" -> Pallas kernel on TPU, pure-JAX block walk on the CPU;
+    "pallas" forces the kernel (interpret mode on the CPU — slow, tests
+    only); "ref" forces the block walk. Both walk pages and never
+    materialize the dense chronological KV view."""
     if _paged_mode(mode) == "ref":
         return ref.paged_attention_ref(q, pool_k, pool_v, page_table,
                                        positions, window=window, cap=cap)
@@ -140,7 +146,7 @@ def paged_attention_quant(q, pool_k, k_scale, pool_v, v_scale, page_table,
     """Paged-attention decode over a quantized KV page pool.
 
     pool_k/v are int8 (int4 packed along head_dim — bitwidth is inferred
-    from the stored minor-dim size) with (P, page, K) fp32 scales. Same
+    from the stored minor-dim size) with (P, K, page) fp32 scales. Same
     dispatch contract as paged_attention; every path dequantizes block-by-
     block inside the walk and never materializes a dense fp KV view."""
     if _paged_mode(mode) == "ref":

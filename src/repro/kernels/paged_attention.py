@@ -1,37 +1,41 @@
-"""Pallas TPU kernel: paged-attention decode (serving decode hot-spot).
+"""Pallas TPU kernel: paged attention over the serving page pool.
 
-One query token per sequence attends to its KV history stored in a paged
-pool — the kernel walks ``page_table[b]`` block-by-block with an online
-softmax (flash-style running max/sum, like kernels/flash_attention.py),
-fusing the page gather, the causal/local-window mask, and the attention
+One kernel serves decode and chunked prefill, over bf16 and quantized
+pools. Each (sequence, kv-head) program walks ``page_table[b]``
+block-by-block with an online softmax (flash-style running max/sum),
+fusing the page gather, the causal/local-window mask and the attention
 itself, so the dense chronological ``(B, n_blocks*page, K, hd)`` KV view is
-never materialized in HBM.
+never materialized in HBM. Decode is the one-query-per-sequence case of
+the chunked-prefill walk (``Sq == 1``).
 
-Layout: the pool keeps its serving layout ``(num_pages, page, K, hd)``;
+Layout: the pool is kv-head-major within a page, ``(num_pages, K, page,
+hd)``, so one (page, hd) tile of one kv head is a block whose last two dims
+are whole array dims — the tiling the TPU compiler requires. Quantized
+pools carry ``(num_pages, K, page)`` fp32 scale tiles; a scale block is one
+page's whole ``(K, page)`` tile, and the kernel picks its kv head's row.
 ``page_table``/``positions`` ride in as scalar-prefetch operands
-(``PrefetchScalarGridSpec``) so the kv BlockSpec index map can resolve
-logical block ``i`` of sequence ``b`` to physical page ``page_table[b, i]``
-before the DMA is issued. Grid is ``(B, K, n_blocks)`` — the block axis is
-innermost, so the fp32 (m, l, acc) VMEM scratch carries across a sequence's
-page walk and the output tile is written once on the final block.
+(``PrefetchScalarGridSpec``) so the BlockSpec index maps resolve logical
+block ``i`` of sequence ``b`` to physical page ``page_table[b, i]`` before
+the DMA is issued. The grid is ``(B, K, n_blocks)`` — the block axis is
+innermost, so the fp32 (m, l, acc) VMEM scratch carries across a
+sequence's page walk and the output tile is written once on the final
+block.
 
-Blocks a sequence does not need — past ``positions[b]`` or, for local
+Blocks a sequence does not need — past its last query or, for local
 layers, wholly below the window — are skipped: the index map clamps their
 page id onto an already-resident page (no new copy is pipelined in) and
 ``pl.when`` predication skips the FLOPs. That makes local-window walks
-O(window), not O(T) — the roofline win the admission policy already
-assumes.
+O(window), not O(T).
 
-Forward-only by design (decode). Validated against the dense oracle in
-tests/test_kernels.py (interpret mode); the pure-JAX block-walk twin used
-as the CPU fallback lives in kernels/ref.py::paged_attention_ref.
+Quantized pools (serving/kvquant) arrive int8 (int4 packed along head_dim)
+and are dequantized inside the block loop: the per-token scales multiply
+the score columns (K) and the probability columns (V), so the only fp KV
+ever built is one (page, hd) tile in VMEM.
 
-``paged_attention_quant_fwd`` is the fused-dequant variant for the HAQ
-KV-quantized page pool (serving/kvquant): pages arrive int8 (int4 packed
-two-per-byte along head_dim) with per-page-slot per-head fp32 scale tiles
-that ride the same scalar-prefetched page-table walk, and dequantization
-happens inside the online-softmax block loop — one (page, hd) fp tile in
-VMEM at a time, never a dense fp KV view in HBM.
+Forward-only by design. Validated against the dense oracles in
+tests/test_kernels.py and tests/test_kvquant.py (interpret mode) and
+compiled for a described TPU v5e in tests/test_tpu_compile.py; the
+pure-JAX block-walk twins live in kernels/ref.py.
 """
 from __future__ import annotations
 
@@ -49,414 +53,202 @@ NEG = -1e30
 LANES = 128  # scratch minor dim, aligned to the VPU lane width
 
 
-def _block_update(q, k, v, pos, i, *, page, window, cap,
-                  m_ref, l_ref, acc_ref):
-    """Masked online-softmax accumulation of one fp32 (page, hd) KV block —
-    the math the fp/fused-dequant decode kernels AND their chunked-prefill
-    variants must agree on exactly, kept in one place. q (rows, hd)
-    pre-scaled fp32; ``pos`` is the query position — a scalar for decode
-    (every row is the same token's G query heads) or a (rows, 1) per-row
-    vector for chunked prefill (rows = Sq*G, causal within the chunk)."""
-    rows = q.shape[0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=F32)        # (rows, page)
-    if cap:
-        s = cap * jnp.tanh(s / cap)
-    kpos = i * page + jax.lax.broadcasted_iota(jnp.int32, (rows, page), 1)
-    valid = kpos <= pos
-    if window:
-        valid &= kpos > pos - window
-    s = jnp.where(valid, s, NEG)
-
-    m_prev = m_ref[:, :1]                                      # (G, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = l_ref[...] * corr \
-        + jnp.broadcast_to(jnp.sum(p, axis=-1, keepdims=True),
-                           l_ref.shape)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=F32)
-
-
-def _block_range(pos, page, window, span=1):
+def _block_range(pos, page, window, span):
     """(lo, hi) inclusive block range the queries at ``pos .. pos+span-1``
-    must walk (span == 1 is the decode case; chunked prefill passes the
-    chunk length). ``lo`` is the first query's window start — later queries
+    must walk. ``lo`` is the first query's window start — later queries
     only look higher, and the per-row mask handles the rest."""
     hi = (pos + span - 1) // page          # last block holding a live token
     lo = jnp.maximum((pos - window + 1) // page, 0) if window else 0
     return lo, hi
 
 
-def _init_scratch(m_ref, l_ref, acc_ref):
-    m_ref[...] = jnp.full_like(m_ref, NEG)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-
-
-def _finalize_out(o_ref, l_ref, acc_ref):
-    out = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
-    o_ref[...] = out.reshape(o_ref.shape).astype(o_ref.dtype)
-
-
-def _kv_index_map(page, window, span=1):
-    """Shared BlockSpec index map for the page-table walk: clamp skipped
-    blocks onto an in-range (already fetched) page so no fresh DMA is
-    pipelined for them; pl.when skips their compute."""
+def _kv_index_map(page, window, span):
+    """Page-table walk for the kv tiles: clamp skipped blocks onto an
+    in-range (already fetched) page so no fresh DMA is pipelined for them;
+    pl.when skips their compute."""
     def kv_map(b, k, i, pt, pos):
-        p = pos[b]
-        lo, hi = _block_range(p, page, window, span)
+        lo, hi = _block_range(pos[b], page, window, span)
         ic = jnp.clip(i, lo, hi) if window else jnp.minimum(i, hi)
-        return (pt[b, ic], 0, k, 0)
+        return (pt[b, ic], k, 0, 0)
     return kv_map
 
 
-def _paged_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, page, G, hd, window, cap, scale,
-                  n_blocks):
-    # q_ref: (1, 1, G, hd) the G query heads of this (batch, kv-head) pair
-    # k_ref/v_ref: (1, page, 1, hd) one physical page of this kv head
+def _paged_kernel(pt_ref, pos_ref, q_ref, *refs, page, Sq, G, bits, window,
+                  cap, scale, n_blocks):
+    # q_ref: (Sq*G, hd) — one (sequence, kv-head)'s queries, row r = s*G + g
+    # holds query s; kv refs: one (page, hd_store) tile of this kv head;
+    # scale refs (quantized pools): the page's whole (K, page) scale tile.
+    if bits == 16:
+        k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        (k_ref, ks_ref, v_ref, vs_ref, o_ref, m_ref, l_ref,
+         acc_ref) = refs
     b = pl.program_id(0)
+    kh = pl.program_id(1)
     i = pl.program_id(2)
     pos = pos_ref[b]
-    lo, hi = _block_range(pos, page, window)
+    lo, hi = _block_range(pos, page, window, Sq)
 
     @pl.when(i == 0)
     def _init():
-        _init_scratch(m_ref, l_ref, acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     @pl.when((i >= lo) & (i <= hi))
     def _block():
-        q = q_ref[...].reshape(G, hd).astype(F32) * scale
-        k = k_ref[...].reshape(page, hd).astype(F32)
-        v = v_ref[...].reshape(page, hd).astype(F32)
-        _block_update(q, k, v, pos, i, page=page, window=window, cap=cap,
-                      m_ref=m_ref, l_ref=l_ref, acc_ref=acc_ref)
+        rows = Sq * G
+        q = q_ref[...].astype(F32) * scale
+        k = k_ref[...]
+        v = v_ref[...]
+        if bits == 4:
+            k = ref.unpack_int4_hd(k)
+            v = ref.unpack_int4_hd(v)
+        s = jax.lax.dot_general(q, k.astype(F32), (((1,), (1,)), ((), ())),
+                                preferred_element_type=F32)    # (rows, page)
+        if bits != 16:
+            # this kv head's per-token scales as a (1, page) row: scaling
+            # the score columns equals dequantizing K before the dot
+            row = jax.lax.broadcasted_iota(jnp.int32, ks_ref.shape, 0) == kh
+            s = s * jnp.sum(jnp.where(row, ks_ref[...], 0.0), axis=0,
+                            keepdims=True)
+        if cap:
+            s = cap * jnp.tanh(s / cap)
+        kpos = i * page + jax.lax.broadcasted_iota(jnp.int32, (rows, page), 1)
+        qpos = pos + jax.lax.broadcasted_iota(jnp.int32, (rows, page), 0) // G
+        valid = kpos <= qpos
+        if window:
+            valid &= kpos > qpos - window
+        s = jnp.where(valid, s, NEG)
+
+        m_prev = m_ref[:, :1]                                  # (rows, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = l_ref[...] * corr + jnp.broadcast_to(
+            jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
+        if bits != 16:
+            # V's per-token scales fold into the probability columns
+            row = jax.lax.broadcasted_iota(jnp.int32, vs_ref.shape, 0) == kh
+            p = p * jnp.sum(jnp.where(row, vs_ref[...], 0.0), axis=0,
+                            keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p, v.astype(F32), (((1,), (0,)), ((), ())),
+            preferred_element_type=F32)
 
     @pl.when(i == n_blocks - 1)
     def _finalize():
-        _finalize_out(o_ref, l_ref, acc_ref)
+        out = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "cap", "interpret"))
-def paged_attention_fwd(q, pool_k, pool_v, page_table, positions, *,
-                        window=0, cap=0.0, interpret=False):
-    """q (B,H,hd); pool_k/v (P, page, K, hd); page_table (B, n_blocks) int32
-    (unused tails -> scratch page 0); positions (B,) int32. H = K*G.
-    Returns (B, H, hd) in q.dtype."""
-    B, H, hd = q.shape
-    _, page, K, _ = pool_k.shape
+def _paged_call(q, pool_k, k_scale, pool_v, v_scale, page_table, positions,
+                *, window, cap, interpret):
+    """Shared pallas_call for every public entry point. q (B, Sq, H, hd);
+    pool_k/v (P, K, page, hd_store); k_scale/v_scale (P, K, page) fp32 for
+    quantized pools, None for bf16 ones. Returns (B, Sq, H, hd)."""
+    B, Sq, H, hd = q.shape
+    _, K, page, hd_store = pool_k.shape
+    bits = 16 if k_scale is None else ref.kv_bits_of(pool_k, hd)
     G = H // K
     n_blocks = page_table.shape[1]
-    scale = hd ** -0.5
-    qr = q.reshape(B, K, G, hd)
+    rows = Sq * G
+    # (B, Sq, K, G, hd) -> (B, K, Sq*G, hd): row r = s*G + g of kv head k
+    qr = jnp.moveaxis(q.reshape(B, Sq, K, G, hd), 1, 2).reshape(B, K, rows,
+                                                                hd)
 
-    kernel = functools.partial(_paged_kernel, page=page, G=G, hd=hd,
-                               window=window, cap=cap, scale=scale,
-                               n_blocks=n_blocks)
-    kv_map = _kv_index_map(page, window)
+    kernel = functools.partial(_paged_kernel, page=page, Sq=Sq, G=G,
+                               bits=bits, window=window, cap=cap,
+                               scale=hd ** -0.5, n_blocks=n_blocks)
+    kv_map = _kv_index_map(page, window, Sq)
+    q_spec = pl.BlockSpec((None, None, rows, hd),
+                          lambda b, k, i, pt, pos: (b, k, 0, 0))
+    kv_spec = pl.BlockSpec((None, None, page, hd_store), kv_map)
+    if bits == 16:
+        in_specs = [q_spec, kv_spec, kv_spec]
+        operands = (qr, pool_k, pool_v)
+    else:
+        scale_spec = pl.BlockSpec(
+            (None, K, page), lambda b, k, i, pt, pos: kv_map(b, k, i, pt,
+                                                             pos)[:1] + (0, 0))
+        in_specs = [q_spec, kv_spec, scale_spec, kv_spec, scale_spec]
+        operands = (qr, pool_k, k_scale, pool_v, v_scale)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, K, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, k, i, pt, pos: (b, k, 0, 0)),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, k, i, pt, pos: (b, k, 0, 0)),
+        in_specs=in_specs,
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((G, LANES), F32),    # running max m
-            pltpu.VMEM((G, LANES), F32),    # running sum l
-            pltpu.VMEM((G, hd), F32),       # output accumulator
+            pltpu.VMEM((rows, LANES), F32),    # running max m
+            pltpu.VMEM((rows, LANES), F32),    # running sum l
+            pltpu.VMEM((rows, hd), F32),       # output accumulator
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, K, rows, hd), q.dtype),
         interpret=interpret,
-    )(page_table, positions, qr, pool_k, pool_v)
-    return out.reshape(B, H, hd)
+    )(page_table, positions, *operands)
+    return jnp.moveaxis(out.reshape(B, K, Sq, G, hd), 2, 1).reshape(
+        B, Sq, H, hd)
 
 
-# ------------------------------------------------- fused-dequant variant ----
-def _paged_quant_kernel(pt_ref, pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
-                        o_ref, m_ref, l_ref, acc_ref, *, page, G, hd, bits,
-                        window, cap, scale, n_blocks):
-    # q_ref: (1, 1, G, hd) fp; k_ref/v_ref: (1, page, 1, hd_store) int8, one
-    # physical page of this kv head; ks_ref/vs_ref: (1, page, 1) fp32 scales
-    # riding the same scalar-prefetched page-table walk as the int8 pages.
-    # Dequant happens here, inside the online-softmax block loop — the only
-    # fp KV ever materialized is one (page, hd) tile in VMEM. Everything
-    # past the load is _block_update, shared with the fp kernel.
-    b = pl.program_id(0)
-    i = pl.program_id(2)
-    pos = pos_ref[b]
-    lo, hi = _block_range(pos, page, window)
-
-    @pl.when(i == 0)
-    def _init():
-        _init_scratch(m_ref, l_ref, acc_ref)
-
-    @pl.when((i >= lo) & (i <= hi))
-    def _block():
-        q = q_ref[...].reshape(G, hd).astype(F32) * scale
-
-        def dequant(int_ref, scale_ref):
-            qv = int_ref[...].reshape(page, -1)
-            if bits == 4:
-                # the storage mapping's single source of truth (static
-                # shapes, jnp-only — fine inside the kernel body)
-                qv = ref.unpack_int4_hd(qv)
-            return qv.astype(F32) * scale_ref[...].reshape(page, 1)
-
-        _block_update(q, dequant(k_ref, ks_ref), dequant(v_ref, vs_ref),
-                      pos, i, page=page, window=window, cap=cap,
-                      m_ref=m_ref, l_ref=l_ref, acc_ref=acc_ref)
-
-    @pl.when(i == n_blocks - 1)
-    def _finalize():
-        _finalize_out(o_ref, l_ref, acc_ref)
+_STATIC = ("window", "cap", "interpret")
 
 
-@functools.partial(jax.jit, static_argnames=("window", "cap", "interpret"))
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def paged_attention_fwd(q, pool_k, pool_v, page_table, positions, *,
+                        window=0, cap=0.0, interpret=False):
+    """Paged-attention decode. q (B, H, hd); pool_k/v (P, K, page, hd);
+    page_table (B, n_blocks) int32 (unused tails -> scratch page 0);
+    positions (B,) int32 position of each query token. H = K*G.
+    Returns (B, H, hd) in q.dtype."""
+    return _paged_call(q[:, None], pool_k, None, pool_v, None, page_table,
+                       positions, window=window, cap=cap,
+                       interpret=interpret)[:, 0]
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def paged_attention_quant_fwd(q, pool_k, k_scale, pool_v, v_scale,
                               page_table, positions, *, window=0, cap=0.0,
                               interpret=False):
-    """Fused dequantizing paged-attention decode.
+    """Fused-dequant paged-attention decode over a quantized pool.
 
-    q (B, H, hd) fp; pool_k/v (P, page, K, hd_store) int8 with hd_store = hd
-    (int8 KV) or hd//2 (int4 packed along head_dim); k_scale/v_scale
-    (P, page, K) fp32 per-page-slot per-head scales; page_table (B,
-    n_blocks) int32 (unused tails -> scratch page 0); positions (B,) int32.
-
-    The scale tiles use the same scalar-prefetch index map as their pages,
-    so the page-table walk resolves both DMAs before issue; dequantization
-    happens inside the online-softmax block loop and no dense fp KV view is
-    ever materialized. Returns (B, H, hd) in q.dtype."""
-    B, H, hd = q.shape
-    _, page, K, hd_store = pool_k.shape
-    bits = ref.kv_bits_of(pool_k, hd)
-    G = H // K
-    n_blocks = page_table.shape[1]
-    scale = hd ** -0.5
-    qr = q.reshape(B, K, G, hd)
-
-    kernel = functools.partial(_paged_quant_kernel, page=page, G=G, hd=hd,
-                               bits=bits, window=window, cap=cap, scale=scale,
-                               n_blocks=n_blocks)
-    kv_map = _kv_index_map(page, window)
-
-    def scale_map(b, k, i, pt, pos):
-        return kv_map(b, k, i, pt, pos)[:3]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, K, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, k, i, pt, pos: (b, k, 0, 0)),
-            pl.BlockSpec((1, page, 1, hd_store), kv_map),
-            pl.BlockSpec((1, page, 1), scale_map),
-            pl.BlockSpec((1, page, 1, hd_store), kv_map),
-            pl.BlockSpec((1, page, 1), scale_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, k, i, pt, pos: (b, k, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, LANES), F32),    # running max m
-            pltpu.VMEM((G, LANES), F32),    # running sum l
-            pltpu.VMEM((G, hd), F32),       # output accumulator
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
-        interpret=interpret,
-    )(page_table, positions, qr, pool_k, k_scale, pool_v, v_scale)
-    return out.reshape(B, H, hd)
+    q (B, H, hd) fp; pool_k/v (P, K, page, hd_store) int8 with hd_store =
+    hd (int8 KV) or hd//2 (int4 packed along head_dim); k_scale/v_scale
+    (P, K, page) fp32 per-token per-head scales; page_table/positions as in
+    paged_attention_fwd. Returns (B, H, hd) in q.dtype."""
+    return _paged_call(q[:, None], pool_k, k_scale, pool_v, v_scale,
+                       page_table, positions, window=window, cap=cap,
+                       interpret=interpret)[:, 0]
 
 
-# ------------------------------------------------ chunked-prefill variant ----
-def _prefill_qpos(pos, Sq, G):
-    """Per-row query positions for the (Sq*G, hd) flattened chunk: row
-    r = s*G + g holds query s, so its absolute position is pos + r // G."""
-    r = jax.lax.broadcasted_iota(jnp.int32, (Sq * G, 1), 0)
-    return pos + r // G
-
-
-def _paged_prefill_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                          m_ref, l_ref, acc_ref, *, page, Sq, G, hd, window,
-                          cap, scale, n_blocks):
-    # q_ref: (1, 1, Sq, G, hd) — one (batch, kv-head)'s chunk of queries,
-    # flattened to (Sq*G, hd) rows; k_ref/v_ref: (1, page, 1, hd) one
-    # physical page of this kv head, walked exactly like decode but with a
-    # per-row causal mask (query t sees kpos <= pos + t).
-    b = pl.program_id(0)
-    i = pl.program_id(2)
-    pos = pos_ref[b]
-    lo, hi = _block_range(pos, page, window, span=Sq)
-
-    @pl.when(i == 0)
-    def _init():
-        _init_scratch(m_ref, l_ref, acc_ref)
-
-    @pl.when((i >= lo) & (i <= hi))
-    def _block():
-        q = q_ref[...].reshape(Sq * G, hd).astype(F32) * scale
-        k = k_ref[...].reshape(page, hd).astype(F32)
-        v = v_ref[...].reshape(page, hd).astype(F32)
-        _block_update(q, k, v, _prefill_qpos(pos, Sq, G), i, page=page,
-                      window=window, cap=cap, m_ref=m_ref, l_ref=l_ref,
-                      acc_ref=acc_ref)
-
-    @pl.when(i == n_blocks - 1)
-    def _finalize():
-        _finalize_out(o_ref, l_ref, acc_ref)
-
-
-@functools.partial(jax.jit, static_argnames=("window", "cap", "interpret"))
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def paged_prefill_fwd(q, pool_k, pool_v, page_table, positions, *,
                       window=0, cap=0.0, interpret=False):
     """Chunked-prefill attention over the page pool (prefill-with-cache).
 
     q (B, Sq, H, hd) — one prompt chunk of queries per sequence, whose K/V
-    have already been scattered into the pool; pool_k/v (P, page, K, hd);
+    have already been scattered into the pool; pool_k/v (P, K, page, hd);
     page_table (B, n_blocks) int32 (unused tails -> scratch page 0);
     positions (B,) int32 absolute position of each chunk's FIRST token.
     Query t of sequence b attends causally to kpos <= positions[b] + t —
-    the resident prompt prefix plus the chunk itself — via the same
-    scalar-prefetched page-table walk and _block_update body as decode, so
-    the dense (B, n_blocks*page, K, hd) prompt KV view is never
-    materialized. Returns (B, Sq, H, hd) in q.dtype."""
-    B, Sq, H, hd = q.shape
-    _, page, K, _ = pool_k.shape
-    G = H // K
-    n_blocks = page_table.shape[1]
-    scale = hd ** -0.5
-    qr = jnp.moveaxis(q.reshape(B, Sq, K, G, hd), 1, 2)  # (B, K, Sq, G, hd)
-
-    kernel = functools.partial(_paged_prefill_kernel, page=page, Sq=Sq, G=G,
-                               hd=hd, window=window, cap=cap, scale=scale,
-                               n_blocks=n_blocks)
-    kv_map = _kv_index_map(page, window, span=Sq)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, K, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, Sq, G, hd),
-                         lambda b, k, i, pt, pos: (b, k, 0, 0, 0)),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, Sq, G, hd),
-                               lambda b, k, i, pt, pos: (b, k, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Sq * G, LANES), F32),    # running max m
-            pltpu.VMEM((Sq * G, LANES), F32),    # running sum l
-            pltpu.VMEM((Sq * G, hd), F32),       # output accumulator
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, Sq, G, hd), q.dtype),
-        interpret=interpret,
-    )(page_table, positions, qr, pool_k, pool_v)
-    return jnp.moveaxis(out, 2, 1).reshape(B, Sq, H, hd)
+    the resident prompt prefix plus the chunk itself. Returns
+    (B, Sq, H, hd) in q.dtype."""
+    return _paged_call(q, pool_k, None, pool_v, None, page_table, positions,
+                       window=window, cap=cap, interpret=interpret)
 
 
-def _paged_prefill_quant_kernel(pt_ref, pos_ref, q_ref, k_ref, ks_ref,
-                                v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref,
-                                *, page, Sq, G, hd, bits, window, cap, scale,
-                                n_blocks):
-    # The fused-dequant chunked-prefill walk: int8/int4 pages + scale tiles
-    # ride the scalar-prefetched page-table walk (as in the decode quant
-    # kernel); the per-row causal chunk mask comes from _prefill_qpos.
-    b = pl.program_id(0)
-    i = pl.program_id(2)
-    pos = pos_ref[b]
-    lo, hi = _block_range(pos, page, window, span=Sq)
-
-    @pl.when(i == 0)
-    def _init():
-        _init_scratch(m_ref, l_ref, acc_ref)
-
-    @pl.when((i >= lo) & (i <= hi))
-    def _block():
-        q = q_ref[...].reshape(Sq * G, hd).astype(F32) * scale
-
-        def dequant(int_ref, scale_ref):
-            qv = int_ref[...].reshape(page, -1)
-            if bits == 4:
-                qv = ref.unpack_int4_hd(qv)
-            return qv.astype(F32) * scale_ref[...].reshape(page, 1)
-
-        _block_update(q, dequant(k_ref, ks_ref), dequant(v_ref, vs_ref),
-                      _prefill_qpos(pos, Sq, G), i, page=page, window=window,
-                      cap=cap, m_ref=m_ref, l_ref=l_ref, acc_ref=acc_ref)
-
-    @pl.when(i == n_blocks - 1)
-    def _finalize():
-        _finalize_out(o_ref, l_ref, acc_ref)
-
-
-@functools.partial(jax.jit, static_argnames=("window", "cap", "interpret"))
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def paged_prefill_quant_fwd(q, pool_k, k_scale, pool_v, v_scale,
                             page_table, positions, *, window=0, cap=0.0,
                             interpret=False):
-    """Fused-dequant chunked-prefill attention over a quantized page pool.
-
-    q (B, Sq, H, hd) fp chunk queries (K/V already quantized into the
-    pool); pool_k/v (P, page, K, hd_store) int8 with hd_store = hd (int8)
-    or hd//2 (int4 packed along head_dim); k_scale/v_scale (P, page, K)
-    fp32; page_table (B, n_blocks); positions (B,) chunk-start positions.
-    Same walk as paged_prefill_fwd with dequantization inside the block
-    loop. Returns (B, Sq, H, hd) in q.dtype."""
-    B, Sq, H, hd = q.shape
-    _, page, K, hd_store = pool_k.shape
-    bits = ref.kv_bits_of(pool_k, hd)
-    G = H // K
-    n_blocks = page_table.shape[1]
-    scale = hd ** -0.5
-    qr = jnp.moveaxis(q.reshape(B, Sq, K, G, hd), 1, 2)  # (B, K, Sq, G, hd)
-
-    kernel = functools.partial(_paged_prefill_quant_kernel, page=page, Sq=Sq,
-                               G=G, hd=hd, bits=bits, window=window, cap=cap,
-                               scale=scale, n_blocks=n_blocks)
-    kv_map = _kv_index_map(page, window, span=Sq)
-
-    def scale_map(b, k, i, pt, pos):
-        return kv_map(b, k, i, pt, pos)[:3]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, K, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, Sq, G, hd),
-                         lambda b, k, i, pt, pos: (b, k, 0, 0, 0)),
-            pl.BlockSpec((1, page, 1, hd_store), kv_map),
-            pl.BlockSpec((1, page, 1), scale_map),
-            pl.BlockSpec((1, page, 1, hd_store), kv_map),
-            pl.BlockSpec((1, page, 1), scale_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, Sq, G, hd),
-                               lambda b, k, i, pt, pos: (b, k, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Sq * G, LANES), F32),    # running max m
-            pltpu.VMEM((Sq * G, LANES), F32),    # running sum l
-            pltpu.VMEM((Sq * G, hd), F32),       # output accumulator
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, Sq, G, hd), q.dtype),
-        interpret=interpret,
-    )(page_table, positions, qr, pool_k, k_scale, pool_v, v_scale)
-    return jnp.moveaxis(out, 2, 1).reshape(B, Sq, H, hd)
+    """Fused-dequant chunked-prefill attention over a quantized page pool
+    (layouts as in paged_attention_quant_fwd, queries and positions as in
+    paged_prefill_fwd). Returns (B, Sq, H, hd) in q.dtype."""
+    return _paged_call(q, pool_k, k_scale, pool_v, v_scale, page_table,
+                       positions, window=window, cap=cap,
+                       interpret=interpret)

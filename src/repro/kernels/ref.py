@@ -81,33 +81,35 @@ def kv_qmax(bits: int) -> float:
 
 def pack_int4_hd(q: jax.Array) -> jax.Array:
     """Pack int4 codes two-per-byte along head_dim (the minor axis):
-    element 2i rides the low nibble, 2i+1 the high nibble.
+    element i of the first half rides the low nibble of byte i, element
+    i + hd/2 its high nibble — so unpacking is two shifts and one
+    concatenation, with no lane interleave for the TPU kernel to lower.
     (..., hd) int8 in [-7, 7] -> (..., hd//2) int8."""
-    assert q.shape[-1] % 2 == 0, q.shape
-    lo = q[..., 0::2] & 0x0F
-    hi = (q[..., 1::2] & 0x0F) << 4
+    h = q.shape[-1] // 2
+    assert q.shape[-1] == 2 * h, q.shape
+    lo = q[..., :h] & 0x0F
+    hi = (q[..., h:] & 0x0F) << 4
     return (lo | hi).astype(jnp.int8)
 
 
 def unpack_int4_hd(packed: jax.Array) -> jax.Array:
-    """Inverse of pack_int4_hd: (..., hd//2) int8 -> (..., hd) int8 in
-    [-7, 7] (arithmetic shifts sign-extend the nibbles)."""
-    lo = (packed.astype(jnp.int8) << 4) >> 4
-    hi = packed.astype(jnp.int8) >> 4
-    out = jnp.stack([lo, hi], axis=-1)            # (..., hd//2, 2)
-    return out.reshape(*packed.shape[:-1], packed.shape[-1] * 2)
+    """Inverse of pack_int4_hd: (..., hd//2) int8 -> (..., hd) int32 in
+    [-7, 7]. Arithmetic shifts on int32 sign-extend the nibbles; int32 is
+    what the Pallas kernel computes in as well."""
+    x = packed.astype(jnp.int32)
+    return jnp.concatenate([(x << 28) >> 28, x >> 4], axis=-1)
 
 
 def quantize_kv(x: jax.Array, bits: int, *, granularity: str = "token"):
     """Symmetric per-head KV quantization (the pool-write semantics).
 
-    x (..., K, hd) — any number of leading axes; for ``granularity="page"``
-    the third-from-last axis is the page-slot axis.
+    x (..., hd) — any number of leading axes; for ``granularity="page"``
+    it is a pool tile (..., K, page, hd), the pool's own layout.
 
     granularity:
-      "token" — one scale per (leading..., K): amax over hd only. This is
+      "token" — one scale per leading index: amax over hd only. This is
                 what the paged pool stores (each page carries a
-                (page_size, K) fp32 scale tile), because decode writes one
+                (K, page_size) fp32 scale tile), because decode writes one
                 token at a time and must never re-scale a page in place.
       "page"  — one scale per (page, K) pair: amax over (slot, hd). Coarser;
                 kept for the scale-granularity error-bound study
@@ -115,17 +117,17 @@ def quantize_kv(x: jax.Array, bits: int, *, granularity: str = "token"):
 
     Returns (stored, scale): stored int8, packed along hd when bits == 4;
     scale fp32 with the reduced axes dropped ("token" -> x.shape[:-1],
-    "page" -> x.shape[:-3] + (K,))."""
+    "page" -> x.shape[:-2])."""
     qmax = kv_qmax(bits)
     xf = x.astype(F32)
     if granularity == "token":
-        amax = jnp.max(jnp.abs(xf), axis=-1)                 # (..., K)
+        amax = jnp.max(jnp.abs(xf), axis=-1)
         scale = amax / qmax + 1e-12
         div = scale[..., None]
     elif granularity == "page":
-        amax = jnp.max(jnp.abs(xf), axis=(-3, -1))           # (..., K)
+        amax = jnp.max(jnp.abs(xf), axis=(-2, -1))           # (..., K)
         scale = amax / qmax + 1e-12
-        div = scale[..., None, :, None]
+        div = scale[..., None, None]
     else:
         raise ValueError(f"unknown scale granularity {granularity!r}")
     q = jnp.clip(jnp.round(xf / div), -qmax, qmax).astype(jnp.int8)
@@ -142,7 +144,7 @@ def dequantize_kv(stored: jax.Array, scale: jax.Array, bits: int, *,
     if granularity == "token":
         return q.astype(F32) * scale[..., None]
     if granularity == "page":
-        return q.astype(F32) * scale[..., None, :, None]
+        return q.astype(F32) * scale[..., None, None]
     raise ValueError(f"unknown scale granularity {granularity!r}")
 
 
@@ -164,9 +166,10 @@ def _paged_block_walk(q, load_k, load_v, K, hd, page, n_blocks, positions, *,
                       window, cap):
     """Shared block-walk body for the fp and quantized pure-JAX paged
     attention refs — the semantics both must agree on exactly, kept in one
-    place (the Pallas twins share _block_update the same way). ``load_k``/
-    ``load_v`` map a block index to its fp32 (B, page, K, hd) tile — a pool
-    gather for the fp path, gather + dequant for the quantized one.
+    place (the Pallas entry points share one kernel body the same way).
+    ``load_k``/``load_v`` map a block index to its fp32 (B, K, page, hd)
+    tile — a pool gather for the fp path, gather + dequant for the
+    quantized one.
 
     q is (B, Sq, H, hd): Sq == 1 is the decode walk, Sq > 1 the
     chunked-prefill walk — query t of sequence b sits at absolute position
@@ -203,7 +206,7 @@ def _paged_block_walk(q, load_k, load_v, K, hd, page, n_blocks, positions, *,
         lo = jnp.zeros((), jnp.int32)
 
     def score_block(i, s_buf):
-        s = jnp.einsum("bkgsd,bpkd->bkgsp", qf, load_k(i)) * scale
+        s = jnp.einsum("bkgsd,bkpd->bkgsp", qf, load_k(i)) * scale
         if cap:
             s = cap * jnp.tanh(s / cap)
         kpos = i * page + jnp.arange(page)
@@ -220,7 +223,7 @@ def _paged_block_walk(q, load_k, load_v, K, hd, page, n_blocks, positions, *,
     def pv_block(i, acc):
         wb = jax.lax.dynamic_slice(w, (0, 0, 0, 0, i * page),
                                    (B, K, G, Sq, page))
-        return acc + jnp.einsum("bkgsp,bpkd->bkgsd", wb, load_v(i))
+        return acc + jnp.einsum("bkgsp,bkpd->bkgsd", wb, load_v(i))
 
     o = jax.lax.fori_loop(lo, hi, pv_block,
                           jnp.zeros((B, K, G, Sq, hd), F32))
@@ -232,7 +235,7 @@ def paged_attention_ref(q, pool_k, pool_v, page_table, positions, *,
     """Block-walking paged decode attention (the CPU serving fallback and
     the semantics oracle for kernels/paged_attention.py).
 
-    q (B, H, hd) one query token per sequence; pool_k/v (P, page, K, hd);
+    q (B, H, hd) one query token per sequence; pool_k/v (P, K, page, hd);
     page_table (B, n_blocks) int32, unused tails pointing at scratch page 0;
     positions (B,) int32 absolute position of the query token (== index of
     the newest cached token). H = K*G (GQA). Walk semantics in
@@ -247,18 +250,27 @@ def paged_prefill_ref(q, pool_k, pool_v, page_table, positions, *,
     the semantics oracle for paged_prefill_fwd).
 
     q (B, Sq, H, hd) one prompt chunk per sequence, whose K/V have already
-    been written into the pool; pool_k/v (P, page, K, hd); page_table
+    been written into the pool; pool_k/v (P, K, page, hd); page_table
     (B, n_blocks) int32 with unused tails on scratch page 0; positions (B,)
     int32 absolute position of each chunk's FIRST token (the resident
     prefix length). Query t attends causally to pool slots at
     kpos <= positions[b] + t — the prompt prefix resident in the pool plus
     the chunk itself. Walk semantics in _paged_block_walk."""
     hd = q.shape[-1]
-    _, page, K, _ = pool_k.shape
+    _, K, page, _ = pool_k.shape
     return _paged_block_walk(
         q, lambda i: pool_k[page_table[:, i]].astype(F32),
         lambda i: pool_v[page_table[:, i]].astype(F32),
         K, hd, page, page_table.shape[1], positions, window=window, cap=cap)
+
+
+def chronological_kv(pool, page_table):
+    """The dense chronological view of a page pool: (P, K, page, hd) gathered
+    through page_table (B, n_blocks) -> (B, n_blocks*page, K, hd). Test-only
+    — exactly the view the walks exist to avoid."""
+    g = pool[page_table]                          # (B, n, K, page, hd)
+    B, n, K, page, hd = g.shape
+    return jnp.swapaxes(g, 2, 3).reshape(B, n * page, K, hd)
 
 
 def paged_attention_dense_ref(q, pool_k, pool_v, page_table, positions, *,
@@ -267,9 +279,9 @@ def paged_attention_dense_ref(q, pool_k, pool_v, page_table, positions, *,
     this materializes exactly the (B, T, K, hd) view the kernel exists to
     avoid."""
     B, H, hd = q.shape
-    K = pool_k.shape[2]
-    k = pool_k[page_table].reshape(B, -1, K, hd)
-    v = pool_v[page_table].reshape(B, -1, K, hd)
+    k = chronological_kv(pool_k, page_table)
+    v = chronological_kv(pool_v, page_table)
+    K = k.shape[2]
     T = k.shape[1]
     G = H // K
     if G > 1:
@@ -296,9 +308,9 @@ def paged_prefill_dense_ref(q, pool_k, pool_v, page_table, positions, *,
     materializes exactly the (B, T, K, hd) view the prefill walk avoids.
     q (B, Sq, H, hd); positions (B,) chunk-start positions."""
     B, Sq, H, hd = q.shape
-    K = pool_k.shape[2]
-    k = pool_k[page_table].reshape(B, -1, K, hd)
-    v = pool_v[page_table].reshape(B, -1, K, hd)
+    k = chronological_kv(pool_k, page_table)
+    v = chronological_kv(pool_v, page_table)
+    K = k.shape[2]
     T = k.shape[1]
     G = H // K
     if G > 1:
@@ -325,14 +337,14 @@ def paged_attention_quant_ref(q, pool_k, k_scale, pool_v, v_scale,
     (the CPU serving fallback and the semantics oracle for the fused-dequant
     Pallas kernel).
 
-    q (B, H, hd) fp; pool_k/v (P, page, K, hd_store) int8 — hd_store == hd
+    q (B, H, hd) fp; pool_k/v (P, K, page, hd_store) int8 — hd_store == hd
     for int8 KV, hd // 2 for int4 packed along head_dim (pack_int4_hd);
-    k_scale/v_scale (P, page, K) fp32 per-page-slot, per-kv-head scales;
+    k_scale/v_scale (P, K, page) fp32 per-page-slot, per-kv-head scales;
     page_table (B, n_blocks) int32 with unused tails on scratch page 0;
     positions (B,) int32.
 
     Pages are dequantized one block at a time inside the walk — each block
-    materializes only a (B, page, K, hd) fp tile; the dense chronological
+    materializes only a (B, K, page, hd) fp tile; the dense chronological
     (B, n_blocks*page, K, hd) fp KV view is never built (asserted on the
     decode jaxpr in tests/test_kvquant.py). Walk semantics shared with the
     fp ref via _paged_block_walk."""
@@ -349,7 +361,7 @@ def paged_prefill_quant_ref(q, pool_k, k_scale, pool_v, v_scale,
     hd) fp; positions (B,) int32 chunk-start positions (see
     paged_prefill_ref)."""
     hd = q.shape[-1]
-    _, page, K, _ = pool_k.shape
+    _, K, page, _ = pool_k.shape
     bits = kv_bits_of(pool_k, hd)
 
     def loader(pool, scales):

@@ -6,18 +6,13 @@ touch jax device state (the dry-run sets XLA_FLAGS before any jax import).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes):
-    """jax.make_mesh across the pinned jax range: newer jax wants explicit
-    Auto axis types; 0.4.x has neither the kwarg nor the enum (every axis
-    is implicitly auto there)."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except (ImportError, TypeError):
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto: the sharding rules place arrays
+    with NamedShardings and let the partitioner propagate the rest."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

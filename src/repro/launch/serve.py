@@ -25,9 +25,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, tiny_config
-from repro.core.hardware_model import HARDWARES
+from repro.core.hardware_model import HARDWARES, hardware_for_device
 from repro.core.quantization import make_quant_dot
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
+from repro.models.transformer import to_page_layout
 from repro.serving.engine import Engine, Request, derive_policy
 from repro.serving.engine.pool import quiet_donation
 
@@ -68,7 +70,8 @@ def _identity_paged_pool(cache, B: int, max_len: int, page: int):
         pad = [(0, 0)] * c.ndim
         pad[2] = (0, span - c.shape[2])
         c = jnp.pad(c, pad)
-        c = c.reshape(c.shape[0], B * ppseq, page, *c.shape[3:])
+        c = to_page_layout(c.reshape(c.shape[0], B * span, *c.shape[3:]),
+                           page)
         pool = jnp.zeros((c.shape[0], B * ppseq + 1) + c.shape[2:], c.dtype)
         return pool.at[:, 1:].set(c)
 
@@ -171,7 +174,10 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--tiny", action="store_true")
-    ap.add_argument("--hw", default="v5e-1chip", choices=sorted(HARDWARES))
+    ap.add_argument("--hw", default="", choices=sorted(HARDWARES),
+                    help="hardware target the admission roofline prices "
+                         "(default: the attached device, looked up by "
+                         "its device kind)")
     ap.add_argument("--requests", type=int, default=8,
                     help="engine mode: number of requests in the trace")
     ap.add_argument("--batch", type=int, default=4,
@@ -259,6 +265,7 @@ def main():
                          "'{\"sub0\": 4, \"sub1\": 8}'. Overrides "
                          "--kv-bits")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.prompt_len < 1:
         ap.error("--prompt-len must be >= 1")
     if args.quant_policy and not args.sequential:
@@ -316,7 +323,8 @@ def main():
               np.asarray(out[0, args.prompt_len:args.prompt_len + 16]))
         return
 
-    hw = HARDWARES[args.hw]
+    hw = HARDWARES[args.hw] if args.hw \
+        else hardware_for_device(jax.devices()[0])
     max_len = args.prompt_len + args.gen
     occupancy = args.expected_occupancy
     if occupancy is None:

@@ -189,7 +189,7 @@ def attention_decode_paged(p, x, pool_k, pool_v, page_table, positions,
     """Slot-indexed one-token decode against a paged KV pool.
 
     x           (B, 1, D)   one new token's activations per sequence
-    pool_k/v    (P, page, K, hd)  this layer's physical page pool
+    pool_k/v    (P, K, page, hd)  this layer's physical page pool
     page_table  (B, n_pages) int32 physical page ids per logical block;
                 unused tail entries must point at the scratch page 0
     positions   (B,) int32  absolute position of the incoming token (== the
@@ -209,7 +209,7 @@ def attention_decode_paged(p, x, pool_k, pool_v, page_table, positions,
     dense chronological cache to fp32-accumulation precision.
 
     Quantized pools (serving/kvquant): ``pool_k``/``pool_v`` may instead be
-    ``{"q": int8 pages, "scale": fp32 (P, page, K)}`` dicts — the stored
+    ``{"q": int8 pages, "scale": fp32 (P, K, page)}`` dicts — the stored
     bitwidth (int8, or int4 packed along head_dim) is inferred from the
     stored minor-dim size. The incoming token's k/v are quantized on write
     (per-token per-head symmetric scales, the same mapping the engine's
@@ -227,7 +227,7 @@ def attention_decode_paged(p, x, pool_k, pool_v, page_table, positions,
     Returns (out (B,1,D), pool_k, pool_v).
     """
     quantized = isinstance(pool_k, dict)
-    page = (pool_k["q"] if quantized else pool_k).shape[1]
+    page = (pool_k["q"] if quantized else pool_k).shape[2]
     q, k_new, v_new = qkv(p, x, cfg.rope_theta, positions[:, None], dot=dot)
     pids = jnp.take_along_axis(page_table, (positions // page)[:, None],
                                axis=1)[:, 0]
@@ -239,9 +239,9 @@ def attention_decode_paged(p, x, pool_k, pool_v, page_table, positions,
 
         def write(pool, new):                        # new: (B, K, hd)
             qv, sc = kref.quantize_kv(new, bits)
-            return {"q": pool["q"].at[pids, slots].set(
+            return {"q": pool["q"].at[pids, :, slots].set(
                         qv, mode="promise_in_bounds"),
-                    "scale": pool["scale"].at[pids, slots].set(
+                    "scale": pool["scale"].at[pids, :, slots].set(
                         sc, mode="promise_in_bounds")}
 
         pool_k = write(pool_k, k_new[:, 0])
@@ -251,10 +251,10 @@ def attention_decode_paged(p, x, pool_k, pool_v, page_table, positions,
             pool_v["scale"], page_table, positions, window=window,
             cap=cfg.attn_softcap, mode=kernel)[:, None]
     else:
-        pool_k = pool_k.at[pids, slots].set(k_new[:, 0],
-                                            mode="promise_in_bounds")
-        pool_v = pool_v.at[pids, slots].set(v_new[:, 0],
-                                            mode="promise_in_bounds")
+        pool_k = pool_k.at[pids, :, slots].set(k_new[:, 0],
+                                               mode="promise_in_bounds")
+        pool_v = pool_v.at[pids, :, slots].set(v_new[:, 0],
+                                               mode="promise_in_bounds")
         o = kops.paged_attention(q[:, 0], pool_k, pool_v, page_table,
                                  positions, window=window,
                                  cap=cfg.attn_softcap, mode=kernel)[:, None]
@@ -268,7 +268,7 @@ def attention_prefill_paged(p, x, pool_k, pool_v, page_table, positions,
     """Chunked prefill against a paged KV pool (prefill-with-cache).
 
     x           (B, Sq, D)  one prompt chunk's activations per sequence
-    pool_k/v    (P, page, K, hd)  this layer's physical page pool (or the
+    pool_k/v    (P, K, page, hd)  this layer's physical page pool (or the
                 quantized ``{"q", "scale"}`` dicts, see below)
     page_table  (B, n_pages) int32; unused tails -> scratch page 0
     positions   (B,) int32  absolute position of each chunk's FIRST token
@@ -292,7 +292,7 @@ def attention_prefill_paged(p, x, pool_k, pool_v, page_table, positions,
     Returns (out (B, Sq, D), pool_k, pool_v).
     """
     quantized = isinstance(pool_k, dict)
-    page = (pool_k["q"] if quantized else pool_k).shape[1]
+    page = (pool_k["q"] if quantized else pool_k).shape[2]
     B, Sq, _ = x.shape
     n_blocks = page_table.shape[1]
     abs_pos = positions[:, None] + jnp.arange(Sq, dtype=jnp.int32)[None, :]
@@ -313,9 +313,9 @@ def attention_prefill_paged(p, x, pool_k, pool_v, page_table, positions,
 
         def write(pool, new):                        # new: (B, Sq, K, hd)
             qv, sc = kref.quantize_kv(new, bits)
-            return {"q": pool["q"].at[pids, slots].set(
+            return {"q": pool["q"].at[pids, :, slots].set(
                         qv, mode="promise_in_bounds"),
-                    "scale": pool["scale"].at[pids, slots].set(
+                    "scale": pool["scale"].at[pids, :, slots].set(
                         sc, mode="promise_in_bounds")}
 
         pool_k = write(pool_k, k_new)
@@ -325,8 +325,10 @@ def attention_prefill_paged(p, x, pool_k, pool_v, page_table, positions,
             page_table, positions, window=window, cap=cfg.attn_softcap,
             mode=kernel)
     else:
-        pool_k = pool_k.at[pids, slots].set(k_new, mode="promise_in_bounds")
-        pool_v = pool_v.at[pids, slots].set(v_new, mode="promise_in_bounds")
+        pool_k = pool_k.at[pids, :, slots].set(k_new,
+                                               mode="promise_in_bounds")
+        pool_v = pool_v.at[pids, :, slots].set(v_new,
+                                               mode="promise_in_bounds")
         o = kops.paged_attention_prefill(q, pool_k, pool_v, page_table,
                                          positions, window=window,
                                          cap=cfg.attn_softcap, mode=kernel)

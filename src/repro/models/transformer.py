@@ -569,19 +569,22 @@ def normalize_kv_bits(cfg, kv_bits) -> Optional[Tuple[int, ...]]:
 
 def pool_specs(cfg, num_pages: int, page_size: int, kv_bits=None):
     """Abstract paged-KV-pool pytree: per sub-layer slot, k/v pools of shape
-    (n_groups, num_pages, page_size, K, hd). Page ids are shared across
-    layers — one logical page allocation covers every layer's pool. Local
-    (sliding-window) layers use the same full-length pages and are masked to
-    the window at attention time; the engine frees pages behind the window
-    when every layer is local (serving/engine/scheduler.py::trim_window).
+    (n_groups, num_pages, K, page_size, hd) — kv-head-major within a page,
+    so one kv head's (page_size, hd) tile is contiguous and is a block the
+    TPU paged-attention kernel can tile (kernels/paged_attention.py). Page
+    ids are shared across layers — one logical page allocation covers every
+    layer's pool. Local (sliding-window) layers use the same full-length
+    pages and are masked to the window at attention time; the engine frees
+    pages behind the window when every layer is local
+    (serving/engine/scheduler.py::trim_window).
 
     ``kv_bits`` (see normalize_kv_bits) selects the HAQ KV-quantized layout
     per sub-layer slot: 16 keeps the bf16 arrays; 8/4 store
-    ``{"q": int8 (n_groups, num_pages, page_size, K, hd_store),
-       "scale": fp32 (n_groups, num_pages, page_size, K)}``
+    ``{"q": int8 (n_groups, num_pages, K, page_size, hd_store),
+       "scale": fp32 (n_groups, num_pages, K, page_size)}``
     with hd_store = hd for int8 and hd//2 for int4 (two codes per byte
     packed along head_dim). Scales are per page slot (token) and per kv
-    head — each physical page carries its own (page_size, K) scale tile, so
+    head — each physical page carries its own (K, page_size) scale tile, so
     quantize-on-write never re-scales resident tokens (see
     serving/kvquant)."""
     if cfg.family not in ("dense", "moe", "vlm"):
@@ -597,17 +600,26 @@ def pool_specs(cfg, num_pages: int, page_size: int, kv_bits=None):
     def kv_spec(b):
         if b == 16:
             return jax.ShapeDtypeStruct(
-                (n_groups, num_pages, page_size, K, hd), jnp.bfloat16)
+                (n_groups, num_pages, K, page_size, hd), jnp.bfloat16)
         hd_store = hd if b == 8 else hd // 2
         return {
             "q": jax.ShapeDtypeStruct(
-                (n_groups, num_pages, page_size, K, hd_store), jnp.int8),
+                (n_groups, num_pages, K, page_size, hd_store), jnp.int8),
             "scale": jax.ShapeDtypeStruct(
-                (n_groups, num_pages, page_size, K), jnp.float32),
+                (n_groups, num_pages, K, page_size), jnp.float32),
         }
 
     return {f"sub{j}": {"k": kv_spec(bits[j]), "v": kv_spec(bits[j])}
             for j in range(P)}
+
+
+def to_page_layout(c, page_size: int):
+    """Full-layout cache (G, T, K, hd), T a multiple of ``page_size`` ->
+    its pages in pool layout (G, T // page_size, K, page_size, hd): logical
+    block i of the sequence becomes page i."""
+    G, T, K, hd = c.shape
+    c = c.reshape(G, T // page_size, page_size, K, hd)
+    return jnp.swapaxes(c, 2, 3)
 
 
 def pool_axes(cfg, kv_bits=None):
@@ -619,8 +631,8 @@ def pool_axes(cfg, kv_bits=None):
     every shard's kv-head slice of that page. The dense decode path's
     ``cache_seq`` fall-through (see distributed.sharding.CANDIDATES) does
     not apply here for the same reason."""
-    kv = ("layer", None, None, "kv_heads", "head_dim")
-    scale = ("layer", None, None, "kv_heads")
+    kv = ("layer", None, "kv_heads", None, "head_dim")
+    scale = ("layer", None, "kv_heads", None)
     return jax.tree.map(
         lambda s: kv if s.ndim == 5 else scale,
         pool_specs(cfg, 2, 2, kv_bits=kv_bits))

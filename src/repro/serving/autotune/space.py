@@ -31,6 +31,12 @@ from repro.serving.engine.admission import AdmissionPolicy, derive_policy
 # sensitivity-gated back-off from serving/kvquant, episodes=0 — no search
 # inside the search)
 KV_POLICIES = ("fp16", "int8", "haq")
+# Page sizes the searcher may pick. Every one must compile on the chip: the
+# paged-attention kernel's K/V block is one kv head's (page, hd) tile and
+# its scale block a page's (K, page) tile — whole array dims at any page
+# size — and tests/test_tpu_compile.py compiles the kernels at each of these
+# for a TPU v5e. A size added here without that compile is not admissible.
+PAGE_SIZES = (8, 16, 32, 64)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,7 +93,7 @@ class ConfigSpace:
         max_devices: int = 1,
         max_batch_cap: int = 8,
         param_bytes: Optional[int] = None,
-        page_sizes: Sequence[int] = (8, 16, 32, 64),
+        page_sizes: Sequence[int] = PAGE_SIZES,
         prefill_chunks: Sequence[int] = (16, 32, 64, 128, 256, 512),
         occupancies: Sequence[float] = (0.25, 0.5, 0.75, 1.0),
         kv_policies: Sequence[str] = KV_POLICIES,

@@ -11,12 +11,15 @@ Page-table layout
 -----------------
 The KV cache is a pool of fixed-size **pages** preallocated once per layer::
 
-    pool["sub{j}"]["k"|"v"] : (n_groups, num_pages, page_size, K, hd) bf16
+    pool["sub{j}"]["k"|"v"] : (n_groups, num_pages, K, page_size, hd) bf16
 
 ``num_pages`` and ``page_size`` are shared by every layer: a single logical
 page allocation covers all layers, so the allocator hands out one list of
 physical page ids per request and the per-layer pools index it identically
 (vLLM's layout, transposed into the repo's scan-stacked group convention).
+Within a page the kv heads are major: one head's (page_size, hd) tile is
+contiguous, which is the block the TPU paged-attention kernel tiles
+(models/transformer.py::pool_specs is the one definition of the layout).
 
 Pages are the unit of **memory and compute**. Allocation is dynamic: a
 request is admitted with only the pages its prompt (plus the first decode
@@ -109,7 +112,7 @@ tick (decode, chunk prefill, whole-prompt prefill, pool span-writer)
 shard_map'd. Per-device layout:
 
     sharded over ``model`` (size N):
-        pool["sub{j}"]["k"|"v"]      (G, num_pages, page, K/N, hd)
+        pool["sub{j}"]["k"|"v"]      (G, num_pages, K/N, page, hd)
         quant pools: both the int codes and the fp32 scale tiles split
         the same way — per-device page bytes really drop Nx, which is how
         ``derive_policy(mesh_model=N)`` finds ~Nx the pool capacity (and

@@ -171,6 +171,7 @@ class PagedKVPool:
         int8/int4 codes + scale tiles in the same fused scatter (garbage
         slots quantize too, harmlessly — they stay behind the mask)."""
         from repro.kernels import ref as kref
+        from repro.models.transformer import to_page_layout
 
         page = self.page_size
         if start % page:
@@ -190,7 +191,7 @@ class PagedKVPool:
                     else:
                         c = jnp.pad(c, ((0, 0), (0, span - Sp))
                                     + ((0, 0),) * (c.ndim - 2))
-                    c = c.reshape(c.shape[0], n, page, *c.shape[2:])
+                    c = to_page_layout(c, page)         # (G, n, K, page, hd)
                     if isinstance(pool_leaf, dict):     # quantized slot
                         bits = kref.kv_bits_of(pool_leaf["q"], c.shape[-1])
                         q, scale = kref.quantize_kv(c, bits)

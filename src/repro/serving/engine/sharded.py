@@ -40,9 +40,9 @@ mesh.shape["model"] == 0`` (``validate_mesh``) and keeps pages whole.
 
 Pool / page-table layout per device (mesh ``model=N``)::
 
-    pool["sub{j}"]["k"|"v"]         (G, num_pages, page, K/N, hd)
-    quantized: {"q":   (G, num_pages, page, K/N, hd_store) int8,
-                "scale":(G, num_pages, page, K/N) f32}     # scale tiles
+    pool["sub{j}"]["k"|"v"]         (G, num_pages, K/N, page, hd)
+    quantized: {"q":   (G, num_pages, K/N, page, hd_store) int8,
+                "scale":(G, num_pages, K/N, page) f32}     # scale tiles
     page_table, positions, tokens   replicated (host-built every tick)
 
 Every device holds a 1/N kv-head slice of EVERY page, so one host-side page
@@ -55,7 +55,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed import sharding as shlib
@@ -189,7 +188,10 @@ class SpmdEngine:
 
     All jits share one contract: page table / tokens / positions replicated,
     params per ``specs_for`` (gathered at use where a contraction would
-    split), pool sharded on ``kv_heads`` over ``model``.
+    split), pool sharded on ``kv_heads`` over ``model``. Every shard_map
+    runs with ``check_vma=False``: the Pallas paged-attention call inside
+    the bodies carries no varying-mesh-axes rule, and replicated outputs
+    are replicated by construction (their inputs are all-gathered first).
     """
 
     def __init__(self, model, mesh: Mesh, *, kv_bits=None,
@@ -242,10 +244,10 @@ class SpmdEngine:
             return model.decode_step_paged(self.gathered(p), pool, pt, tok,
                                            pos, dot=dot, kernel=kernel)
 
-        return jax.jit(shard_map(
-            body, self.mesh,
+        return jax.jit(jax.shard_map(
+            body, mesh=self.mesh,
             in_specs=(self.param_pspecs, self.pool_pspecs, P(), P(), P()),
-            out_specs=(P(), self.pool_pspecs), check_rep=False),
+            out_specs=(P(), self.pool_pspecs), check_vma=False),
             donate_argnums=(1,))
 
     def jit_prefill_chunk(self):
@@ -256,10 +258,10 @@ class SpmdEngine:
                                              toks, pos, dot=dot,
                                              kernel=kernel)
 
-        return jax.jit(shard_map(
-            body, self.mesh,
+        return jax.jit(jax.shard_map(
+            body, mesh=self.mesh,
             in_specs=(self.param_pspecs, self.pool_pspecs, P(), P(), P()),
-            out_specs=(P(), self.pool_pspecs), check_rep=False),
+            out_specs=(P(), self.pool_pspecs), check_vma=False),
             donate_argnums=(1,))
 
     def jit_unembed_row(self):
@@ -269,9 +271,9 @@ class SpmdEngine:
             h = jnp.take_along_axis(h, idx.reshape(1, 1, 1), axis=1)
             return model.unembed(self.gathered(p), h, dot=dot)
 
-        return jax.jit(shard_map(
-            body, self.mesh, in_specs=(self.param_pspecs, P(), P()),
-            out_specs=P(), check_rep=False))
+        return jax.jit(jax.shard_map(
+            body, mesh=self.mesh, in_specs=(self.param_pspecs, P(), P()),
+            out_specs=P(), check_vma=False))
 
     def make_prefill(self, prefill_fn):
         """Whole-prompt (non-chunked) bucketed prefill: logits replicated,
@@ -281,10 +283,10 @@ class SpmdEngine:
         cache = self.model.cache_specs(1, 2)
         cspecs = self._cache_pspecs(
             {k: v for k, v in cache.items() if k.startswith("sub")})
-        return jax.jit(shard_map(
-            prefill_fn, self.mesh,
+        return jax.jit(jax.shard_map(
+            prefill_fn, mesh=self.mesh,
             in_specs=(self.param_pspecs, P(), P()),
-            out_specs=(P(), cspecs), check_rep=False))
+            out_specs=(P(), cspecs), check_vma=False))
 
     def jit_pool_writer(self, write_fn, cache):
         """shard_map'd span writer for one (n_pages, cache_len) shape:
@@ -293,10 +295,10 @@ class SpmdEngine:
         page ids is purely local. Donation rides the engine's JitLRU entry
         exactly like the unsharded writer."""
         cspecs = self._cache_pspecs(cache)
-        return jax.jit(shard_map(
-            write_fn, self.mesh,
+        return jax.jit(jax.shard_map(
+            write_fn, mesh=self.mesh,
             in_specs=(self.pool_pspecs, cspecs, P()),
-            out_specs=self.pool_pspecs, check_rep=False),
+            out_specs=self.pool_pspecs, check_vma=False),
             donate_argnums=(0,))
 
     # ------------------------------------------------------------ describe --

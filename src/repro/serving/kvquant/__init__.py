@@ -12,25 +12,25 @@ Quantized page layout
 ---------------------
 The fp pool stores, per sub-layer slot ``sub{j}`` (see serving/engine)::
 
-    pool["sub{j}"]["k"|"v"] : (n_groups, num_pages, page_size, K, hd) bf16
+    pool["sub{j}"]["k"|"v"] : (n_groups, num_pages, K, page_size, hd) bf16
 
 A slot quantized to ``bits`` ∈ {8, 4} stores instead::
 
     pool["sub{j}"]["k"|"v"] = {
-        "q":     (n_groups, num_pages, page_size, K, hd_store) int8,
-        "scale": (n_groups, num_pages, page_size, K)            fp32,
+        "q":     (n_groups, num_pages, K, page_size, hd_store) int8,
+        "scale": (n_groups, num_pages, K, page_size)            fp32,
     }
 
 with ``hd_store = hd`` for int8 and ``hd // 2`` for int4 — int4 packs two
-codes per byte along head_dim (element ``2i`` in the low nibble, ``2i+1``
-in the high; kernels/ref.py::pack_int4_hd). The stored bitwidth is encoded
-by the shape itself (``kv_bits_of``), so it stays static under jit and no
-side-channel bits tag rides the pytree.
+codes per byte along head_dim (element ``i`` in the low nibble, ``i +
+hd/2`` in the high; kernels/ref.py::pack_int4_hd). The stored bitwidth is
+encoded by the shape itself (``kv_bits_of``), so it stays static under jit
+and no side-channel bits tag rides the pytree.
 
 Scale placement
 ---------------
 Scales are symmetric per page *slot* (token) and per kv head: each physical
-page carries its own ``(page_size, K)`` fp32 scale tile next to its codes.
+page carries its own ``(K, page_size)`` fp32 scale tile next to its codes.
 Per-token granularity is what makes quantize-on-write exact bookkeeping:
 prefill scatters whole quantized pages, decode writes one ``(K, hd)`` token
 into ``page_table[b, pos // page]`` slot ``pos % page`` — and neither ever
@@ -45,7 +45,8 @@ At attention time the scale tiles ride the same scalar-prefetched
 page-table walk as their pages (kernels/paged_attention.py::
 paged_attention_quant_fwd on TPU, kernels/ref.py::paged_attention_quant_ref
 as the pure-JAX twin): dequant happens inside the online-softmax block
-loop, one (page, hd) fp tile in VMEM at a time.
+loop — the kernel folds each token's scale into its score and probability
+columns, so the only fp tile is one (page, hd) block in VMEM.
 
 Bit policy
 ----------

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models.transformer import to_page_layout
 from repro.serving.engine.pool import quiet_donation
 from repro.serving.kvquant.quantize import quantize_pool
 
@@ -34,8 +35,7 @@ def _identity_pool(cache, max_len: int, page: int):
     def to_pages(c):                     # (G, 1, S, K, hd) full layout
         pad = [(0, 0)] * c.ndim
         pad[2] = (0, span - c.shape[2])
-        c = jnp.pad(c, pad)[:, 0]
-        c = c.reshape(c.shape[0], ppseq, page, *c.shape[2:])
+        c = to_page_layout(jnp.pad(c, pad)[:, 0], page)
         pool = jnp.zeros((c.shape[0], ppseq + 1) + c.shape[2:], c.dtype)
         return pool.at[:, 1:].set(c)
 

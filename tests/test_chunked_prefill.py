@@ -38,8 +38,8 @@ def _prefill_case(B, H, K, hd, page, n_blocks, Sq, *, num_pages=11, seed=0):
     page-table tails on the poisoned scratch page 0."""
     rng = np.random.default_rng(seed)
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    pool_k = jax.random.normal(ks[0], (num_pages, page, K, hd), jnp.float32)
-    pool_v = jax.random.normal(ks[1], (num_pages, page, K, hd), jnp.float32)
+    pool_k = jax.random.normal(ks[0], (num_pages, K, page, hd), jnp.float32)
+    pool_v = jax.random.normal(ks[1], (num_pages, K, page, hd), jnp.float32)
     pool_k = pool_k.at[0].set(37.0)          # a masking bug reads these
     pool_v = pool_v.at[0].set(-53.0)
     q = jax.random.normal(ks[2], (B, Sq, H, hd), jnp.float32)
@@ -102,8 +102,8 @@ def test_prefill_chunk_overruns_page_table():
     scores at a clamped offset, clobbering the last real block."""
     page, n_blocks, Sq = 16, 6, 64          # chunk spans blocks 4..7 of 6
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    pk = jax.random.normal(ks[0], (9, page, 2, 32), jnp.float32)
-    pv = jax.random.normal(ks[1], (9, page, 2, 32), jnp.float32)
+    pk = jax.random.normal(ks[0], (9, 2, page, 32), jnp.float32)
+    pv = jax.random.normal(ks[1], (9, 2, page, 32), jnp.float32)
     q = jax.random.normal(ks[2], (1, Sq, 4, 32), jnp.float32)
     pt = jnp.asarray(np.arange(1, n_blocks + 1, dtype=np.int32)[None])
     pos = jnp.asarray([64], jnp.int32)      # real rows: qpos 64..95
@@ -285,7 +285,7 @@ def test_write_prefill_span_offsets(gemma_tiny):
 
 # ------------------------------------------------------- jaxpr guarantee ---
 def _iter_avals(jaxpr):
-    from jax.core import Jaxpr
+    from jax.extend.core import Jaxpr
     for eqn in jaxpr.eqns:
         for v in eqn.outvars:
             yield v.aval
@@ -302,7 +302,7 @@ def _iter_avals(jaxpr):
 def test_chunked_prefill_never_builds_dense_prompt_kv(gemma_tiny):
     """The jitted chunk forward contains no chronological dense prompt KV
     intermediate — neither the flat (1, max_pages*page, K, hd) gather nor
-    its pre-reshape (1, max_pages, page, K, hd) form."""
+    its pre-reshape (1, max_pages, K|page, page|K, hd) forms."""
     model, params = gemma_tiny
     pol = _policy()
     maxp, page = pol.pages_per_seq, pol.page_size
@@ -314,14 +314,15 @@ def test_chunked_prefill_never_builds_dense_prompt_kv(gemma_tiny):
     jaxpr = jax.make_jaxpr(
         lambda *a: model.prefill_chunk_paged(*a))(params, pool, pt, toks,
                                                   pos)
-    banned = {(1, maxp * page, K, hd), (1, maxp, page, K, hd)}
+    banned = {(1, maxp * page, K, hd), (1, maxp, K, page, hd),
+              (1, maxp, page, K, hd)}
     dense = [a for a in _iter_avals(jaxpr.jaxpr)
              if getattr(a, "shape", None) in banned]
     assert not dense, dense
     # positive control: the dense oracle must trip the same scan
     q = jnp.zeros((1, pol.prefill_chunk, model.cfg.num_heads, hd),
                   jnp.bfloat16)
-    pk = jax.tree.leaves(pool)[0][0]          # (P, page, K, hd)
+    pk = jax.tree.leaves(pool)[0][0]          # (P, K, page, hd)
     jx = jax.make_jaxpr(
         lambda *a: ref.paged_prefill_dense_ref(*a))(q, pk, pk, pt, pos)
     hits = [a for a in _iter_avals(jx.jaxpr)

@@ -337,7 +337,7 @@ def test_engine_lazy_beats_upfront_admission(gemma_tiny):
 
 
 def _iter_avals(jaxpr):
-    from jax.core import Jaxpr
+    from jax.extend.core import Jaxpr
     for eqn in jaxpr.eqns:
         for v in eqn.outvars:
             yield v.aval
@@ -353,8 +353,8 @@ def _iter_avals(jaxpr):
 
 def test_paged_decode_never_builds_dense_kv(gemma_tiny):
     """Acceptance: the jitted decode step contains no chronological
-    (B, max_pages*page, K, hd) dense KV intermediate — neither flat nor in
-    its pre-reshape (B, max_pages, page, K, hd) form."""
+    (B, max_pages*page, K, hd) dense KV intermediate — neither flat nor
+    in its pre-reshape (B, max_pages, K|page, page|K, hd) forms."""
     model, params = gemma_tiny
     pol = _policy()
     B, maxp, page = pol.max_batch, pol.pages_per_seq, pol.page_size
@@ -365,14 +365,15 @@ def test_paged_decode_never_builds_dense_kv(gemma_tiny):
     pos = jnp.zeros((B,), jnp.int32)
     jaxpr = jax.make_jaxpr(
         lambda *a: model.decode_step_paged(*a))(params, pool, pt, tok, pos)
-    banned = {(B, maxp * page, K, hd), (B, maxp, page, K, hd)}
+    banned = {(B, maxp * page, K, hd), (B, maxp, K, page, hd),
+              (B, maxp, page, K, hd)}
     dense = [a for a in _iter_avals(jaxpr.jaxpr)
              if getattr(a, "shape", None) in banned]
     assert not dense, dense
     # positive control: the same scan flags the dense-gather oracle
     from repro.kernels.ref import paged_attention_dense_ref
     q = jnp.zeros((B, model.cfg.num_heads, hd), jnp.bfloat16)
-    pk = jax.tree.leaves(pool)[0][0]          # (P, page, K, hd)
+    pk = jax.tree.leaves(pool)[0][0]          # (P, K, page, hd)
     jx = jax.make_jaxpr(
         lambda *a: paged_attention_dense_ref(*a))(q, pk, pk, pt, pos)
     hits = [a for a in _iter_avals(jx.jaxpr)

@@ -101,9 +101,9 @@ def _paged_case(B, H, K, hd, page, n_blocks, *, num_pages=11, seed=0,
     import numpy as np
     rng = np.random.default_rng(seed)
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    pool_k = jax.random.normal(ks[0], (num_pages, page, K, hd),
+    pool_k = jax.random.normal(ks[0], (num_pages, K, page, hd),
                                jnp.float32).astype(dtype)
-    pool_v = jax.random.normal(ks[1], (num_pages, page, K, hd),
+    pool_v = jax.random.normal(ks[1], (num_pages, K, page, hd),
                                jnp.float32).astype(dtype)
     # poison the scratch page: a masking bug shows up as a huge error
     pool_k = pool_k.at[0].set(37.0)

@@ -15,7 +15,7 @@ from repro.kernels import ops, ref
 from repro.kernels import paged_attention as pa
 from repro.launch.serve import generate
 from repro.models.api import build_model
-from repro.models.transformer import normalize_kv_bits
+from repro.models.transformer import normalize_kv_bits, to_page_layout
 from repro.serving import kvquant
 from repro.serving.engine import (AdmissionPolicy, Engine, PageAllocator,
                                   Request, Scheduler, derive_policy)
@@ -68,15 +68,15 @@ def test_int4_pack_roundtrip_exact():
 @pytest.mark.parametrize("granularity", ["token", "page"])
 def test_kv_roundtrip_bounded(bits, hd, granularity):
     x = jax.random.normal(jax.random.PRNGKey(bits + hd),
-                          (3, 8, 2, hd), jnp.float32) * 2.0
+                          (3, 2, 8, hd), jnp.float32) * 2.0  # (P, K, page)
     q, scale = kvquant.quantize_kv(x, bits, granularity=granularity)
     deq = kvquant.dequantize_kv(q, scale, bits, granularity=granularity)
     bound = scale[..., None] if granularity == "token" \
-        else scale[..., None, :, None]
+        else scale[..., None, None]
     assert bool(jnp.all(jnp.abs(deq - x) <= bound * 0.5 + 1e-6))
-    # int4 really halves storage; scale tile is per (slot, head) or (head,)
+    # int4 really halves storage; scale tile is per (head, slot) or (head,)
     assert q.shape[-1] == (hd if bits == 8 else hd // 2)
-    assert scale.shape == ((3, 8, 2) if granularity == "token" else (3, 2))
+    assert scale.shape == ((3, 2, 8) if granularity == "token" else (3, 2))
 
 
 def test_kv_roundtrip_property():
@@ -95,12 +95,12 @@ def test_kv_roundtrip_property():
            seed=st.integers(0, 50), amp=st.floats(1e-3, 100.0))
     def check(bits, hd, gran, slots, heads, seed, amp):
         x = jnp.asarray(np.random.default_rng(seed)
-                        .standard_normal((slots, heads, hd)) * amp,
+                        .standard_normal((heads, slots, hd)) * amp,
                         jnp.float32)
         q, scale = kvquant.quantize_kv(x, bits, granularity=gran)
         deq = kvquant.dequantize_kv(q, scale, bits, granularity=gran)
         bound = scale[..., None] if gran == "token" \
-            else scale[..., None, :, None]
+            else scale[..., None, None]
         assert bool(jnp.all(jnp.abs(deq - x) <= bound * 0.5
                             + 1e-6 * amp + 1e-9))
         # monotone: int8 reconstruction never worse than int4
@@ -126,8 +126,8 @@ def _quant_case(B, H, K, hd, page, n_blocks, bits, *, num_pages=11, seed=0):
     scales poisoned so any leak past the mask explodes the error."""
     rng = np.random.default_rng(seed)
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    pool_k = jax.random.normal(ks[0], (num_pages, page, K, hd), jnp.float32)
-    pool_v = jax.random.normal(ks[1], (num_pages, page, K, hd), jnp.float32)
+    pool_k = jax.random.normal(ks[0], (num_pages, K, page, hd), jnp.float32)
+    pool_v = jax.random.normal(ks[1], (num_pages, K, page, hd), jnp.float32)
     q = jax.random.normal(ks[2], (B, H, hd), jnp.float32)
     kq, ksc = ref.quantize_kv(pool_k, bits)
     vq, vsc = ref.quantize_kv(pool_v, bits)
@@ -211,10 +211,10 @@ def test_pool_specs_quantized_layout(gemma_tiny):
     G = cfg.num_layers // 2
     specs = model.pool_specs(9, 16, kv_bits=(4, 8))
     s4, s8 = specs["sub0"]["k"], specs["sub1"]["k"]
-    assert s4["q"].shape == (G, 9, 16, K, hd // 2)
-    assert s8["q"].shape == (G, 9, 16, K, hd)
+    assert s4["q"].shape == (G, 9, K, 16, hd // 2)
+    assert s8["q"].shape == (G, 9, K, 16, hd)
     assert s4["q"].dtype == jnp.int8
-    assert s4["scale"].shape == (G, 9, 16, K)
+    assert s4["scale"].shape == (G, 9, K, 16)
     assert s4["scale"].dtype == jnp.float32
     # fp slots keep the bf16 layout; all-16 collapses to it entirely
     mixed = model.pool_specs(9, 16, kv_bits={"sub0": 8})
@@ -302,7 +302,7 @@ def test_write_prefill_quantizes_on_write(gemma_tiny):
     kv.write_prefill(cache, pages)
     for j, bits in ((0, 4), (1, 8)):
         c = cache[f"sub{j}"]["k"][:, 0]                # (G, 32, K, hd)
-        c = c.reshape(c.shape[0], 2, 16, *c.shape[2:]).astype(jnp.float32)
+        c = to_page_layout(c, 16).astype(jnp.float32)  # (G, 2, K, 16, hd)
         _, want_s = kvquant.quantize_kv(c, bits)
         got = kv.pool[f"sub{j}"]["k"]
         for i, p in enumerate(pages):
@@ -441,7 +441,7 @@ def test_engine_window_trim_occupancy_drops_outputs_exact():
 
 # ------------------------------------------------------------ jaxpr scan --
 def _iter_avals(jaxpr):
-    from jax.core import Jaxpr
+    from jax.extend.core import Jaxpr
     for eqn in jaxpr.eqns:
         for v in eqn.outvars:
             yield v.aval
@@ -459,7 +459,7 @@ def _iter_avals(jaxpr):
 def test_quant_decode_never_builds_dense_fp_kv(gemma_tiny, kv_bits):
     """Acceptance: the quantized decode step materializes neither the
     chronological dense KV view nor a full-pool fp dequant — the only fp
-    KV ever built is the per-block (B, page, K, hd) tile inside the walk."""
+    KV ever built is the per-block (B, K, page, hd) tile inside the walk."""
     model, params = gemma_tiny
     pol = _policy()
     B, maxp, page = pol.max_batch, pol.pages_per_seq, pol.page_size
@@ -472,8 +472,8 @@ def test_quant_decode_never_builds_dense_fp_kv(gemma_tiny, kv_bits):
     pos = jnp.zeros((B,), jnp.int32)
     jaxpr = jax.make_jaxpr(
         lambda *a: model.decode_step_paged(*a))(params, pool, pt, tok, pos)
-    banned = {(B, maxp * page, K, hd), (B, maxp, page, K, hd),
-              (P, page, K, hd), (G, P, page, K, hd)}
+    banned = {(B, maxp * page, K, hd), (B, maxp, K, page, hd),
+              (P, K, page, hd), (G, P, K, page, hd)}
     dense = [a for a in _iter_avals(jaxpr.jaxpr)
              if getattr(a, "shape", None) in banned
              and jnp.issubdtype(a.dtype, jnp.inexact)]

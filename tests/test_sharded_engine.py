@@ -136,7 +136,7 @@ def test_pool_is_sharded_on_kv_heads(gemma_tiny):
         K = model.cfg.num_kv_heads
         for leaf in jax.tree.leaves(eng.kv.pool):
             local = leaf.sharding.shard_shape(leaf.shape)
-            assert local[3] == K // 2, (leaf.shape, local)
+            assert local[2] == K // 2, (leaf.shape, local)
         # replicated decode inputs, sharded params at rest: param bytes per
         # device strictly below the full footprint
         full = sum(x.size * x.dtype.itemsize
@@ -165,7 +165,8 @@ def test_sharded_decode_never_builds_dense_kv(gemma_tiny):
         lambda *a: eng._decode(*a))(eng.params, eng.kv.pool, pt, tok, pos)
     banned = set()
     for k in (K, K // 2):
-        banned |= {(B, maxp * page, k, hd), (B, maxp, page, k, hd)}
+        banned |= {(B, maxp * page, k, hd), (B, maxp, k, page, hd),
+                   (B, maxp, page, k, hd)}
     dense = [a for a in _iter_avals(jaxpr.jaxpr)
              if getattr(a, "shape", None) in banned]
     assert not dense, dense
