@@ -144,10 +144,14 @@ def _paged_kernel(pt_ref, pos_ref, q_ref, *refs, page, Sq, G, bits, window,
 
 
 def _paged_call(q, pool_k, k_scale, pool_v, v_scale, page_table, positions,
-                *, window, cap, interpret):
+                *, name, window, cap, interpret):
     """Shared pallas_call for every public entry point. q (B, Sq, H, hd);
     pool_k/v (P, K, page, hd_store); k_scale/v_scale (P, K, page) fp32 for
-    quantized pools, None for bf16 ones. Returns (B, Sq, H, hd)."""
+    quantized pools, None for bf16 ones. Returns (B, Sq, H, hd).
+
+    ``name`` becomes the kernel's HLO instruction name (the custom call's
+    ``kernel_name``; without it the instruction takes the name of the
+    enclosing jitted function), which is how a device trace finds it."""
     B, Sq, H, hd = q.shape
     _, K, page, hd_store = pool_k.shape
     bits = 16 if k_scale is None else ref.kv_bits_of(pool_k, hd)
@@ -191,6 +195,7 @@ def _paged_call(q, pool_k, k_scale, pool_v, v_scale, page_table, positions,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, rows, hd), q.dtype),
         interpret=interpret,
+        name=name,
     )(page_table, positions, *operands)
     return jnp.moveaxis(out.reshape(B, K, Sq, G, hd), 2, 1).reshape(
         B, Sq, H, hd)
@@ -207,8 +212,8 @@ def paged_attention_fwd(q, pool_k, pool_v, page_table, positions, *,
     positions (B,) int32 position of each query token. H = K*G.
     Returns (B, H, hd) in q.dtype."""
     return _paged_call(q[:, None], pool_k, None, pool_v, None, page_table,
-                       positions, window=window, cap=cap,
-                       interpret=interpret)[:, 0]
+                       positions, name="paged_attention_fwd", window=window,
+                       cap=cap, interpret=interpret)[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -222,8 +227,9 @@ def paged_attention_quant_fwd(q, pool_k, k_scale, pool_v, v_scale,
     (P, K, page) fp32 per-token per-head scales; page_table/positions as in
     paged_attention_fwd. Returns (B, H, hd) in q.dtype."""
     return _paged_call(q[:, None], pool_k, k_scale, pool_v, v_scale,
-                       page_table, positions, window=window, cap=cap,
-                       interpret=interpret)[:, 0]
+                       page_table, positions,
+                       name="paged_attention_quant_fwd", window=window,
+                       cap=cap, interpret=interpret)[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -239,7 +245,8 @@ def paged_prefill_fwd(q, pool_k, pool_v, page_table, positions, *,
     the resident prompt prefix plus the chunk itself. Returns
     (B, Sq, H, hd) in q.dtype."""
     return _paged_call(q, pool_k, None, pool_v, None, page_table, positions,
-                       window=window, cap=cap, interpret=interpret)
+                       name="paged_prefill_fwd", window=window, cap=cap,
+                       interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -250,5 +257,5 @@ def paged_prefill_quant_fwd(q, pool_k, k_scale, pool_v, v_scale,
     (layouts as in paged_attention_quant_fwd, queries and positions as in
     paged_prefill_fwd). Returns (B, Sq, H, hd) in q.dtype."""
     return _paged_call(q, pool_k, k_scale, pool_v, v_scale, page_table,
-                       positions, window=window, cap=cap,
-                       interpret=interpret)
+                       positions, name="paged_prefill_quant_fwd",
+                       window=window, cap=cap, interpret=interpret)

@@ -142,9 +142,9 @@ scripts/check_bench_regression.py sharded floors).
 
 Observability (serving/telemetry)
 ---------------------------------
-Every engine owns a `Telemetry` recorder (in-memory, jax-free, no-op
-export sink by default — the disabled path costs a few dataclass appends
-per tick, and greedy outputs are untouched). Two event streams:
+Every engine owns a `Telemetry` recorder (in-memory and jax-free — it
+costs a few dataclass appends per tick, and greedy outputs are
+untouched). Two event streams, and phase spans:
 
 **Tick events** — one per jitted dispatch, ``kind`` in {``prefill``,
 ``chunk``, ``decode``}::
@@ -171,12 +171,44 @@ Spans yield real TTFT / queue-wait / stall; ``Engine.stall_log`` and
 ``Engine.first_token_s`` survive as thin views over them (a preempted
 request keeps its first served token's TTFT).
 
-The metrics registry (``engine.telemetry.metrics``) rolls both streams
+**Phase spans** — the host loop opens a named span around each of its
+phases through ``telemetry.span(name)``, by default a
+``jax.profiler.TraceAnnotation``: under a profiler session it lands in
+the same trace as the device ops, on their clock; with none it is a
+near no-op. The tree, with the benchmark metric that reads it::
+
+    engine.step                   Engine.step, whole     engine.schedule_ms
+      engine.admit                scheduler.admit and the admission loop
+        engine.prefill            whole-prompt prefill (unchunked mode)
+          engine.prefill.dispatch / engine.prefill.fence
+      engine.chunk                one per prompt chunk
+        engine.chunk.prepare      host token / page-table arrays
+        engine.chunk.dispatch     input transfer + the jit call returning
+        engine.chunk.fence        block_until_ready(hidden)
+        engine.chunk.first_token  final chunk: unembed row, pull, sample
+      engine.decode               the decode tick
+        engine.decode.grow        trim / growth / preemption loop
+        engine.decode.prepare     tokens, positions, page table
+        engine.decode.dispatch    input transfer + the jit call returning
+        engine.decode.fence       block_until_ready(logits)
+        engine.decode.sample      logits to the host + a token per row
+                                  (engine.sample_ms,
+                                  device.idle_sample_share)
+      engine.finish               one per finished request
+      engine.gauges               the per-step gauges
+
+``engine.schedule_ms`` is a step's self time: ``engine.step`` less its
+``*.dispatch``, ``*.fence``, ``engine.decode.sample`` and
+``engine.chunk.first_token`` spans (bench/engine_spans.py). No span is
+opened per batch row, so a step opens as many spans at any
+``max_batch``.
+
+The metrics registry (``engine.telemetry.metrics``) rolls the streams
 into counters/gauges/histograms: ``ticks.*``, ``tokens.*``,
-``pool.free`` (min = low-water mark), ``pool.occupancy`` /
-``.fragmentation``, ``queue.depth``, ``preemptions``,
+``pool.free`` (min = low-water mark), ``pool.occupancy`` (fragmentation
+is 1 - occupancy), ``pool.min_free``, ``preemptions``,
 ``jit.*.hits/misses/cache_size`` (steady-state decode must not
-retrace), ``tick.*.measured_s`` / ``.rel_err`` histograms.
+retrace), ``tick.*.measured_s`` and ``stall.measured_s`` histograms.
 
 Exports: ``telemetry.write_chrome_trace(engine.telemetry, path)`` emits
 Chrome trace-event JSON — open it at https://ui.perfetto.dev (or

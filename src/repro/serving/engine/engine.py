@@ -57,7 +57,11 @@ budgets, with the roofline's predicted stall recorded alongside in
 ``telemetry.stalls``) and ``first_token_s`` (per-request TTFT) survive
 as thin views over that record; both feed the long-prompt section of
 benchmarks/bench_engine_throughput.py, and ``telemetry.calibrate``
-turns the tick trace into per-kind roofline scale factors.
+turns the tick trace into per-kind roofline scale factors. Each phase of
+``step()`` (admission, prompt chunk, decode growth / input prep /
+dispatch / fence / sampling, finish, gauges) runs inside a named
+``telemetry.span`` — the tree is in serving/engine/__init__.py — so a
+profiler trace puts every device idle gap down to a host phase.
 """
 from __future__ import annotations
 
@@ -279,41 +283,44 @@ class Engine:
         moment they finish — before the decode tick's growth phase — so
         their pages backfill growth instead of tempting the preemption
         picker."""
-        self.telemetry.start_clock()
-        self._step_idx += 1
-        self._step_admitted = 0
-        out: List[int] = []
-        ready_before = len(self.scheduler.decode_ready())
-        stall_pred = 0.0
-        t_prefill = time.monotonic()
-        for seq in self.scheduler.admit(now):
-            self.stats["admitted"] += 1
-            self._step_admitted += 1
-            if not self.chunked:
-                stall_pred += self._run_prefill(seq)
-                if seq.is_done():
+        with self.telemetry.span("engine.step"):
+            self.telemetry.start_clock()
+            self._step_idx += 1
+            self._step_admitted = 0
+            out: List[int] = []
+            ready_before = len(self.scheduler.decode_ready())
+            stall_pred = 0.0
+            t_prefill = time.monotonic()
+            with self.telemetry.span("engine.admit"):
+                for seq in self.scheduler.admit(now):
+                    self.stats["admitted"] += 1
+                    self._step_admitted += 1
+                    if not self.chunked:
+                        stall_pred += self._run_prefill(seq)
+                        if seq.is_done():
+                            out.append(self._finish(seq))
+            if self.chunked:
+                for seq in self.scheduler.prefill_pending():
+                    stall_pred += self._run_prefill_chunk(seq)
+                    if seq.prefill_done and seq.is_done():
+                        out.append(self._finish(seq))
+            t_prefill = time.monotonic() - t_prefill
+            live = self.scheduler.decode_ready()
+            if live:
+                finished: List[ActiveSeq] = []
+                ticks_before = self.stats["decode_ticks"]
+                self._decode_tick(live, finished)
+                if self.stats["decode_ticks"] > ticks_before \
+                        and ready_before:
+                    # per-decode-tick stall: seconds this tick's already-
+                    # ready sequences waited on prefill work (0.0 when none
+                    # ran) — the quantity prefill_stall_factor budgets per
+                    # tick, recorded next to the roofline's prediction for
+                    # the same prefill work so calibration sees both sides.
+                    self.telemetry.stall(t_prefill, stall_pred)
+                for seq in finished:
                     out.append(self._finish(seq))
-        if self.chunked:
-            for seq in self.scheduler.prefill_pending():
-                stall_pred += self._run_prefill_chunk(seq)
-                if seq.prefill_done and seq.is_done():
-                    out.append(self._finish(seq))
-        t_prefill = time.monotonic() - t_prefill
-        live = self.scheduler.decode_ready()
-        if live:
-            finished: List[ActiveSeq] = []
-            ticks_before = self.stats["decode_ticks"]
-            self._decode_tick(live, finished)
-            if self.stats["decode_ticks"] > ticks_before and ready_before:
-                # per-decode-tick stall: seconds this tick's already-ready
-                # sequences waited on prefill work (0.0 when none ran) —
-                # the quantity prefill_stall_factor budgets per tick,
-                # recorded next to the roofline's prediction for the same
-                # prefill work so calibration sees both sides.
-                self.telemetry.stall(t_prefill, stall_pred)
-            for seq in finished:
-                out.append(self._finish(seq))
-        self._update_gauges()
+            self._update_gauges()
         return out
 
     # ---------------------------------------------------- telemetry emit --
@@ -347,42 +354,42 @@ class Engine:
             **self._tick_deltas()))
 
     def _update_gauges(self) -> None:
-        """Per-step gauges that aren't per-tick deltas: pool occupancy /
-        fragmentation (token-granular — allocated pages may be mostly
-        empty while sequences are young) and the jit-cache hit/miss
-        counters (satellite: JitLRU observability — steady-state decode
-        must not retrace)."""
-        m = self.telemetry.metrics
-        a = self.kv.allocator
-        page = a.page_size
-        used = 0
-        for seq in self.scheduler.active.values():
-            live_pages = sum(p != 0 for p in seq.pages)
-            trimmed = len(seq.pages) - live_pages
-            used += max(min(seq.pos - trimmed * page, live_pages * page), 0)
-        cap = a.num_allocated * page
-        occ = used / cap if cap else 0.0
-        m.gauge("pool.occupancy").set(occ)
-        m.gauge("pool.fragmentation").set(1.0 - occ if cap else 0.0)
-        m.gauge("pool.min_free").set(a.min_free)
-        m.gauge("jit.prefill.hits").set(self._prefill_jits.hits)
-        m.gauge("jit.prefill.misses").set(self._prefill_jits.misses)
-        m.gauge("jit.pool_writer.hits").set(self.kv._write_jit.hits)
-        m.gauge("jit.pool_writer.misses").set(self.kv._write_jit.misses)
-        # the once-jitted closures: retrace count straight from jax (a
-        # steady-state engine holds these at 1)
-        for name, fn in (("decode", self._decode),
-                         ("chunk", self._chunk_prefill)):
-            size = getattr(fn, "_cache_size", lambda: -1)()
-            m.gauge(f"jit.{name}.cache_size").set(size)
+        """Per-step gauges that aren't per-tick deltas: pool occupancy
+        (token-granular — allocated pages may be mostly empty while
+        sequences are young; fragmentation is 1 - occupancy) and the
+        jit-cache hit/miss counters (satellite: JitLRU observability —
+        steady-state decode must not retrace). O(active sequences)."""
+        with self.telemetry.span("engine.gauges"):
+            m = self.telemetry.metrics
+            a = self.kv.allocator
+            page = a.page_size
+            used = 0
+            for seq in self.scheduler.active.values():
+                live_pages = len(seq.pages) - seq.trimmed
+                used += max(min(seq.pos - seq.trimmed * page,
+                                live_pages * page), 0)
+            cap = a.num_allocated * page
+            m.gauge("pool.occupancy").set(used / cap if cap else 0.0)
+            m.gauge("pool.min_free").set(a.min_free)
+            m.gauge("jit.prefill.hits").set(self._prefill_jits.hits)
+            m.gauge("jit.prefill.misses").set(self._prefill_jits.misses)
+            m.gauge("jit.pool_writer.hits").set(self.kv._write_jit.hits)
+            m.gauge("jit.pool_writer.misses").set(self.kv._write_jit.misses)
+            # the once-jitted closures: retrace count straight from jax (a
+            # steady-state engine holds these at 1)
+            for name, fn in (("decode", self._decode),
+                             ("chunk", self._chunk_prefill)):
+                size = getattr(fn, "_cache_size", lambda: -1)()
+                m.gauge(f"jit.{name}.cache_size").set(size)
 
     def _finish(self, seq: ActiveSeq) -> int:
-        self.telemetry.seq_event(seq.req.rid, "finish",
-                                 generated=len(seq.generated))
-        self.scheduler.release(seq)
-        self._outputs[seq.req.rid] = np.concatenate(
-            [np.asarray(seq.req.prompt, np.int32),
-             np.asarray(seq.generated, np.int32)])
+        with self.telemetry.span("engine.finish"):
+            self.telemetry.seq_event(seq.req.rid, "finish",
+                                     generated=len(seq.generated))
+            self.scheduler.release(seq)
+            self._outputs[seq.req.rid] = np.concatenate(
+                [np.asarray(seq.req.prompt, np.int32),
+                 np.asarray(seq.generated, np.int32)])
         return seq.req.rid
 
     def _first_token(self, seq: ActiveSeq, logits_row) -> None:
@@ -405,26 +412,31 @@ class Engine:
         decode for its full prefill latency — kept as the pre-chunking
         baseline the bench compares against. Returns the roofline's
         predicted seconds for the dispatch (the step's stall budget)."""
-        prompt = np.asarray(seq.req.prompt, np.int32)
-        S = len(prompt)
-        chunk = self.policy.prefill_chunk
-        Sp = -(-S // chunk) * chunk
-        toks = np.zeros((1, Sp), np.int32)
-        toks[0, :S] = prompt
-        t_start = time.monotonic()
-        prefill = self._prefill_jits.get(Sp, self._make_prefill)
-        logits, cache = prefill(self.params, jnp.asarray(toks),
-                                jnp.asarray(S - 1, jnp.int32))
-        self.kv.write_prefill(cache, seq.pages)
-        # fence: the writer donated the pool, so blocking on (logits, pool)
-        # covers the whole admission dispatch before the timer stops
-        jax.block_until_ready((logits, self.kv.pool))
-        pred = self._predict("prefill", 1, Sp)
-        self._emit_tick("prefill", t_start, time.monotonic() - t_start,
-                        pred, batch=1, padded_batch=1, q_len=Sp, tokens=S,
-                        rids=(seq.req.rid,))
-        seq.prefill_progress = S
-        self._first_token(seq, np.asarray(logits[0, 0]))
+        span = self.telemetry.span
+        with span("engine.prefill"):
+            prompt = np.asarray(seq.req.prompt, np.int32)
+            S = len(prompt)
+            chunk = self.policy.prefill_chunk
+            Sp = -(-S // chunk) * chunk
+            toks = np.zeros((1, Sp), np.int32)
+            toks[0, :S] = prompt
+            t_start = time.monotonic()
+            with span("engine.prefill.dispatch"):
+                prefill = self._prefill_jits.get(Sp, self._make_prefill)
+                logits, cache = prefill(self.params, jnp.asarray(toks),
+                                        jnp.asarray(S - 1, jnp.int32))
+                self.kv.write_prefill(cache, seq.pages)
+            # fence: the writer donated the pool, so blocking on (logits,
+            # pool) covers the whole admission dispatch before the timer
+            # stops
+            with span("engine.prefill.fence"):
+                jax.block_until_ready((logits, self.kv.pool))
+            pred = self._predict("prefill", 1, Sp)
+            self._emit_tick("prefill", t_start, time.monotonic() - t_start,
+                            pred, batch=1, padded_batch=1, q_len=Sp, tokens=S,
+                            rids=(seq.req.rid,))
+            seq.prefill_progress = S
+            self._first_token(seq, np.asarray(logits[0, 0]))
         return pred
 
     def _run_prefill_chunk(self, seq: ActiveSeq) -> float:
@@ -435,37 +447,44 @@ class Engine:
         then the sequence stays out of the decode batch. Returns the
         roofline's predicted seconds for the chunk (the step's stall
         budget accumulates these)."""
-        prompt = np.asarray(seq.req.prompt, np.int32)
-        S = len(prompt)
-        C = self.policy.prefill_chunk
-        start = seq.prefill_progress
-        end = min(start + C, S)
-        toks = np.zeros((1, C), np.int32)
-        toks[0, :end - start] = prompt[start:end]
-        maxp = self.policy.pages_per_seq
-        pt = np.zeros((1, maxp), np.int32)
-        pt[0, :len(seq.pages)] = seq.pages
-        t_start = time.monotonic()
-        with quiet_donation():
-            hidden, self.kv.pool = self._chunk_prefill(
-                self.params, self.kv.pool, jnp.asarray(pt),
-                jnp.asarray(toks), jnp.asarray([start], jnp.int32))
-        # sync before the step's stall timer stops: dispatch is async, and
-        # an unblocked intermediate chunk would bill its compute to the
-        # decode tick instead of the stall it actually causes.
-        jax.block_until_ready(hidden)
-        pred = self._predict("chunk", 1, C)
-        self._emit_tick("chunk", t_start, time.monotonic() - t_start,
-                        pred, batch=1, padded_batch=1, q_len=C,
-                        tokens=end - start, rids=(seq.req.rid,))
-        self.telemetry.seq_event(seq.req.rid, "chunk", start=start, end=end)
-        seq.prefill_progress = end
-        seq.pos = end
-        self.stats["prefill_chunks"] += 1
-        if end == S:
-            logits = self._unembed_row(self.params, hidden,
-                                       jnp.asarray(S - 1 - start, jnp.int32))
-            self._first_token(seq, np.asarray(logits[0, 0]))
+        span = self.telemetry.span
+        with span("engine.chunk"):
+            with span("engine.chunk.prepare"):
+                prompt = np.asarray(seq.req.prompt, np.int32)
+                S = len(prompt)
+                C = self.policy.prefill_chunk
+                start = seq.prefill_progress
+                end = min(start + C, S)
+                toks = np.zeros((1, C), np.int32)
+                toks[0, :end - start] = prompt[start:end]
+                maxp = self.policy.pages_per_seq
+                pt = np.zeros((1, maxp), np.int32)
+                pt[0, :len(seq.pages)] = seq.pages
+            t_start = time.monotonic()
+            with span("engine.chunk.dispatch"), quiet_donation():
+                hidden, self.kv.pool = self._chunk_prefill(
+                    self.params, self.kv.pool, jnp.asarray(pt),
+                    jnp.asarray(toks), jnp.asarray([start], jnp.int32))
+            # sync before the step's stall timer stops: dispatch is async,
+            # and an unblocked intermediate chunk would bill its compute to
+            # the decode tick instead of the stall it actually causes.
+            with span("engine.chunk.fence"):
+                jax.block_until_ready(hidden)
+            pred = self._predict("chunk", 1, C)
+            self._emit_tick("chunk", t_start, time.monotonic() - t_start,
+                            pred, batch=1, padded_batch=1, q_len=C,
+                            tokens=end - start, rids=(seq.req.rid,))
+            self.telemetry.seq_event(seq.req.rid, "chunk", start=start,
+                                     end=end)
+            seq.prefill_progress = end
+            seq.pos = end
+            self.stats["prefill_chunks"] += 1
+            if end == S:
+                with span("engine.chunk.first_token"):
+                    logits = self._unembed_row(
+                        self.params, hidden,
+                        jnp.asarray(S - 1 - start, jnp.int32))
+                    self._first_token(seq, np.asarray(logits[0, 0]))
         return pred
 
     def _is_live(self, seq: ActiveSeq) -> bool:
@@ -473,12 +492,64 @@ class Engine:
 
     def _decode_tick(self, live: List[ActiveSeq],
                      finished: List[ActiveSeq]) -> None:
-        # Growth phase, oldest first: crossing a page boundary claims a new
-        # page; exhaustion preempts the youngest active sequence — the
-        # grower itself, if it is the youngest, so pages only ever flow
-        # from younger to older and the FIFO head keeps draining. Victims
-        # already in `live` are filtered out below; their requests ride the
-        # queue back in on a later step.
+        span = self.telemetry.span
+        with span("engine.decode"):
+            with span("engine.decode.grow"):
+                ready = self._grow(live)
+            if not ready:
+                return
+
+            B = self.policy.max_batch
+            with span("engine.decode.prepare"):
+                maxp = self.policy.pages_per_seq
+                tokens = np.zeros((B, 1), np.int32)
+                # idle slots ride along against the scratch page; they
+                # carry the minimum live position (not 0) so the block
+                # walk's batch-wide window-trim bound stays tight for
+                # local-attention layers.
+                positions = np.full((B,), min(s.pos for s in ready),
+                                    np.int32)
+                pt = np.zeros((B, maxp), np.int32)       # 0 -> scratch page
+                for seq in ready:
+                    tokens[seq.slot, 0] = seq.last_token
+                    positions[seq.slot] = seq.pos
+                    pt[seq.slot, :len(seq.pages)] = seq.pages
+            t_start = time.monotonic()
+            with span("engine.decode.dispatch"), quiet_donation():
+                logits, self.kv.pool = self._decode(
+                    self.params, self.kv.pool, jnp.asarray(pt),
+                    jnp.asarray(tokens), jnp.asarray(positions))
+            # fence before the host transfer so the tick's measured
+            # duration is dispatch + compute, not whenever the async
+            # stream drains
+            with span("engine.decode.fence"):
+                jax.block_until_ready(logits)
+            measured = time.monotonic() - t_start
+            self.stats["decode_ticks"] += 1
+            # prediction priced at the PADDED jit batch — idle slots ride
+            # along in the fixed-shape dispatch, so B is what actually runs
+            self._emit_tick("decode", t_start, measured,
+                            self._predict("decode", B, 1), batch=len(ready),
+                            padded_batch=B, q_len=1, tokens=len(ready),
+                            rids=(s.req.rid for s in ready))
+            with span("engine.decode.sample"):
+                rows = np.asarray(logits[:, 0])  # one host transfer per tick
+                for seq in ready:
+                    tok = sample_token(rows[seq.slot], self.temperature,
+                                       self._step_key(seq))
+                    seq.generated.append(tok)
+                    seq.pos += 1
+                    self.stats["decode_tokens"] += 1
+                    if seq.is_done():
+                        finished.append(seq)
+
+    def _grow(self, live: List[ActiveSeq]) -> List[ActiveSeq]:
+        """Growth phase, oldest first: crossing a page boundary claims a
+        new page; exhaustion preempts the youngest active sequence — the
+        grower itself, if it is the youngest, so pages only ever flow from
+        younger to older and the FIFO head keeps draining. Returns the
+        sequences still live, oldest first; victims ride the queue back in
+        on a later step."""
         live = sorted(live, key=lambda s: s.birth)
         for seq in live:
             if not self._is_live(seq):
@@ -501,47 +572,7 @@ class Engine:
             if self._is_live(seq):
                 self.stats["grown_pages"] += len(seq.pages) - before
         self.stats["preemptions"] = self.scheduler.num_preempted
-        ready = [s for s in live if self._is_live(s)]
-        if not ready:
-            return
-
-        B = self.policy.max_batch
-        maxp = self.policy.pages_per_seq
-        tokens = np.zeros((B, 1), np.int32)
-        # idle slots ride along against the scratch page; they carry the
-        # minimum live position (not 0) so the block walk's batch-wide
-        # window-trim bound stays tight for local-attention layers.
-        positions = np.full((B,), min(s.pos for s in ready), np.int32)
-        pt = np.zeros((B, maxp), np.int32)       # 0 -> scratch page
-        for seq in ready:
-            tokens[seq.slot, 0] = seq.last_token
-            positions[seq.slot] = seq.pos
-            pt[seq.slot, :len(seq.pages)] = seq.pages
-        t_start = time.monotonic()
-        with quiet_donation():
-            logits, self.kv.pool = self._decode(
-                self.params, self.kv.pool, jnp.asarray(pt),
-                jnp.asarray(tokens), jnp.asarray(positions))
-        # fence before the host transfer so the tick's measured duration
-        # is dispatch + compute, not whenever the async stream drains
-        jax.block_until_ready(logits)
-        measured = time.monotonic() - t_start
-        self.stats["decode_ticks"] += 1
-        # prediction priced at the PADDED jit batch — idle slots ride
-        # along in the fixed-shape dispatch, so B is what actually runs
-        self._emit_tick("decode", t_start, measured,
-                        self._predict("decode", B, 1), batch=len(ready),
-                        padded_batch=B, q_len=1, tokens=len(ready),
-                        rids=(s.req.rid for s in ready))
-        rows = np.asarray(logits[:, 0])      # one host transfer per tick
-        for seq in ready:
-            tok = sample_token(rows[seq.slot], self.temperature,
-                               self._step_key(seq))
-            seq.generated.append(tok)
-            seq.pos += 1
-            self.stats["decode_tokens"] += 1
-            if seq.is_done():
-                finished.append(seq)
+        return [s for s in live if self._is_live(s)]
 
     def _step_key(self, seq: ActiveSeq):
         if self._key is None:

@@ -64,6 +64,7 @@ class ActiveSeq:
     birth: int = 0               # admission order (preemption picks max)
     pos: int = 0                 # tokens currently cached
     prefill_progress: int = 0    # prompt tokens resident in the pool
+    trimmed: int = 0             # leading page slots freed by trim_window
     generated: List[int] = dataclasses.field(default_factory=list)
 
     @property
@@ -178,6 +179,7 @@ class Scheduler:
         if dead:
             self.allocator.free(dead)
             seq.pages[:lo] = [0] * lo
+            seq.trimmed = lo
         return len(dead)
 
     def decode_ready(self) -> List[ActiveSeq]:

@@ -24,10 +24,14 @@ the two. Telemetry closes the loop:
   ``Engine.stall_log`` / ``Engine.first_token_s`` survive as thin views
   over this record, so pre-telemetry tests and benches run unchanged.
 * **Metrics registry** — counters/gauges/histograms (pool occupancy,
-  fragmentation, free-page low-water mark, queue depth, preemptions,
-  JitLRU hit/miss, per-kind tick latency). The default sink is a no-op
-  (`sinks.NULL_SINK`), so the always-on path costs dataclass appends
-  and integer bumps — no serialization, no export.
+  free-page low-water mark, preemptions, JitLRU hit/miss, per-kind tick
+  latency): plain Python ints and lists, cheap enough to leave on.
+* **Phase spans** — `Telemetry.span(name)` opens a named span of the
+  engine's host loop (``engine.step``, ``engine.decode.sample``, ...; the
+  tree is in serving/engine/__init__.py). By default it is a
+  ``jax.profiler.TraceAnnotation``, so under a profiler session each
+  span lands in the same trace as the device ops, on the same clock,
+  and a device idle gap can be put down to the host phase it falls in.
 * **Exports + calibration** — Chrome trace-event JSON
   (`write_chrome_trace`, ``--trace-out`` in launch/serve.py, loadable
   in Perfetto), a text `summarize`, and `calibrate()`: per
@@ -44,9 +48,9 @@ shows one span per request with instant marks at admit / chunk /
 first_token / preempt.
 
 Modules: `events` (typed event/span dataclasses), `metrics` (registry),
-`sinks` (streaming extension point, NULL_SINK default), `recorder`
-(the per-engine `Telemetry` object), `trace` (Chrome export + text
-summary), `calibrate` (predicted-vs-measured fits).
+`recorder` (the per-engine `Telemetry` object and its span factory),
+`trace` (Chrome export + text summary), `calibrate` (predicted-vs-
+measured fits).
 """
 from repro.serving.telemetry.calibrate import (CalibrationGroup,
                                                CalibrationReport,
@@ -56,8 +60,6 @@ from repro.serving.telemetry.events import (SEQ_EVENTS, TICK_KINDS, SeqEvent,
 from repro.serving.telemetry.metrics import (Counter, Gauge, Histogram,
                                              MetricsRegistry)
 from repro.serving.telemetry.recorder import Telemetry
-from repro.serving.telemetry.sinks import (NULL_SINK, NullSink,
-                                           RecordingSink, Sink)
 from repro.serving.telemetry.trace import (chrome_trace, summarize,
                                            write_chrome_trace)
 
@@ -66,6 +68,5 @@ __all__ = [
     "calibrate",
     "SEQ_EVENTS", "TICK_KINDS", "SeqEvent", "SeqSpan", "StallRecord",
     "TickEvent", "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "Telemetry", "NULL_SINK", "NullSink", "RecordingSink", "Sink",
-    "chrome_trace", "summarize", "write_chrome_trace",
+    "Telemetry", "chrome_trace", "summarize", "write_chrome_trace",
 ]

@@ -2,11 +2,10 @@
 histograms, host-side and jax-free.
 
 The registry is always "on" — its instruments are plain Python ints and
-float lists, cheap enough that the engine updates them unconditionally —
-while *export* cost lives entirely in the sinks (`sinks.NULL_SINK` by
-default, so a disabled engine pays no serialization). Instruments are
-created on first use and survive `reset()` with zeroed state, so a
-steady-state monitor can hold references across bench re-timings.
+float lists, cheap enough that the engine updates them unconditionally.
+Instruments are created on first use and survive `reset()` with zeroed
+state, so a steady-state monitor can hold references across bench
+re-timings.
 """
 from __future__ import annotations
 
@@ -96,11 +95,11 @@ class MetricsRegistry:
 
     Naming convention (dotted, grep-able): ``ticks.decode``,
     ``tokens.decode``, ``preemptions``, ``pool.free`` (min = low-water
-    mark), ``pool.occupancy``, ``pool.fragmentation``, ``queue.depth``,
-    ``jit.prefill.hits`` / ``.misses``, ``jit.pool_writer.hits`` /
-    ``.misses``, ``jit.decode.cache_size``, ``tick.decode.measured_s``
-    (histogram), ``tick.decode.rel_err`` (histogram), and the chunk /
-    prefill twins of the tick instruments."""
+    mark), ``pool.occupancy``, ``pool.min_free``, ``jit.prefill.hits`` /
+    ``.misses``, ``jit.pool_writer.hits`` / ``.misses``,
+    ``jit.decode.cache_size``, ``stall.measured_s`` (histogram),
+    ``tick.decode.measured_s`` (histogram), and the chunk / prefill twins
+    of the tick instruments."""
 
     def __init__(self):
         self.counters: Dict[str, Counter] = {}

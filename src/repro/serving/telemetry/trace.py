@@ -138,10 +138,11 @@ def summarize(tel: Telemetry) -> str:
     if free.value is not None:
         lines.append(f"  pool free={free.value:.0f} low-water={free.min:.0f} "
                      f"preemptions={m.counter('preemptions').value}")
-    occ = m.gauge("pool.occupancy")
-    if occ.value is not None:
-        frag = m.gauge("pool.fragmentation").value
-        lines.append(f"  pool occupancy={occ.value:.2f} "
+    occ = m.gauge("pool.occupancy").value
+    if occ is not None:
+        # fragmentation = 1 - occupancy; an empty pool reads 0 for both
+        frag = 1.0 - occ if occ else 0.0
+        lines.append(f"  pool occupancy={occ:.2f} "
                      f"fragmentation={frag:.2f}")
     jit_bits = []
     for name in ("prefill", "pool_writer"):

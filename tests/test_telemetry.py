@@ -1,11 +1,15 @@
 """Engine telemetry (serving/telemetry): metrics instruments, the
-recorder's tick/span/stall record, roofline calibration, Chrome trace
-export, back-compat views (stall_log / first_token_s), and the engine
-integration — including the JitLRU no-retrace steady-state guarantee
-and per-shard mesh tags."""
+recorder's tick/span/stall record, the engine's phase span tree,
+roofline calibration, Chrome trace export, back-compat views (stall_log
+/ first_token_s), and the engine integration — including the JitLRU
+no-retrace steady-state guarantee and per-shard mesh tags."""
 
+import contextlib
 import itertools
 import json
+import os
+import subprocess
+import sys
 
 import jax
 import numpy as np
@@ -14,9 +18,8 @@ import pytest
 from repro.configs import tiny_config
 from repro.models.api import build_model
 from repro.serving.engine import AdmissionPolicy, Engine, Request
-from repro.serving.telemetry import (MetricsRegistry, RecordingSink,
-                                     Telemetry, TickEvent, calibrate,
-                                     chrome_trace, summarize,
+from repro.serving.telemetry import (MetricsRegistry, Telemetry, TickEvent,
+                                     calibrate, chrome_trace, summarize,
                                      write_chrome_trace)
 
 
@@ -134,13 +137,19 @@ def test_recorder_first_token_keeps_first_edge():
     assert tel.spans[0].count("first_token") == 2
 
 
-def test_recording_sink_sees_the_stream():
-    sink = RecordingSink()
-    tel = Telemetry(sink=sink)
-    tel.tick(_tick())
-    tel.seq_event(1, "enqueue")
-    assert len(sink.ticks) == 1 and sink.ticks[0].kind == "decode"
-    assert sink.seq_events[0][0] == 1
+def test_default_span_is_a_profiler_annotation():
+    """Without a factory a span is a jax.profiler.TraceAnnotation, and the
+    package itself stays importable without jax (the import is lazy)."""
+    tel = Telemetry()
+    span = tel.span("engine.step")
+    assert isinstance(span, jax.profiler.TraceAnnotation)
+    with span:
+        pass
+    code = ("import sys; sys.modules['jax'] = None; "
+            "import repro.serving.telemetry as t; t.Telemetry()")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(sys.path))
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=60,
+                   env=env)
 
 
 # -------------------------------------------------------------- calibrate --
@@ -367,4 +376,146 @@ def test_engine_roofline_prediction_on_known_hw(gemma_tiny):
     report = calibrate(tel.ticks)
     for kind, scale in report.scale_factors().items():
         assert scale is not None and np.isfinite(scale) and scale > 0.0
-    assert tel.metrics.histogram("tick.decode.rel_err").count > 0
+    for kind, err in report.rel_err_by_kind().items():
+        assert err is not None and np.isfinite(err)
+
+
+# ------------------------------------------------------------ phase spans --
+# the engine's span tree: each span and the spans it may open inside
+SPAN_PARENTS = {
+    "engine.step": {None},
+    "engine.admit": {"engine.step"},
+    "engine.prefill": {"engine.admit"},
+    "engine.prefill.dispatch": {"engine.prefill"},
+    "engine.prefill.fence": {"engine.prefill"},
+    "engine.chunk": {"engine.step"},
+    "engine.chunk.prepare": {"engine.chunk"},
+    "engine.chunk.dispatch": {"engine.chunk"},
+    "engine.chunk.fence": {"engine.chunk"},
+    "engine.chunk.first_token": {"engine.chunk"},
+    "engine.decode": {"engine.step"},
+    "engine.decode.grow": {"engine.decode"},
+    "engine.decode.prepare": {"engine.decode"},
+    "engine.decode.dispatch": {"engine.decode"},
+    "engine.decode.fence": {"engine.decode"},
+    "engine.decode.sample": {"engine.decode"},
+    "engine.finish": {"engine.step", "engine.admit"},
+    "engine.gauges": {"engine.step"},
+}
+
+
+class SpanTree:
+    """A span factory that records each span opened, with its parent."""
+
+    def __init__(self):
+        self.opened = []             # (name, parent name or None)
+        self._stack = []
+
+    @contextlib.contextmanager
+    def __call__(self, name):
+        self.opened.append((name, self._stack[-1] if self._stack else None))
+        self._stack.append(name)
+        try:
+            yield
+        finally:
+            self._stack.pop()
+
+
+def _spans_per_step(engine, tree):
+    """The names of the spans each step opened, step by step."""
+    steps = []
+    while engine.scheduler.has_work():
+        before = len(tree.opened)
+        engine.step()
+        steps.append(sorted(n for n, _ in tree.opened[before:]))
+    return steps
+
+
+@pytest.mark.parametrize("chunked", [True, False])
+def test_engine_span_tree(gemma_tiny, chunked):
+    """Every span the engine opens has a name of the tree and opens inside
+    its parent; chunked mode opens every chunk and decode phase, and the
+    whole-prompt mode its prefill phases inside admission."""
+    model, params = gemma_tiny
+    tree = SpanTree()
+    engine = Engine(model, params, _policy(max_batch=2),
+                    chunked_prefill=chunked,
+                    telemetry=Telemetry(span_factory=tree))
+    engine.run([_req(0, 20, 4), _req(1, 8, 1)])
+    assert tree.opened and not tree._stack
+    for name, parent in tree.opened:
+        assert parent in SPAN_PARENTS[name], (name, parent)
+    names = {n for n, _ in tree.opened}
+    steps = sum(n == "engine.step" for n, _ in tree.opened)
+    assert steps == engine._step_idx
+    assert sum(n == "engine.gauges" for n, _ in tree.opened) == steps
+    assert sum(n == "engine.finish" for n, _ in tree.opened) == 2
+    decode = {n for n in SPAN_PARENTS if n.startswith("engine.decode")}
+    assert decode <= names
+    if chunked:
+        chunk = {n for n in SPAN_PARENTS if n.startswith("engine.chunk")}
+        assert chunk <= names
+        assert not {n for n in names if n.startswith("engine.prefill")}
+        assert sum(n == "engine.chunk.dispatch" for n, _ in tree.opened) \
+            == engine.stats["prefill_chunks"]
+    else:
+        prefill = {n for n in SPAN_PARENTS if n.startswith("engine.prefill")}
+        assert prefill <= names
+        assert ("engine.finish", "engine.admit") in tree.opened
+    assert sum(n == "engine.decode.sample" for n, _ in tree.opened) \
+        == engine.stats["decode_ticks"]
+
+
+def test_engine_spans_per_step_do_not_grow_with_batch(gemma_tiny):
+    """No span is opened per batch slot or per sequence: one request opens
+    the same spans in every step at max_batch 2 and 8, and a step that
+    only decodes opens the same spans for one sequence as for six."""
+    model, params = gemma_tiny
+
+    def run(max_batch, reqs):
+        tree = SpanTree()
+        engine = Engine(model, params, _policy(max_batch=max_batch),
+                        telemetry=Telemetry(span_factory=tree))
+        for r in reqs:
+            engine.submit(r)
+        return _spans_per_step(engine, tree)
+
+    one = run(2, [_req(0, 20, 5)])
+    assert one == run(8, [_req(0, 20, 5)])
+    assert len(one) >= 4
+
+    def decode_only(steps):
+        return {tuple(names) for names in steps
+                if "engine.decode" in names and "engine.chunk" not in names
+                and "engine.finish" not in names}
+
+    six = run(8, [_req(i, 8 + 3 * i, 12) for i in range(6)])
+    assert decode_only(one) and decode_only(one) == decode_only(six)
+
+
+def test_occupancy_gauge_matches_page_walk():
+    """The per-step occupancy gauge (kept from each sequence's count of
+    trimmed pages) equals the walk over every page slot it replaced, on
+    an all-local model whose pages are trimmed behind the window."""
+    cfg = tiny_config("gemma2-2b").replace(attn_pattern=("local",))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    engine = Engine(model, params, _policy(max_model_len=96, num_pages=100,
+                                           max_batch=2))
+    engine.submit(_req(0, 8, 70))
+    engine.submit(_req(1, 30, 40))
+    page = engine.kv.allocator.page_size
+    seen = set()
+    while engine.scheduler.has_work():
+        engine.step()
+        used = 0
+        for seq in engine.scheduler.active.values():
+            live = sum(p != 0 for p in seq.pages)
+            trimmed = len(seq.pages) - live
+            assert seq.trimmed == trimmed
+            used += max(min(seq.pos - trimmed * page, live * page), 0)
+            seen.add(trimmed)
+        cap = engine.kv.allocator.num_allocated * page
+        want = used / cap if cap else 0.0
+        assert engine.telemetry.metrics.gauge("pool.occupancy").value == want
+    assert max(seen) >= 2               # pages were trimmed on the way

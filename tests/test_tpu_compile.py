@@ -89,6 +89,40 @@ def test_paged_kernels_compile(one_chip, page, bits, Sq):
     assert "tpu_custom_call" in c.as_text()
 
 
+@pytest.mark.parametrize("name", ["paged_attention_fwd",
+                                  "paged_attention_quant_fwd",
+                                  "paged_prefill_fwd",
+                                  "paged_prefill_quant_fwd"])
+def test_paged_kernel_hlo_names(one_chip, name):
+    """Each kernel's custom call keeps its own HLO instruction name inside
+    a jitted step of another name: the device trace finds the kernel by
+    it (``pallas_call(name=...)`` sets it; without it the instruction
+    would take the enclosing function's name)."""
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    page, Sq = 16, (1 if "attention" in name else CHUNK)
+    B = BATCH if Sq == 1 else 1
+    q = sd((B, H, HD) if Sq == 1 else (B, Sq, H, HD), jnp.bfloat16)
+    pt, pos = sd((B, N_BLOCKS), jnp.int32), sd((B,), jnp.int32)
+    fn = getattr(pa, name)
+    if "quant" in name:
+        kv = sd((NUM_PAGES, K, page, HD), jnp.int8)
+        sc = sd((NUM_PAGES, K, page), jnp.float32)
+        args = (q, kv, sc, kv, sc, pt, pos)
+    else:
+        kv = sd((NUM_PAGES, K, page, HD), jnp.bfloat16)
+        args = (q, kv, kv, pt, pos)
+
+    def serving_step(*a):
+        return fn(*a) * 2
+
+    text = _compile(serving_step, *args).as_text()
+    calls = [ln.strip() for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    assert calls[0].startswith(f"%{name}.") or \
+        calls[0].startswith(f"%{name} "), calls[0][:120]
+
+
 @pytest.fixture(scope="module")
 def full_width(one_chip):
     """gemma2-2b's abstract parameters and page pool on one described
