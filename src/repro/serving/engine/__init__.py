@@ -17,9 +17,11 @@ The KV cache is a pool of fixed-size **pages** preallocated once per layer::
 page allocation covers all layers, so the allocator hands out one list of
 physical page ids per request and the per-layer pools index it identically
 (vLLM's layout, transposed into the repo's scan-stacked group convention).
-Within a page the kv heads are major: one head's (page_size, hd) tile is
-contiguous, which is the block the TPU paged-attention kernel tiles
-(models/transformer.py::pool_specs is the one definition of the layout).
+Within a page the kv heads are major: a page's (K, page_size, hd) tile is
+contiguous, which the TPU decode kernel copies whole, one DMA per page,
+and one head's (page_size, hd) tile within it is the block the prefill
+kernel tiles (models/transformer.py::pool_specs is the one definition of
+the layout).
 
 Pages are the unit of **memory and compute**. Allocation is dynamic: a
 request is admitted with only the pages its prompt (plus the first decode

@@ -94,13 +94,16 @@ def test_flash_kernel(causal, window, cap, H, K):
 
 # ------------------------------------------------------- paged attention ---
 def _paged_case(B, H, K, hd, page, n_blocks, *, num_pages=11, seed=0,
-                dtype=jnp.float32):
+                dtype=jnp.float32, positions=None):
     """Random pool + ragged page tables: each sequence at a different
     position, allocated pages shuffled, unused tails left on scratch page
-    0 (whose contents are poisoned to catch any leak past the mask)."""
+    0 (whose contents are poisoned to catch any leak past the mask).
+    ``positions`` (B of them) fixes the positions instead; a row at 0 is
+    then an idle slot, its whole page table on scratch page 0."""
     import numpy as np
     rng = np.random.default_rng(seed)
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    num_pages = max(num_pages, n_blocks + 1)
     pool_k = jax.random.normal(ks[0], (num_pages, K, page, hd),
                                jnp.float32).astype(dtype)
     pool_v = jax.random.normal(ks[1], (num_pages, K, page, hd),
@@ -109,10 +112,15 @@ def _paged_case(B, H, K, hd, page, n_blocks, *, num_pages=11, seed=0,
     pool_k = pool_k.at[0].set(37.0)
     pool_v = pool_v.at[0].set(-53.0)
     q = jax.random.normal(ks[2], (B, H, hd), jnp.float32).astype(dtype)
-    positions = rng.integers(0, n_blocks * page, B).astype(jnp.int32)
-    positions[0] = 0                          # scratch-tail-only edge case
+    idle = positions is not None
+    if positions is None:
+        positions = rng.integers(0, n_blocks * page, B).astype(jnp.int32)
+        positions[0] = 0                      # scratch-tail-only edge case
+    positions = np.asarray(positions, np.int32)
     pt = np.zeros((B, n_blocks), np.int32)
     for b in range(B):
+        if idle and positions[b] == 0:
+            continue
         need = positions[b] // page + 1
         pt[b, :need] = rng.choice(np.arange(1, num_pages), need,
                                   replace=False)
@@ -120,14 +128,36 @@ def _paged_case(B, H, K, hd, page, n_blocks, *, num_pages=11, seed=0,
             jnp.asarray(positions, jnp.int32))
 
 
-@pytest.mark.parametrize("page,n_blocks", [(8, 6), (16, 4), (32, 2)])
-@pytest.mark.parametrize("window,cap", [(0, 0.0), (24, 0.0), (0, 30.0)])
-@pytest.mark.parametrize("H,K", [(4, 2), (2, 2), (4, 1)])
-def test_paged_attention_kernel_parity(page, n_blocks, window, cap, H, K):
+# The decode walk takes 128 // page pages of a sequence per grid step. Each
+# case: H, K, window, cap, page, n_blocks, positions (None: random, row 0
+# at 0 on a real page; fixed: a row at 0 is an idle slot on scratch page 0).
+PAGED_WALK_CASES = [
+    pytest.param(H, K, window, cap, page, n_blocks, None,
+                 id=f"{H}-{K}-{window}-{cap}-{page}-{n_blocks}")
+    for H, K in [(4, 2), (2, 2), (4, 1)]
+    for window, cap in [(0, 0.0), (24, 0.0), (0, 30.0)]
+    for page, n_blocks in [(8, 6), (16, 4), (32, 2)]
+] + [
+    # G = 6 as nemotron; 68 pages = 8 steps of 8 and one of 4; contexts
+    # ending mid-step and on the table's last slot
+    pytest.param(48, 8, 0, 0.0, 16, 68, (0, 300, 1087), id="G6-68pages"),
+    # a local window whose first live page falls mid-step
+    pytest.param(48, 8, 200, 0.0, 16, 68, (1000, 0, 812),
+                 id="G6-68pages-window-lo-mid-step"),
+    pytest.param(4, 2, 0, 30.0, 32, 19, (0, 129, 607), id="page32-19pages"),
+    pytest.param(4, 1, 100, 0.0, 64, 5, (319, 200, 0), id="page64-5pages"),
+]
+
+
+@pytest.mark.parametrize("H,K,window,cap,page,n_blocks,positions",
+                         PAGED_WALK_CASES)
+def test_paged_attention_kernel_parity(H, K, window, cap, page, n_blocks,
+                                       positions):
     """Pallas page-walk kernel (interpret) and pure-JAX block walk both
     match the dense gather+mask oracle across page sizes, local windows,
-    GQA shapes, ragged positions, and scratch-page tails."""
-    q, pk, pv, pt, pos = _paged_case(3, H, K, 32, page, n_blocks)
+    GQA shapes, ragged positions, scratch-page tails and idle slots."""
+    q, pk, pv, pt, pos = _paged_case(3, H, K, 32, page, n_blocks,
+                                     positions=positions)
     want = ref.paged_attention_dense_ref(q, pk, pv, pt, pos,
                                          window=window, cap=cap)
     from repro.kernels import paged_attention as pa

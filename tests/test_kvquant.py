@@ -121,11 +121,15 @@ def test_kv_bits_inference_rejects_garbage():
 
 
 # -------------------------------------------------------- kernel parity ---
-def _quant_case(B, H, K, hd, page, n_blocks, bits, *, num_pages=11, seed=0):
+def _quant_case(B, H, K, hd, page, n_blocks, bits, *, num_pages=11, seed=0,
+                positions=None):
     """Random quantized pool + ragged page tables; scratch page 0 codes AND
-    scales poisoned so any leak past the mask explodes the error."""
+    scales poisoned so any leak past the mask explodes the error.
+    ``positions`` (B of them) fixes the positions; a row at 0 is then an
+    idle slot, its whole page table on scratch page 0."""
     rng = np.random.default_rng(seed)
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    num_pages = max(num_pages, n_blocks + 1)
     pool_k = jax.random.normal(ks[0], (num_pages, K, page, hd), jnp.float32)
     pool_v = jax.random.normal(ks[1], (num_pages, K, page, hd), jnp.float32)
     q = jax.random.normal(ks[2], (B, H, hd), jnp.float32)
@@ -135,10 +139,15 @@ def _quant_case(B, H, K, hd, page, n_blocks, bits, *, num_pages=11, seed=0):
     vq = vq.at[0].set(-55)
     ksc = ksc.at[0].set(97.0)
     vsc = vsc.at[0].set(83.0)
-    positions = rng.integers(0, n_blocks * page, B).astype(np.int32)
-    positions[0] = 0
+    idle = positions is not None
+    if positions is None:
+        positions = rng.integers(0, n_blocks * page, B).astype(np.int32)
+        positions[0] = 0
+    positions = np.asarray(positions, np.int32)
     pt = np.zeros((B, n_blocks), np.int32)
     for b in range(B):
+        if idle and positions[b] == 0:
+            continue
         need = positions[b] // page + 1
         pt[b, :need] = rng.choice(np.arange(1, num_pages), need,
                                   replace=False)
@@ -146,18 +155,37 @@ def _quant_case(B, H, K, hd, page, n_blocks, bits, *, num_pages=11, seed=0):
             jnp.asarray(positions, jnp.int32))
 
 
-@pytest.mark.parametrize("bits", [8, 4])
-@pytest.mark.parametrize("page,n_blocks", [(8, 6), (16, 4), (32, 2)])
-@pytest.mark.parametrize("window,cap", [(0, 0.0), (24, 0.0), (0, 30.0)])
-@pytest.mark.parametrize("H,K", [(4, 2), (2, 2), (4, 1)])
-def test_paged_attention_quant_parity(bits, page, n_blocks, window, cap,
-                                      H, K):
+# H, K, window, cap, page, n_blocks, positions, bits (positions as in
+# _quant_case; the last four cases are tests/test_kernels.py's walk edges)
+QUANT_WALK_CASES = [
+    pytest.param(H, K, window, cap, page, n_blocks, None, bits,
+                 id=f"{H}-{K}-{window}-{cap}-{page}-{n_blocks}-{bits}")
+    for H, K in [(4, 2), (2, 2), (4, 1)]
+    for window, cap in [(0, 0.0), (24, 0.0), (0, 30.0)]
+    for page, n_blocks in [(8, 6), (16, 4), (32, 2)]
+    for bits in [8, 4]
+] + [
+    pytest.param(*case, bits, id=f"{name}-{bits}")
+    for name, case in [
+        ("G6-68pages", (48, 8, 0, 0.0, 16, 68, (0, 300, 1087))),
+        ("G6-68pages-window-lo-mid-step",
+         (48, 8, 200, 0.0, 16, 68, (1000, 0, 812))),
+        ("page32-19pages", (4, 2, 0, 30.0, 32, 19, (0, 129, 607))),
+        ("page64-5pages", (4, 1, 100, 0.0, 64, 5, (319, 200, 0)))]
+    for bits in [8, 4]
+]
+
+
+@pytest.mark.parametrize("H,K,window,cap,page,n_blocks,positions,bits",
+                         QUANT_WALK_CASES)
+def test_paged_attention_quant_parity(H, K, window, cap, page, n_blocks,
+                                      positions, bits):
     """Fused-dequant Pallas kernel (interpret) and the pure-JAX quant walk
     both match the dense oracle evaluated on the dequantized pool, across
     bitwidths, page sizes, local windows, GQA shapes, ragged positions,
-    and poisoned scratch pages/scales."""
+    poisoned scratch pages/scales and idle slots."""
     q, kq, ksc, vq, vsc, pt, pos = _quant_case(3, H, K, 32, page, n_blocks,
-                                               bits)
+                                               bits, positions=positions)
     kd = ref.dequantize_kv(kq, ksc, bits)
     vd = ref.dequantize_kv(vq, vsc, bits)
     want = ref.paged_attention_dense_ref(q, kd, vd, pt, pos,

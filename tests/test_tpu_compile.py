@@ -89,6 +89,18 @@ def test_paged_kernels_compile(one_chip, page, bits, Sq):
     assert "tpu_custom_call" in c.as_text()
 
 
+@pytest.mark.parametrize("B,H,num_pages", [(42, 48, 2965), (18, 32, 2655)],
+                         ids=["nemotron-4-15b", "granite-3-8b"])
+def test_decode_kernel_compiles_at_cell_shapes(one_chip, B, H, num_pages):
+    """The bf16 decode kernel at the benchmark cells' decode shapes: 8 kv
+    heads of 128, pages of 16, 256 page-table slots per sequence."""
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    kv = sd((num_pages, 8, 16, 128), jnp.bfloat16)
+    c = _compile(pa.paged_attention_fwd, sd((B, H, 128), jnp.bfloat16), kv,
+                 kv, sd((B, 256), jnp.int32), sd((B,), jnp.int32))
+    assert "tpu_custom_call" in c.as_text()
+
+
 @pytest.mark.parametrize("name", ["paged_attention_fwd",
                                   "paged_attention_quant_fwd",
                                   "paged_prefill_fwd",
