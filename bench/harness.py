@@ -2,8 +2,11 @@
 
 Everything about a cell is data found by name: ``BENCHMARK.json`` names the
 cell's configuration and traffic, ``bench/configs/<config>.json`` holds the
-model and serving settings, ``bench/traffic/<traffic>.json`` the mix, and
-``bench/metrics/<metric>.py`` the reader of each per-layer metric.
+model and serving settings, ``bench/traffic/<traffic>.json`` the mix,
+``bench/metrics/<metric>.py`` the reader of each per-layer metric, and
+``bench/families/<family>.py``, by the configuration's ``model.family``,
+what depends on the model's structure: the parameter layout, the
+reference's blocks and the counts the readers use.
 
 A run: check the device; make the weights on the device from the seed in
 one jitted call; derive the admission policy for the attached device with
@@ -12,7 +15,8 @@ request; run the closed loop in through ``Engine.submit`` and
 ``Engine.step`` until it is steady (set-up ends here), then drive it for
 ``--seconds``; read the device's peak memory; free
 the program's state; compare a seeded sample of the finished requests with
-the float32 reference (bench/reference.py); print the result line.
+the float32 reference (bench/reference.py with the family's blocks);
+print the result line.
 """
 from __future__ import annotations
 
@@ -23,7 +27,9 @@ import os
 import shutil
 import sys
 import time
+import typing
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -52,6 +58,7 @@ class Cell:
     mix: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    family: ModuleType       # bench/families/<model.family>.py
 
 
 def _applies(metric: dict, cell: str, e2e_names) -> bool:
@@ -77,7 +84,7 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     names = {m["name"] for m in e2e}
     per = [m for m in bench["per_layer"] if _applies(m, name, names)]
     return Cell(name, c["chips"], c["config"], config, c["traffic"], mix,
-                e2e, per)
+                e2e, per, load_family(config["model"]["family"], root))
 
 
 def load_peaks(kind: str) -> dict:
@@ -88,15 +95,28 @@ def load_peaks(kind: str) -> dict:
     return table[kind]
 
 
+def _load(path: Path, prefix: str, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(
+        prefix + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(name: str, root: Path = ROOT):
     path = root / "bench" / "metrics" / f"{name}.py"
     if not path.is_file():
         fail(f"no reader {path} for per-layer metric {name}")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(path, "bench_metric_", name).read
+
+
+def load_family(name: str, root: Path = ROOT) -> ModuleType:
+    """The module of one model family: ``leaf_specs``, ``forward`` (the
+    reference's blocks) and the readers' counts."""
+    rel = Path("bench") / "families" / f"{name}.py"
+    if not (root / rel).is_file():
+        fail(f"no module {rel} for the model family {name!r}")
+    return _load(root / rel, "bench_family_", name)
 
 
 # ----------------------------------------------------------------- device --
@@ -123,18 +143,57 @@ def enable_cache() -> str:
 
 
 # ------------------------------------------------------------------ model --
+def _nested_class(tp):
+    """The dataclass that a field's type (``Optional[MoEConfig]``) names,
+    or None."""
+    return next((a for a in (tp, *typing.get_args(tp))
+                 if dataclasses.is_dataclass(a)), None)
+
+
+def _typed(cls, d: dict, where: str, base=None):
+    """``cls`` (over ``base`` where given) with the fields of ``d``: a
+    list becomes a tuple, a nested dict the dataclass its field holds,
+    over the nested object that ``base`` already has there, so fields the
+    file leaves out keep the base's values, not the class defaults."""
+    hints = typing.get_type_hints(cls)
+    kw = {}
+    for k, v in d.items():
+        if k not in hints:
+            fail(f"configuration key {where}.{k} is not a field of the "
+                 f"program's {cls.__name__}")
+        if isinstance(v, dict):
+            sub = _nested_class(hints[k])
+            if sub is None:
+                fail(f"configuration key {where}.{k} holds a dict; the "
+                     f"program's {cls.__name__}.{k} takes no dataclass")
+            v = _typed(sub, v, f"{where}.{k}",
+                       getattr(base, k, None) if base is not None else None)
+        kw[k] = tuple(v) if isinstance(v, list) else v
+    try:
+        return dataclasses.replace(base, **kw) if base is not None \
+            else cls(**kw)
+    except TypeError as e:
+        fail(f"configuration {where}: {e}")
+
+
+def _check_taken(obj, d: dict, where: str) -> None:
+    for k, v in d.items():
+        got = getattr(obj, k)
+        if isinstance(v, dict):
+            _check_taken(got, v, f"{where}.{k}")
+        elif (list(got) if isinstance(got, tuple) else got) != v:
+            fail(f"configuration {where}.{k}={v!r} not taken by the "
+                 f"program ({got!r})")
+
+
 def model_config(config: dict):
-    """The program's ModelConfig for the file's model dict, checked field
-    by field against the file."""
+    """The program's ModelConfig for the file's model dict (nested
+    ``moe`` and ``ssm`` dicts become the program's MoEConfig and
+    SSMConfig), checked field by field against the file."""
     from repro.configs import get_config
-    m = config["model"]
-    cfg = get_config(config["arch"]).replace(
-        **{k: tuple(v) if isinstance(v, list) else v for k, v in m.items()})
-    for k, v in m.items():
-        got = getattr(cfg, k)
-        if (list(got) if isinstance(got, tuple) else got) != v:
-            fail(f"configuration {k}={v!r} not taken by the program "
-                 f"({got!r})")
+    base = get_config(config["arch"])
+    cfg = _typed(type(base), config["model"], "model", base)
+    _check_taken(cfg, config["model"], "model")
     return cfg
 
 
@@ -149,12 +208,9 @@ def build(cell: Cell, seed: int, devices, log, hw=None):
     from bench import weights as W
 
     conf = cell.config
-    if conf["model"].get("family") != "dense":
-        fail(f"bench/reference.py has the dense family only, not "
-             f"{conf['model'].get('family')!r}")
     cfg = model_config(conf)
     model = build_model(cfg)
-    specs = W.leaf_specs(conf["model"])
+    specs = cell.family.leaf_specs(conf["model"])
     W.check_layout(specs, model.abstract_params())
     srv = conf["serving"]
     if cell.chips > 1:
@@ -333,6 +389,7 @@ def ticks_of(engine, w) -> List[Tick]:
 class Context:
     """What a per-layer metric reader sees."""
     model: dict
+    family: ModuleType       # the model family's counts
     peaks: dict
     chips: int
     window: object
@@ -394,8 +451,8 @@ def check(cell: Cell, seed: int, window, traffic, log,
         prompt, _ = traffic.request(*window.origin[r])
         reqs.append((prompt, window.finished[r].astype(np.int32)))
     t = time.monotonic()
-    gaps, ctl = served_gaps(m, seed, reqs, control=control) if reqs \
-        else (np.asarray([np.inf]), None)
+    gaps, ctl = (served_gaps(cell.family, m, seed, reqs, control=control)
+                 if reqs else (np.asarray([np.inf]), None))
     n_served = sum(len(s) for _, s in reqs)
     log(f"reference: {len(reqs)} requests, {n_served} served tokens, "
         f"{sum(len(p) for p, _ in reqs)} prompt tokens, "
@@ -515,7 +572,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         path = xplane.newest(tdir) if tdir else None
         if path:
             tr = xplane.reduce(path)
-        ctx = Context(m, peaks, cell.chips, w, ticks, set(profiled), tr)
+        ctx = Context(m, cell.family, peaks, cell.chips, w, ticks,
+                      set(profiled), tr)
         for spec in cell.per_layer:
             v = load_reader(spec["name"], root)(ctx)
             if isinstance(v, tuple):

@@ -24,7 +24,8 @@ def kernel_ops(ops):
 
 def roofline_share(ctx, engine_fn, work):
     """(share in %, note) of the kernel inside ``engine_fn``'s spans; None
-    when the trace has no such kernel op."""
+    when the trace has no such kernel op. ``work`` holds (operations,
+    bytes) per call, from the family's counts (``ctx.family``)."""
     tr = ctx.trace
     if tr is None or not work:
         return None

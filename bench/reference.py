@@ -1,16 +1,15 @@
-"""Plain float32 reference of the dense decoder, and its lower-precision
+"""Plain float32 reference of a served decoder, and its lower-precision
 control. Imports nothing of the program and takes nothing it made: the
 weights are made again here from the seed (bench/weights.py), one layer at
 a time, rounded to the bfloat16 the configuration serves and computed in
 float32 at ``highest`` matmul precision.
 
-Block: x += Attn(RMSNorm(x)); x += FFN(RMSNorm(x)). RMSNorm multiplies by
-(1 + scale). Attention is causal, grouped-query (query head h reads kv
-head h // (H / K)), with rotary embedding over all head dims (the two
-halves of a head rotated as a pair, base ``rope_theta``) and scale
-hd^-1/2. FFN is SwiGLU (silu(x Wg) * (x Wi)) Wo or squared ReLU
-relu(x Wi)^2 Wo. The head is RMSNorm then the unembedding (the embedding's transpose
-where the configuration ties them).
+What every model family shares is here: the embedding rows, the final
+RMSNorm (multiplying by 1 + scale) and the blocked unembedding (the
+embedding's transpose where the configuration ties them), teacher
+forcing, the served tokens' gaps and the control. The blocks in between
+are the family's: ``forward`` of bench/families/<family>.py, built from
+the helpers below, which also gives the parameter layout (``leaf_specs``).
 
 ``precision="fp8"`` is the control: the same forward with every matmul
 operand (weights per output channel, activations per row, attention
@@ -34,7 +33,6 @@ from bench import weights as W
 F32 = jnp.float32
 HI = jax.lax.Precision.HIGHEST
 BUCKET = 1024          # sequences are padded to a multiple of this
-Q_BLOCK = 512          # query rows per attention block
 ROW_BLOCK = 128        # rows per unembedding block
 V_BLOCK = 16384        # vocabulary columns per unembedding block
 FP8_MAX = 448.0
@@ -46,97 +44,63 @@ def _fp8(x, axis):
     return (x / s).astype(jnp.float8_e4m3fn).astype(F32) * s
 
 
-def _q(x, axis, fp8):
+def q(x, axis, fp8: bool):
+    """x, or with ``fp8`` x rounded to float8 e4m3 with one absmax scale
+    per slice along ``axis``: every matmul operand goes through this."""
     return _fp8(x, axis) if fp8 else x
 
 
-def _mm(eq, a, b):
+def mm(eq, a, b):
+    """A float32 einsum at ``highest`` precision."""
     return jnp.einsum(eq, a, b, precision=HI, preferred_element_type=F32)
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
+    """RMSNorm multiplying by (1 + scale)."""
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
         * (1.0 + scale)
 
 
-def _rope(x, pos, theta):
-    hd = x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd)
-    ang = pos.astype(F32)[:, None, None] * inv            # (T, 1, hd/2)
-    c, s = jnp.cos(ang), jnp.sin(ang)
-    a, b = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([a * c - b * s, a * s + b * c], -1)
-
-
-def _layer(m: dict, fp8: bool, w: Dict[str, jax.Array], x):
-    """One block over one sequence x (T, d)."""
-    T = x.shape[0]
-    H, K = m["num_heads"], m["num_kv_heads"]
-    hd = m.get("head_dim") or m["d_model"] // H
-    G = H // K
-    eps, theta = m["norm_eps"], m["rope_theta"]
-    pos = jnp.arange(T)
-    h = _q(_rms(x, w["ln1"], eps), -1, fp8)
-    q = _rope(_mm("td,dnh->tnh", h, _q(w["wq"], 0, fp8)), pos, theta)
-    k = _rope(_mm("td,dnh->tnh", h, _q(w["wk"], 0, fp8)), pos, theta)
-    v = _mm("td,dnh->tnh", h, _q(w["wv"], 0, fp8))
-    q = _q(q, -1, fp8).reshape(T, K, G, hd)
-    k, v = _q(k, -1, fp8), _q(v, 0, fp8)
-    outs = []
-    for s0 in range(0, T, Q_BLOCK):
-        qb = q[s0:s0 + Q_BLOCK]
-        sc = _mm("tkgh,skh->kgts", qb, k) * (hd ** -0.5)
-        causal = (jnp.arange(T)[None, :]
-                  <= (s0 + jnp.arange(qb.shape[0]))[:, None])
-        sc = jnp.where(causal, sc, -jnp.inf)
-        p = _q(jax.nn.softmax(sc, axis=-1), -1, fp8)
-        outs.append(_mm("kgts,skh->tkgh", p, v))
-    o = _q(jnp.concatenate(outs, 0).reshape(T, H * hd), -1, fp8)
-    x = x + _mm("te,ed->td", o, _q(w["wo"].reshape(H * hd, -1), 0, fp8))
-    h = _q(_rms(x, w["ln2"], eps), -1, fp8)
-    up = _mm("td,df->tf", h, _q(w["w_in"], 0, fp8))
-    if "w_gate" in w:
-        g = _mm("td,df->tf", h, _q(w["w_gate"], 0, fp8))
-        a = jax.nn.silu(g) * up if m["activation"] == "swiglu" \
-            else jax.nn.gelu(g, approximate=True) * up
-    else:
-        a = jnp.square(jax.nn.relu(up))
-    return x + _mm("tf,fd->td", _q(a, -1, fp8), _q(w["w_out"], 0, fp8))
-
-
-def _served(spec: W.Leaf, v):
+def as_served(spec: W.Leaf, v):
     """The value the program serves: rounded to the leaf's dtype."""
     return v.astype(spec.dtype).astype(F32)
 
 
-class Reference:
-    """The reference forward of one configuration and seed."""
+def layer_keys(keys: Dict[W.Path, np.ndarray], prefix: W.Path, layer: int):
+    """Layer ``layer``'s key of every stacked leaf under ``prefix``."""
+    return {p: k[layer] for p, k in keys.items()
+            if p[:len(prefix)] == prefix}
 
-    def __init__(self, m: dict, seed: int, precision: str = "f32"):
+
+def layer_weights(specs: Dict[W.Path, W.Leaf], prefix: W.Path, keys):
+    """One layer of every leaf whose ``layer_keys`` are given, as served
+    (float32), nested by the rest of its path below ``prefix``."""
+    out = {}
+    for p, k in keys.items():
+        s = specs[p]
+        one = s.shape[1:]
+        out[p[len(prefix):]] = as_served(
+            s, W.block_values(k, one, (0,) * len(one), one, s.std))
+    return W.nest(out)
+
+
+class Reference:
+    """The reference forward of one configuration and seed; ``family`` is
+    the configuration's module of bench/families/."""
+
+    def __init__(self, family, m: dict, seed: int, precision: str = "f32"):
         if precision not in ("f32", "fp8"):
             raise ValueError(precision)
-        self.m, self.fp8 = m, precision == "fp8"
-        self.specs = W.leaf_specs(m)
+        self.family, self.m, self.precision = family, m, precision
+        self.fp8 = precision == "fp8"
+        self.specs = family.leaf_specs(m)
         self.keys = W.leaf_keys(self.specs, seed)
-        self.blk = ("blocks", "sub0")
-        self._layer_w = jax.jit(self._make_layer_weights)
-        self._layer = jax.jit(lambda w, x: _layer(m, self.fp8, w, x))
         self._embed = jax.jit(self._embed_rows)
         self._head = jax.jit(self._head_rows)
 
-    def _make_layer_weights(self, keys):
-        out = {}
-        for p, s in self.specs.items():
-            if p[:2] != self.blk:
-                continue
-            one = s.shape[1:]
-            v = W.block_values(keys[p], one, (0,) * len(one), one, s.std)
-            out[p[-1]] = _served(s, v)
-        return out
-
     def _embed_rows(self, key, toks):
         s = self.specs[("embed",)]
-        return _served(s, W.row_values(key, s.shape, toks, s.std))
+        return as_served(s, W.row_values(key, s.shape, toks, s.std))
 
     def _head_rows(self, keys, x, served):
         """Per row of final hidden x (R, d): (best logit, its token, the
@@ -144,9 +108,9 @@ class Reference:
         m = self.m
         V = m["vocab_size"]
         sn = self.specs[("final_norm",)]
-        fn = _served(sn, W.block_values(keys["final_norm"], sn.shape, (0,),
-                                        sn.shape, sn.std))
-        h = _q(_rms(x, fn, m["norm_eps"]), -1, self.fp8)
+        fn = as_served(sn, W.block_values(keys["final_norm"], sn.shape,
+                                           (0,), sn.shape, sn.std))
+        h = q(rms(x, fn, m["norm_eps"]), -1, self.fp8)
         tied = ("lm_head",) not in self.specs
         sh = self.specs[("embed",) if tied else ("lm_head",)]
         d = m["d_model"]
@@ -164,8 +128,8 @@ class Reference:
         def body(carry, j):
             best, arg, got = carry
             c0 = j * V_BLOCK
-            w = _served(sh, head_block(c0))
-            lg = _mm("rd,dv->rv", h, _q(w, 0, self.fp8))
+            w = as_served(sh, head_block(c0))
+            lg = mm("rd,dv->rv", h, q(w, 0, self.fp8))
             col = c0 + jnp.arange(V_BLOCK)
             lg = jnp.where(col[None, :] < V, lg, -jnp.inf)
             b = jnp.max(lg, -1)
@@ -197,11 +161,8 @@ class Reference:
             pad = np.zeros(T, np.int32)
             pad[:len(toks)] = toks
             xs.append(self._embed(ek, jnp.asarray(pad)))
-        for l in range(self.m["num_layers"]):
-            w = self._layer_w({p: k[l] for p, k in self.keys.items()
-                               if p[:2] == self.blk})
-            xs = [self._layer(w, x) for x in xs]
-        return xs
+        return self.family.forward(self.m, self.specs, self.keys, xs,
+                                   self.precision)
 
     def head(self, rows: jax.Array, targets: np.ndarray):
         """(best logit, best token, logit of target) per row, in blocks."""
@@ -229,14 +190,16 @@ def teacher_forced(requests: Sequence[Tuple[np.ndarray, np.ndarray]]):
     return seqs, picks
 
 
-def served_gaps(m: dict, seed: int, requests, *, control: bool = False):
+def served_gaps(family, m: dict, seed: int, requests, *,
+                control: bool = False):
     """Per served token, the reference's best logit minus its logit of the
     token served. With ``control=True`` also, per position, the same gap
     of the token that the fp8 control puts first (same prompts and served
-    tokens), read on the float32 reference's logits. Returns (served
-    gaps, control gaps or None)."""
+    tokens), read on the float32 reference's logits. ``family`` is the
+    configuration's module of bench/families/. Returns (served gaps,
+    control gaps or None)."""
     seqs, picks = teacher_forced(requests)
-    ref = Reference(m, seed)
+    ref = Reference(family, m, seed)
     hs = ref.final_hidden(seqs)
     rows = jnp.concatenate([h[p] for h, p in zip(hs, picks)])
     del hs
@@ -244,7 +207,7 @@ def served_gaps(m: dict, seed: int, requests, *, control: bool = False):
     best, _, got = ref.head(rows, served)
     if not control:
         return best - got, None
-    ctl = Reference(m, seed, "fp8")
+    ctl = Reference(family, m, seed, "fp8")
     hc = ctl.final_hidden(seqs)
     crow = jnp.concatenate([h[p] for h, p in zip(hc, picks)])
     del hc

@@ -6,6 +6,7 @@ from pathlib import Path
 import pytest
 
 from bench import costs
+from bench.families import dense
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 
@@ -35,18 +36,18 @@ HAND = [
 def test_hand_counts(name, L, per_layer, head, attn, kv):
     base, _, layers = name.partition("@")
     m = model(base, **({"num_layers": int(layers)} if layers else {}))
-    assert costs.layer_matmul_params(m) == per_layer
+    assert dense.layer_matmul_params(m) == per_layer
     # one decode token at context 1000, one at 3000
     want = 2 * (2 * per_layer * L + 2 * head) + attn * L * (1000 + 3000)
-    assert costs.decode_flops(m, [1000, 3000]) == want
+    assert dense.decode_flops(m, [1000, 3000]) == want
     # a chunk of rows 1024..2047 (1024 rows; keys 1025..2048 per row)
     keys = sum(range(1025, 2049))
-    assert costs.chunk_flops(m, 1024, 2048) == \
+    assert dense.chunk_flops(m, 1024, 2048) == \
         2 * per_layer * L * 1024 + attn * L * keys
-    assert costs.kv_bytes(m, 100) == kv * L * 100
-    f, b = costs.chunk_attention_work(m, 1024, 2048)
+    assert dense.kv_bytes(m, 100) == kv * L * 100
+    f, b = dense.chunk_attention_work(m, 1024, 2048)
     assert (f, b) == (attn * L * keys, kv * L * 2048)
-    f, b = costs.decode_attention_work(m, [10, 20])
+    f, b = dense.decode_attention_work(m, [10, 20])
     assert (f, b) == (attn * L * 30, kv * L * 30)
 
 

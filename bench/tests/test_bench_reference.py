@@ -8,13 +8,14 @@ import pytest
 import jax.numpy as jnp
 
 from bench import harness, weights as W
+from bench.families import dense
 from bench.reference import Reference
 from bench.tests import tinyroot
 
 
 def ref_logits(m, seed, tokens):
     """Full reference logits at every position of ``tokens``."""
-    ref = Reference(m, seed)
+    ref = Reference(dense, m, seed)
     h = ref.final_hidden([tokens])[0][:len(tokens)]
     sn, sh = ref.specs[("final_norm",)], ref.specs[("lm_head",)]
     fn = W.block_values(ref.keys[("final_norm",)][0], sn.shape, (0,),

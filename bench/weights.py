@@ -10,11 +10,12 @@ deviation, quantized to 16 bits before the one multiply by the scale, so
 the float32 value is one correctly rounded product and the bfloat16 value
 served is that product rounded once more.
 
-The leaf table (``leaf_specs``) is the dense decoder's parameter layout as
-the served program stores it: every matrix bfloat16, every norm scale
-float32, the layers stacked on a leading axis. The harness checks the
-program's own abstract parameter tree against it before it makes
-anything.
+A leaf table maps each parameter's path to its ``Leaf``: its shape as the
+served program stores it (the layers stacked on a leading axis), its
+dtype and the standard deviation of its values. Each model family gives
+its own (``leaf_specs`` of bench/families/<family>.py); what is made from
+a table is the same for every family. The harness checks the program's
+own abstract parameter tree against the table before it makes anything.
 """
 from __future__ import annotations
 
@@ -60,43 +61,6 @@ class Leaf:
 
 
 Path = Tuple[str, ...]
-
-
-def leaf_specs(m: dict) -> Dict[Path, Leaf]:
-    """Parameter layout of the dense decoder from the configuration's
-    model dict (keys as in ``bench/configs/*.json``)."""
-    d, H, K = m["d_model"], m["num_heads"], m["num_kv_heads"]
-    hd = m.get("head_dim") or d // H
-    F, L = m["d_ff"], m["num_layers"]
-    Vp = -(-m["vocab_size"] // 256) * 256
-    bf, f32 = "bfloat16", "float32"
-    tied = m.get("tie_embeddings", False)
-    # a tied table is also the unembedding: scaled like the head, so the
-    # logits spread about as a trained model's do and rounding can move
-    # the top token (at std 1 it never does, in bf16 or in fp8)
-    specs = {
-        ("embed",): Leaf((Vp, d), bf, 1 / math.sqrt(d) if tied else 1.0,
-                         False),
-        ("final_norm",): Leaf((d,), f32, NORM_STD, False),
-    }
-    if not tied:
-        specs[("lm_head",)] = Leaf((d, Vp), bf, 1 / math.sqrt(d), False)
-    blk = ("blocks", "sub0")
-    layer = {
-        ("ln1",): ((d,), f32, NORM_STD),
-        ("ln2",): ((d,), f32, NORM_STD),
-        ("attn", "wq"): ((d, H, hd), bf, 1 / math.sqrt(d)),
-        ("attn", "wk"): ((d, K, hd), bf, 1 / math.sqrt(d)),
-        ("attn", "wv"): ((d, K, hd), bf, 1 / math.sqrt(d)),
-        ("attn", "wo"): ((H, hd, d), bf, 1 / math.sqrt(H * hd)),
-        ("ffn", "w_in"): ((d, F), bf, 1 / math.sqrt(d)),
-        ("ffn", "w_out"): ((F, d), bf, 1 / math.sqrt(F)),
-    }
-    if m["activation"] in ("swiglu", "geglu"):
-        layer[("ffn", "w_gate")] = ((d, F), bf, 1 / math.sqrt(d))
-    for p, (shape, dt, std) in layer.items():
-        specs[blk + p] = Leaf((L,) + shape, dt, std, True)
-    return specs
 
 
 def leaf_key(seed: int, path: Path, layer: int) -> int:
@@ -183,7 +147,7 @@ def check_layout(specs: Dict[Path, Leaf], abstract) -> None:
     if got != want:
         diff = sorted(set(got.items()) ^ set(want.items()))
         raise SystemExit(f"bench: the program's parameter layout differs "
-                         f"from bench/weights.py: {diff[:6]}")
+                         f"from the family's leaf_specs: {diff[:6]}")
 
 
 def make_params_fn(specs: Dict[Path, Leaf]):
