@@ -16,6 +16,7 @@ from repro.configs.whisper_large_v3 import CONFIG as _whisper
 from repro.configs.llava_next_mistral_7b import CONFIG as _llava
 from repro.configs.llama4_maverick_400b import CONFIG as _llama4
 from repro.configs.granite_moe_3b import CONFIG as _granite_moe
+from repro.configs.granite_4_h_small import CONFIG as _granite4h
 from repro.configs.zamba2_1_2b import CONFIG as _zamba2
 from repro.configs.mamba2_370m import CONFIG as _mamba2
 from repro.configs.supernet_lm import BACKBONE as _supernet
@@ -23,7 +24,8 @@ from repro.configs.supernet_lm import BACKBONE as _supernet
 ARCHS = {
     c.name: c
     for c in [_granite, _mistral, _nemotron, _gemma2, _whisper, _llava,
-              _llama4, _granite_moe, _zamba2, _mamba2, _supernet]
+              _llama4, _granite_moe, _granite4h, _zamba2, _mamba2,
+              _supernet]
 }
 
 # Short aliases accepted by --arch.
@@ -38,6 +40,7 @@ ALIASES = {
     "llama4-maverick-400b": "llama4-maverick-400b-a17b",
     "granite-moe-3b-a800m": "granite-moe-3b-a800m",
     "granite-moe-3b": "granite-moe-3b-a800m",
+    "granite-4.0-h-small": "granite-4.0-h-small",
     "zamba2-1.2b": "zamba2-1.2b",
     "mamba2-370m": "mamba2-370m",
     "supernet-lm": "supernet-lm",
@@ -90,7 +93,10 @@ def tiny_config(arch: str) -> ModelConfig:
             offset=cfg.moe.offset,
             # effectively drop-free so prefill/decode equivalence is exact
             capacity_factor=4.0,
+            d_ff_shared=64 if cfg.moe.d_ff_shared else 0,
         )
+    if cfg.layer_types:     # one period: a mamba layer, then attention
+        kw["layer_types"] = ("mamba", "attention")
     if cfg.ssm:
         kw["ssm"] = cfg.ssm.__class__(
             d_state=16, expand=2, head_dim=32, n_groups=1, conv_width=4,

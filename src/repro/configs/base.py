@@ -21,6 +21,18 @@ class MoEConfig:
     offset: int = 0
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
+    # a shared SwiGLU expert of this width beside the routed ones (0: none)
+    d_ff_shared: int = 0
+    # The experts held here, first_held .. first_held + num_held - 1: with
+    # expert parallelism a chip holds a contiguous share and computes its
+    # part of the result; the router still scores all num_experts.
+    # 0 holds every expert.
+    num_held: int = 0
+    first_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.num_held or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -60,6 +72,19 @@ class ModelConfig:
     ssm: Optional[SSMConfig] = None
     # hybrid (zamba2): a shared attention block applied every k core layers
     shared_attn_every: int = 0
+    # Mixer of each layer ("attention" | "mamba"), cycled as a period like
+    # attn_pattern; () makes every layer attention. Mamba layers take their
+    # sizes from `ssm` (granite-4.0-h: attention at fixed places).
+    layer_types: Tuple[str, ...] = ()
+    position_embedding: str = "rope"   # rope | nope (no position encoding)
+    # Granite's scalar multipliers: the embedding times embedding_multiplier,
+    # every residual add x + residual_multiplier * f(x), attention scores
+    # scaled by attention_multiplier (0: head_dim^-1/2), logits divided by
+    # logits_scaling. The defaults are neutral.
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # enc-dec (whisper): num_layers applies to BOTH encoder and decoder
     is_encdec: bool = False
     dec_ratio: int = 8          # decoder_len = seq_len // dec_ratio
@@ -95,6 +120,12 @@ class ModelConfig:
             return 0
         return self.ssm.num_heads or (self.d_inner // self.ssm.head_dim)
 
+    def mixer(self, i: int) -> str:
+        """Layer i's mixer: "attention" or "mamba"."""
+        if not self.layer_types:
+            return "attention"
+        return self.layer_types[i % len(self.layer_types)]
+
     def is_moe_layer(self, i: int) -> bool:
         return bool(self.moe) and (i - self.moe.offset) % self.moe.every == 0 \
             and i >= self.moe.offset
@@ -103,7 +134,8 @@ class ModelConfig:
         return dataclasses.replace(self, **kw)
 
     def param_count(self) -> int:
-        """Analytic parameter count (embedding + blocks + head)."""
+        """Analytic parameter count (embedding + blocks + head; norm scales
+        are not counted). Of a MoE layer, the experts held here."""
         d, h = self.d_model, self.resolved_head_dim
         n_q, n_kv = self.num_heads, self.num_kv_heads
         emb = self.vocab_size * d
@@ -120,13 +152,18 @@ class ModelConfig:
                 nh = self.ssm_heads
                 in_proj = d * (2 * di + 2 * g * n + nh)
                 blocks += in_proj + di * d + di * self.ssm.conv_width + 3 * nh
+                if self.layer_types:    # the conv over B and C, its bias
+                    d_conv = di + 2 * g * n
+                    blocks += 2 * g * n * self.ssm.conv_width + d_conv
             else:
                 blocks += per_attn
+            if self.layer_types or self._is_attn_layer(i):
                 if self.is_moe_layer(i):
                     m = self.moe
                     e_ff = m.d_ff_expert
-                    blocks += m.num_experts * d * e_ff * (3 if gated else 2)
+                    blocks += m.held * d * e_ff * (3 if gated else 2)
                     blocks += d * m.num_experts  # router
+                    blocks += d * m.d_ff_shared * (3 if gated else 2)
                 elif self.d_ff:
                     blocks += per_ffn
         blocks *= n_stacks
@@ -142,7 +179,7 @@ class ModelConfig:
             return False
         if self.family == "hybrid":
             return False  # zamba2 core stack is all-mamba; attn is the shared block
-        return True
+        return self.mixer(i) == "attention"
 
 
 @dataclass(frozen=True)

@@ -196,6 +196,16 @@ def ssd_cost(batch: int, seq: int, d_inner: int, d_state: int,
                   act_bytes=jnp.asarray(2.0 * batch * seq * d_inner * 2.0))
 
 
+def ssm_state_cost(batch: int, d_inner: int, d_state: int) -> OpCost:
+    """One call's Mamba-2 recurrent state, fp32 (heads x head_dim =
+    d_inner, by d_state per sequence), read and written once per sequence:
+    the bytes that bound a decode tick of a state-space layer."""
+    return OpCost(flops=jnp.asarray(0.0),
+                  weight_bytes=jnp.asarray(0.0),
+                  act_bytes=jnp.asarray(2.0 * batch * d_inner * d_state
+                                        * 4.0))
+
+
 def moe_cost(tokens: int, d_model: int, d_ff: int, n_experts: int,
              top_k: int, *, ep: int = 1) -> OpCost:
     """Top-k expert FFN + all-to-all dispatch."""

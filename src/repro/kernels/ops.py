@@ -15,6 +15,7 @@ from repro.kernels import flash_attention as fa
 from repro.kernels import paged_attention as pa
 from repro.kernels import quant_matmul as qmm
 from repro.kernels import ref
+from repro.kernels import ssm_decode as sd
 
 F32 = jnp.float32
 
@@ -186,3 +187,16 @@ def paged_attention_prefill_quant(q, pool_k, k_scale, pool_v, v_scale,
     return pa.paged_prefill_quant_fwd(
         q, pool_k, k_scale, pool_v, v_scale, page_table, positions,
         window=window, cap=cap, interpret=_interpret())
+
+
+def ssm_decode(state, rows, x, dt, a_log, Bm, Cm, d_skip, *,
+               mode: str = "auto"):
+    """Mamba-2 decode state update of the slot rows ``rows`` of ``state``
+    (R, H, P, N) f32, in place; see kernels/ssm_decode.py. Same dispatch
+    contract as paged_attention: "auto" runs the Pallas kernel on TPU and
+    the jnp twin on the CPU. Returns (y (B, H, P) f32, state)."""
+    a = -jnp.exp(a_log.astype(F32))
+    if _paged_mode(mode) == "ref":
+        return ref.ssm_decode_ref(state, rows, x, dt, a, Bm, Cm, d_skip)
+    return sd.ssm_decode_fwd(state, rows, x, dt, a, Bm, Cm, d_skip,
+                             interpret=_interpret())

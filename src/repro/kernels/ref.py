@@ -399,3 +399,18 @@ def flash_attention_ref(q, k, v, *, causal=True, window=0, cap=0.0):
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(F32))
     return out.astype(q.dtype)
+
+
+# -------------------------------------------------- ssm decode update -----
+def ssm_decode_ref(state, rows, x, dt, a, Bm, Cm, d_skip):
+    """Oracle of kernels/ssm_decode.py: the Mamba-2 one-token update of the
+    states in rows ``rows`` of ``state`` (R, H, P, N) f32. x (B, H, P),
+    dt (B, H) after softplus, a (H,) = -exp(a_log), Bm/Cm (B, G, N),
+    d_skip (H,). Returns (y (B, H, P) f32 with the D skip, state)."""
+    H, G = x.shape[1], Bm.shape[1]
+    Bh = jnp.repeat(Bm, H // G, axis=1)[:, :, None, :]       # (B,H,1,N)
+    Ch = jnp.repeat(Cm, H // G, axis=1)[:, :, None, :]
+    decay = jnp.exp(dt * a[None, :])[:, :, None, None]
+    new = state[rows] * decay + (dt[:, :, None] * x)[..., None] * Bh
+    y = jnp.sum(new * Ch, axis=-1) + x * d_skip[None, :, None]
+    return y, state.at[rows].set(new, mode="promise_in_bounds")

@@ -87,26 +87,31 @@ class Model:
         return transformer.unembed(params, hidden, self.cfg, dot=dot)
 
     def decode_step_paged(self, params, pool, page_table, token, positions,
-                          *, ac=None, dot=None, kernel="auto"):
+                          *, rows=None, ac=None, dot=None, kernel="auto"):
         """Continuous-batching decode: per-sequence positions, KV walked
         page-by-page through the page table (see serving/engine). ``kernel``
         picks the paged-attention path: "auto" (Pallas on TPU, pure-JAX
-        block walk elsewhere), "pallas", or "ref"."""
+        block walk elsewhere), "pallas", or "ref". ``rows``: each batch
+        row's recurrent-state slot, for models with Mamba layers."""
         ac = ac or transformer._identity_ac
         return transformer.decode_step_paged(params, pool, page_table, token,
-                                             positions, self.cfg, ac=ac,
-                                             dot=dot, kernel=kernel)
+                                             positions, self.cfg, rows=rows,
+                                             ac=ac, dot=dot, kernel=kernel)
 
     def prefill_chunk_paged(self, params, pool, page_table, tokens,
-                            positions, *, dot=None, kernel="auto"):
+                            positions, *, rows=None, lengths=None, dot=None,
+                            kernel="auto"):
         """Chunked prefill: run one prompt chunk (tokens (B, Sq), first
         token of sequence b at absolute position ``positions[b]``) through
         the model, scattering its K/V into the paged pool and attending
         over the pool itself (resident prefix + chunk). Returns
         (hidden (B, Sq, D), new_pool); unembed the rows you need via
-        ``unembed``. See transformer.prefill_chunk_paged."""
+        ``unembed``. ``rows`` and ``lengths``: each sequence's recurrent-
+        state slot and real tokens in the chunk, for models with Mamba
+        layers. See transformer.prefill_chunk_paged."""
         return transformer.prefill_chunk_paged(params, pool, page_table,
                                                tokens, positions, self.cfg,
+                                               rows=rows, lengths=lengths,
                                                dot=dot, kernel=kernel)
 
     # -- caches & inputs ----------------------------------------------------
@@ -119,16 +124,22 @@ class Model:
         return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
                             self.cache_specs(batch, seq_len))
 
-    def pool_specs(self, num_pages: int, page_size: int, kv_bits=None):
+    def pool_specs(self, num_pages: int, page_size: int, kv_bits=None,
+                   state_slots: int = 0):
         """``kv_bits`` selects the HAQ KV-quantized pool layout (int8/int4
         pages + per-page-slot scales) per sub-layer slot; None keeps the
-        bf16 pool. See transformer.pool_specs / serving/kvquant."""
+        bf16 pool. ``state_slots``: rows of recurrent state beside the
+        pages, for models with Mamba layers. See transformer.pool_specs /
+        serving/kvquant."""
         return transformer.pool_specs(self.cfg, num_pages, page_size,
-                                      kv_bits=kv_bits)
+                                      kv_bits=kv_bits,
+                                      state_slots=state_slots)
 
-    def init_pool(self, num_pages: int, page_size: int, kv_bits=None):
+    def init_pool(self, num_pages: int, page_size: int, kv_bits=None,
+                  state_slots: int = 0):
         return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                            self.pool_specs(num_pages, page_size, kv_bits))
+                            self.pool_specs(num_pages, page_size, kv_bits,
+                                            state_slots))
 
     def input_specs(self, shape) -> Dict[str, Any]:
         """ShapeDtypeStruct stand-ins for one step's inputs (dry-run)."""

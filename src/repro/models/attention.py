@@ -8,7 +8,8 @@ Layout conventions:
   full KV cache          (B, S_max, K, hd)
   ring KV cache (local)  (B, W, K, hd)     slot = position % W
 Attention logits are computed in fp32; RoPE is applied at cache-write time
-(absolute positions), which keeps ring-buffer decode exact.
+(absolute positions), which keeps ring-buffer decode exact, or not at all
+where the configuration's attention has no position encoding.
 """
 from __future__ import annotations
 
@@ -52,6 +53,19 @@ def qkv(p, x, theta: float, positions, *, dot=None):
     if theta > 0 and positions is not None:
         q = apply_rope(q, positions, theta)
         k = apply_rope(k, positions, theta)
+    return q, k, v
+
+
+def project(p, x, cfg, positions, *, dot=None):
+    """``qkv`` under ``cfg``: RoPE unless ``position_embedding`` is "nope",
+    and q scaled so that every attention path's hd^-1/2 comes to
+    ``attention_multiplier`` where the configuration sets one (the Pallas
+    kernels keep their own scale)."""
+    theta = 0.0 if cfg.position_embedding == "nope" else cfg.rope_theta
+    q, k, v = qkv(p, x, theta, positions, dot=dot)
+    if cfg.attention_multiplier:
+        s = cfg.attention_multiplier * cfg.resolved_head_dim ** 0.5
+        q = (q.astype(F32) * s).astype(q.dtype)
     return q, k, v
 
 
@@ -106,7 +120,7 @@ def attention_fwd(p, x, kind: str, cfg, positions, *, dot=None,
     paged serving engine can copy them into its page pool).
     """
     B, S, D = x.shape
-    q, k, v = qkv(p, x, cfg.rope_theta, positions, dot=dot)
+    q, k, v = project(p, x, cfg, positions, dot=dot)
     W = cfg.window_size
     if S >= flash_lib.FLASH_MIN and segment_ids is None:
         o = flash_lib.flash_attention(q, k, v, kind, W, cfg.attn_softcap)
@@ -158,7 +172,7 @@ def attention_decode(p, x, cache_k, cache_v, pos, kind: str, cfg, *,
     """
     B = x.shape[0]
     positions = jnp.full((B, 1), pos, jnp.int32)
-    q, k_new, v_new = qkv(p, x, cfg.rope_theta, positions, dot=dot)
+    q, k_new, v_new = project(p, x, cfg, positions, dot=dot)
     T = cache_k.shape[1]
     if kind == "local" and T == cfg.window_size:
         slot = jnp.mod(pos, T)
@@ -228,7 +242,8 @@ def attention_decode_paged(p, x, pool_k, pool_v, page_table, positions,
     """
     quantized = isinstance(pool_k, dict)
     page = (pool_k["q"] if quantized else pool_k).shape[2]
-    q, k_new, v_new = qkv(p, x, cfg.rope_theta, positions[:, None], dot=dot)
+    q, k_new, v_new = project(p, x, cfg, positions[:, None],
+                                dot=dot)
     pids = jnp.take_along_axis(page_table, (positions // page)[:, None],
                                axis=1)[:, 0]
     slots = positions % page
@@ -296,7 +311,7 @@ def attention_prefill_paged(p, x, pool_k, pool_v, page_table, positions,
     B, Sq, _ = x.shape
     n_blocks = page_table.shape[1]
     abs_pos = positions[:, None] + jnp.arange(Sq, dtype=jnp.int32)[None, :]
-    q, k_new, v_new = qkv(p, x, cfg.rope_theta, abs_pos, dot=dot)
+    q, k_new, v_new = project(p, x, cfg, abs_pos, dot=dot)
     # A final chunk padded past the page-table width routes its overflow
     # rows to the scratch page explicitly: an unclamped gather fills OOB
     # indices with INT_MIN, which the promise_in_bounds scatter below

@@ -1,9 +1,10 @@
-"""Generic decoder-only LM assembly for the dense / moe / vlm / ssm / hybrid
-families. Layers are scanned in groups of ``period`` sub-layers, where period
-is the LCM of the attention pattern (gemma2 local/global) and the MoE
-interleave (llama4 dense/MoE) — each sub-layer slot has its own stacked
-parameter pytree so `lax.scan` keeps HLO size and CPU compile time bounded
-for the 88-layer/123B configs.
+"""Generic decoder-only LM assembly for the dense / moe / vlm / ssm / hybrid /
+hybrid_moe families. Layers are scanned in groups of ``period`` sub-layers,
+where period is the LCM of the attention pattern (gemma2 local/global), the
+MoE interleave (llama4 dense/MoE) and the mixer pattern (granite-4.0-h: a
+Mamba-2 or an attention mixer per sub-layer) — each sub-layer slot has its
+own stacked parameter pytree so `lax.scan` keeps HLO size and CPU compile
+time bounded for the 88-layer/123B configs.
 
 Public surface (used by models/api.py):
   param_defs(cfg)                     -> PDef pytree
@@ -39,6 +40,8 @@ def period_of(cfg) -> int:
     p = len(cfg.attn_pattern)
     if cfg.moe:
         p = math.lcm(p, cfg.moe.every)
+    if cfg.layer_types:
+        p = math.lcm(p, len(cfg.layer_types))
     return p
 
 
@@ -50,8 +53,15 @@ def sublayer_kinds(cfg):
         kinds.append({
             "attn": cfg.attn_pattern[j % len(cfg.attn_pattern)],
             "moe": cfg.is_moe_layer(j),
+            "mixer": cfg.mixer(j),
         })
     return kinds
+
+
+def has_state(cfg) -> bool:
+    """Whether some sub-layer slot is a Mamba mixer: its paged serving
+    keeps per-sequence recurrent state beside the K/V pages."""
+    return any(cfg.mixer(j) == "mamba" for j in range(period_of(cfg)))
 
 
 def hybrid_groups(cfg):
@@ -68,14 +78,14 @@ def hybrid_groups(cfg):
 # ------------------------------------------------------------ param defs ----
 def _dense_sublayer_defs(cfg, kind) -> dict:
     d = cfg.d_model
-    defs: Dict[str, Any] = {
-        "ln1": norm_def(d),
-        "attn": attn.attn_defs(d, cfg.num_heads, cfg.num_kv_heads,
-                               cfg.resolved_head_dim),
-        "ln2": norm_def(d),
-    }
+    defs: Dict[str, Any] = {"ln1": norm_def(d), "ln2": norm_def(d)}
+    if kind.get("mixer") == "mamba":
+        defs["mamba"] = ssm_lib.mamba_defs(cfg)
+    else:
+        defs["attn"] = attn.attn_defs(d, cfg.num_heads, cfg.num_kv_heads,
+                                      cfg.resolved_head_dim)
     if kind["moe"]:
-        defs["moe"] = moe_lib.moe_defs(d, cfg.moe)
+        defs["moe"] = moe_lib.moe_defs(d, cfg.moe, cfg.activation)
     else:
         defs["ffn"] = ffn_defs(d, cfg.d_ff, cfg.activation)
     if cfg.sandwich_norm:
@@ -120,24 +130,49 @@ def param_defs(cfg) -> dict:
 
 
 # ----------------------------------------------------------------- blocks ----
+def _residual(cfg, x, f):
+    """x + residual_multiplier * f, rounded once (the plain sum where the
+    multiplier is 1)."""
+    if cfg.residual_multiplier == 1.0:
+        return x + f
+    return (x.astype(F32) + f.astype(F32) * cfg.residual_multiplier
+            ).astype(x.dtype)
+
+
+def _norm(x, scale, cfg):
+    """RMSNorm of the residual x, in the compute dtype (a float32 residual
+    stream feeds bf16 matmuls)."""
+    return rms_norm(x, scale, cfg.norm_eps).astype(cfg.dtype)
+
+
+def _route_in(p, x, cfg):
+    """The feed-forward norm of x in float32 (not rounded to x's dtype),
+    which the MoE router scores."""
+    return rms_norm(x.astype(F32), p["ln2"], cfg.norm_eps)
+
+
 def _dense_block_fwd(p, x, kind, cfg, positions, ac: Ac, dot=None,
                      want_cache=True, ring=True):
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    a, cache = attn.attention_fwd(p["attn"], h, kind["attn"], cfg, positions,
-                                  dot=dot, ring=ring)
+    h = _norm(x, p["ln1"], cfg)
+    if "mamba" in p:
+        a, cache = ssm_lib.mamba_block_fwd(p["mamba"], h, cfg, dot=dot)
+    else:
+        a, cache = attn.attention_fwd(p["attn"], h, kind["attn"], cfg,
+                                      positions, dot=dot, ring=ring)
     if cfg.sandwich_norm:
         a = rms_norm(a, p["ln1_post"], cfg.norm_eps)
-    x = ac(x + a, "resid")
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
+    x = ac(_residual(cfg, x, a), "resid")
+    h = _norm(x, p["ln2"], cfg)
     if kind["moe"]:
         f, aux = moe_lib.moe_apply(p["moe"], h, cfg.moe, cfg.activation,
-                                   dot=dot, ac=ac)
+                                   dot=dot, ac=ac,
+                                   x_route=_route_in(p, x, cfg))
     else:
         f, aux = ffn_apply(p["ffn"], h, cfg.activation, dot=dot), 0.0
     if cfg.sandwich_norm:
         f = rms_norm(f, p["ln2_post"], cfg.norm_eps)
-    x = ac(x + f, "resid")
-    if want_cache and ring and kind["attn"] == "local":
+    x = ac(_residual(cfg, x, f), "resid")
+    if want_cache and ring and kind["attn"] == "local" and "attn" in p:
         W = cfg.window_size
         cache = {"k": _to_ring(cache["k"], W), "v": _to_ring(cache["v"], W)}
     return x, (cache if want_cache else None), aux
@@ -153,21 +188,27 @@ def _to_ring(k: jax.Array, W: int) -> jax.Array:
 
 
 def _dense_block_decode(p, x, cache, pos, kind, cfg, dot=None, ac=None):
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    a, ck, cv = attn.attention_decode(p["attn"], h, cache["k"], cache["v"],
-                                      pos, kind["attn"], cfg, dot=dot, ac=ac)
+    h = _norm(x, p["ln1"], cfg)
+    if "mamba" in p:
+        a, new_cache = ssm_lib.mamba_block_decode(p["mamba"], h, cache, cfg,
+                                                  dot=dot)
+    else:
+        a, ck, cv = attn.attention_decode(p["attn"], h, cache["k"],
+                                          cache["v"], pos, kind["attn"], cfg,
+                                          dot=dot, ac=ac)
+        new_cache = {"k": ck, "v": cv}
     if cfg.sandwich_norm:
         a = rms_norm(a, p["ln1_post"], cfg.norm_eps)
-    x = x + a
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
+    x = _residual(cfg, x, a)
+    h = _norm(x, p["ln2"], cfg)
     if kind["moe"]:
         f, _ = moe_lib.moe_apply(p["moe"], h, cfg.moe, cfg.activation,
-                                 dot=dot)
+                                 dot=dot, x_route=_route_in(p, x, cfg))
     else:
         f = ffn_apply(p["ffn"], h, cfg.activation, dot=dot)
     if cfg.sandwich_norm:
         f = rms_norm(f, p["ln2_post"], cfg.norm_eps)
-    return x + f, {"k": ck, "v": cv}
+    return _residual(cfg, x, f), new_cache
 
 
 def _shared_block_fwd(p, x, emb, cfg, positions, ac, dot=None,
@@ -196,6 +237,13 @@ def embed_tokens(params, tokens, cfg):
     x = jnp.take(params["embed"], tokens, axis=0)
     if cfg.scale_embeddings:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+    if cfg.moe:
+        # top-k routing turns near-equal router scores into different
+        # experts: carry the residual stream in float32, so that the
+        # router sees its input without a rounding at every layer
+        x = x.astype(F32)
+    if cfg.embedding_multiplier != 1.0:
+        x = (x.astype(F32) * cfg.embedding_multiplier).astype(x.dtype)
     return x
 
 
@@ -219,6 +267,8 @@ def unembed(params, x, cfg, *, dot=None):
     dot = dot or (lambda a, ww, name: jnp.einsum(
         "bsd,dv->bsv", a, ww, preferred_element_type=jnp.float32))
     logits = softcap(dot(x, w, "lm_head").astype(F32), cfg.logit_softcap)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.padded_vocab != cfg.vocab_size:  # mask vocab-padding columns
         pad_mask = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
         logits = jnp.where(pad_mask, logits, -1e9)
@@ -337,7 +387,7 @@ def forward(params, batch, cfg, *, want_cache: bool, remat: bool = False,
         if want_cache:
             caches.update(gcaches)
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x = _norm(x, params["final_norm"], cfg)
     if unembed_mode == "none":
         return x, (caches if want_cache else None), aux_total, loss_mask
     if unembed_mode == "last":
@@ -406,7 +456,7 @@ def decode_step(params, cache, token, pos, cfg, *, ac: Ac = _identity_ac,
                             {k: cache[k] for k in cache if k.startswith("sub")}))
         new_cache = gcaches
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x = _norm(x, params["final_norm"], cfg)
     logits = unembed(params, x, cfg, dot=dot)
     return logits, new_cache
 
@@ -414,26 +464,71 @@ def decode_step(params, cache, token, pos, cfg, *, ac: Ac = _identity_ac,
 # ----------------------------------------------------------- paged decode ----
 def _dense_block_decode_paged(p, x, pool_kv, page_table, positions, kind, cfg,
                               dot=None, ac=None, kernel="auto"):
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
+    h = _norm(x, p["ln1"], cfg)
     a, ck, cv = attn.attention_decode_paged(
         p["attn"], h, pool_kv["k"], pool_kv["v"], page_table, positions,
         kind["attn"], cfg, dot=dot, ac=ac, kernel=kernel)
     if cfg.sandwich_norm:
         a = rms_norm(a, p["ln1_post"], cfg.norm_eps)
-    x = x + a
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
+    x = _residual(cfg, x, a)
+    h = _norm(x, p["ln2"], cfg)
     if kind["moe"]:
-        f, _ = moe_lib.moe_apply(p["moe"], h, cfg.moe, cfg.activation,
-                                 dot=dot)
+        f = moe_lib.moe_serve(p["moe"], h, cfg.moe, cfg.activation, dot=dot,
+                              x_route=_route_in(p, x, cfg))
     else:
         f = ffn_apply(p["ffn"], h, cfg.activation, dot=dot)
     if cfg.sandwich_norm:
         f = rms_norm(f, p["ln2_post"], cfg.norm_eps)
-    return x + f, {"k": ck, "v": cv}
+    return _residual(cfg, x, f), {"k": ck, "v": cv}
+
+
+PAGED_FAMILIES = ("dense", "moe", "vlm", "hybrid_moe")
+
+
+def _check_paged(cfg, what: str) -> None:
+    if cfg.family not in PAGED_FAMILIES:
+        raise NotImplementedError(
+            f"{what} supports the families {PAGED_FAMILIES} only, "
+            f"got {cfg.family!r}")
+
+
+def _paged_stateful(params, pool, x, cfg, attend, recur, dot):
+    """Every layer of a model with Mamba sub-slots against its pool.
+    ``attend(p, h, kv, off, kind)`` and ``recur(p, h, st, off)`` run one
+    layer's mixer on that sub-slot's pool leaves, flattened over the
+    groups: group g's pages (or state rows) start at ``off``. The groups
+    are unrolled and the flattened pools carried through them, so every
+    write lands in place and no pool is sliced out or stacked again.
+    Returns (x, new_pool)."""
+    P = period_of(cfg)
+    kinds = sublayer_kinds(cfg)
+    flat = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), pool)
+    for g in range(cfg.num_layers // P):
+        for j in range(P):
+            name = f"sub{j}"
+            p = jax.tree.map(lambda a: a[g], params["blocks"][name])
+            h = _norm(x, p["ln1"], cfg)
+            if kinds[j]["mixer"] == "mamba":
+                off = g * pool[name]["state"].shape[1]
+                a, flat[name] = recur(p["mamba"], h, flat[name], off)
+            else:
+                off = g * pool[name]["k"].shape[1]
+                a, flat[name] = attend(p["attn"], h, flat[name], off,
+                                       kinds[j])
+            x = _residual(cfg, x, a)
+            h = _norm(x, p["ln2"], cfg)
+            if kinds[j]["moe"]:
+                f = moe_lib.moe_serve(p["moe"], h, cfg.moe, cfg.activation,
+                                      dot=dot, x_route=_route_in(p, x, cfg))
+            else:
+                f = ffn_apply(p["ffn"], h, cfg.activation, dot=dot)
+            x = _residual(cfg, x, f)
+    return x, jax.tree.map(lambda a, o: a.reshape(o.shape), flat, pool)
 
 
 def decode_step_paged(params, pool, page_table, token, positions, cfg, *,
-                      ac: Ac = _identity_ac, dot=None, kernel="auto"):
+                      rows=None, ac: Ac = _identity_ac, dot=None,
+                      kernel="auto"):
     """Batched slot-indexed decode against a paged KV pool.
 
     token (B,1) int32; positions (B,) int32 per-sequence absolute positions
@@ -443,13 +538,29 @@ def decode_step_paged(params, pool, page_table, token, positions, cfg, *,
     ``kernel`` selects the paged-attention path (see attention_decode_paged)
     — every choice walks pages block-by-block; no layer materializes the
     dense chronological KV view, and local layers trim the walk to their
-    window. Returns (logits (B,1,V), new_pool).
+    window. With Mamba sub-slots, ``rows`` (B,) int32 names each batch
+    row's state slot (idle rows: the scratch slot). Returns (logits
+    (B,1,V), new_pool).
     """
-    if cfg.family not in ("dense", "moe", "vlm"):
-        raise NotImplementedError(
-            f"paged decode supports attention-cache families only, "
-            f"got {cfg.family!r}")
+    _check_paged(cfg, "paged decode")
     x = embed_tokens(params, token, cfg)
+    if has_state(cfg):
+        def attend(p, h, kv, off, kind):
+            a, k, v = attn.attention_decode_paged(
+                p, h, kv["k"], kv["v"], page_table + off, positions,
+                kind["attn"], cfg, dot=dot, kernel=kernel)
+            return a, {"k": k, "v": v}
+
+        def recur(p, h, st, off):
+            a, conv, state = ssm_lib.mamba_decode_rows(
+                p, h, st["conv"], st["state"], rows + off, cfg, dot=dot,
+                kernel=kernel)
+            return a, {"conv": conv, "state": state}
+
+        x, new_pool = _paged_stateful(params, pool, x, cfg, attend, recur,
+                                      dot)
+        x = _norm(x, params["final_norm"], cfg)
+        return unembed(params, x, cfg, dot=dot), new_pool
     P = period_of(cfg)
     kinds = sublayer_kinds(cfg)
 
@@ -463,7 +574,7 @@ def decode_step_paged(params, pool, page_table, token, positions, cfg, *,
         return h, new_g
 
     x, new_pool = jax.lax.scan(group_body, x, (params["blocks"], pool))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x = _norm(x, params["final_norm"], cfg)
     logits = unembed(params, x, cfg, dot=dot)
     return logits, new_pool
 
@@ -471,26 +582,26 @@ def decode_step_paged(params, pool, page_table, token, positions, cfg, *,
 # --------------------------------------------------------- paged prefill ----
 def _dense_block_prefill_paged(p, x, pool_kv, page_table, positions, kind,
                                cfg, dot=None, kernel="auto"):
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
+    h = _norm(x, p["ln1"], cfg)
     a, ck, cv = attn.attention_prefill_paged(
         p["attn"], h, pool_kv["k"], pool_kv["v"], page_table, positions,
         kind["attn"], cfg, dot=dot, kernel=kernel)
     if cfg.sandwich_norm:
         a = rms_norm(a, p["ln1_post"], cfg.norm_eps)
-    x = x + a
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
+    x = _residual(cfg, x, a)
+    h = _norm(x, p["ln2"], cfg)
     if kind["moe"]:
-        f, _ = moe_lib.moe_apply(p["moe"], h, cfg.moe, cfg.activation,
-                                 dot=dot)
+        f = moe_lib.moe_serve(p["moe"], h, cfg.moe, cfg.activation, dot=dot,
+                              x_route=_route_in(p, x, cfg))
     else:
         f = ffn_apply(p["ffn"], h, cfg.activation, dot=dot)
     if cfg.sandwich_norm:
         f = rms_norm(f, p["ln2_post"], cfg.norm_eps)
-    return x + f, {"k": ck, "v": cv}
+    return _residual(cfg, x, f), {"k": ck, "v": cv}
 
 
 def prefill_chunk_paged(params, pool, page_table, tokens, positions, cfg, *,
-                        dot=None, kernel="auto"):
+                        rows=None, lengths=None, dot=None, kernel="auto"):
     """One chunked-prefill step: run ``tokens`` (B, Sq) — a contiguous
     prompt chunk whose first token sits at absolute position
     ``positions[b]`` — through every layer, writing each layer's chunk K/V
@@ -502,12 +613,38 @@ def prefill_chunk_paged(params, pool, page_table, tokens, positions, cfg, *,
     Returns (hidden (B, Sq, D) final-norm hidden states, new_pool) — the
     caller unembeds only the rows it needs (the last real prompt position
     of the final chunk; intermediate chunks need no logits at all).
+
+    With Mamba sub-slots, sequence b's conv tail and state live in slot
+    ``rows[b]``; a chunk at position 0 starts from zeros, a later one from
+    the slot, and only its first ``lengths[b]`` rows (the real prompt
+    tokens) advance the state.
     """
-    if cfg.family not in ("dense", "moe", "vlm"):
-        raise NotImplementedError(
-            f"paged prefill supports attention-cache families only, "
-            f"got {cfg.family!r}")
+    _check_paged(cfg, "paged prefill")
     x = embed_tokens(params, tokens, cfg)
+    if has_state(cfg):
+        def attend(p, h, kv, off, kind):
+            a, k, v = attn.attention_prefill_paged(
+                p, h, kv["k"], kv["v"], page_table + off, positions,
+                kind["attn"], cfg, dot=dot, kernel=kernel)
+            return a, {"k": k, "v": v}
+
+        def recur(p, h, st, off):
+            r = rows + off
+            fresh = positions == 0
+            conv, state = st["conv"], st["state"]
+            cache = {"conv": jnp.where(fresh[:, None, None], 0, conv[r]),
+                     "state": jnp.where(fresh[:, None, None, None], 0.0,
+                                        state[r])}
+            a, new = ssm_lib.mamba_block_fwd(p, h, cfg, dot=dot, cache=cache,
+                                             lengths=lengths)
+            return a, {"conv": conv.at[r].set(new["conv"].astype(conv.dtype),
+                                              mode="promise_in_bounds"),
+                       "state": state.at[r].set(new["state"],
+                                                mode="promise_in_bounds")}
+
+        x, new_pool = _paged_stateful(params, pool, x, cfg, attend, recur,
+                                      dot)
+        return _norm(x, params["final_norm"], cfg), new_pool
     P = period_of(cfg)
     kinds = sublayer_kinds(cfg)
 
@@ -521,7 +658,7 @@ def prefill_chunk_paged(params, pool, page_table, tokens, positions, cfg, *,
         return h, new_g
 
     x, new_pool = jax.lax.scan(group_body, x, (params["blocks"], pool))
-    return rms_norm(x, params["final_norm"], cfg.norm_eps), new_pool
+    return _norm(x, params["final_norm"], cfg), new_pool
 
 
 def normalize_kv_bits(cfg, kv_bits) -> Optional[Tuple[int, ...]]:
@@ -567,7 +704,8 @@ def normalize_kv_bits(cfg, kv_bits) -> Optional[Tuple[int, ...]]:
     return bits
 
 
-def pool_specs(cfg, num_pages: int, page_size: int, kv_bits=None):
+def pool_specs(cfg, num_pages: int, page_size: int, kv_bits=None,
+               state_slots: int = 0):
     """Abstract paged-KV-pool pytree: per sub-layer slot, k/v pools of shape
     (n_groups, num_pages, K, page_size, hd) — kv-head-major within a page,
     so one kv head's (page_size, hd) tile is contiguous and is a block the
@@ -579,18 +717,27 @@ def pool_specs(cfg, num_pages: int, page_size: int, kv_bits=None):
     (serving/engine/scheduler.py::trim_window).
 
     ``kv_bits`` (see normalize_kv_bits) selects the HAQ KV-quantized layout
-    per sub-layer slot: 16 keeps the bf16 arrays; 8/4 store
+    per sub-layer slot: 16 keeps the arrays in the configuration's dtype
+    (bf16); 8/4 store
     ``{"q": int8 (n_groups, num_pages, K, page_size, hd_store),
        "scale": fp32 (n_groups, num_pages, K, page_size)}``
     with hd_store = hd for int8 and hd//2 for int4 (two codes per byte
     packed along head_dim). Scales are per page slot (token) and per kv
     head — each physical page carries its own (K, page_size) scale tile, so
     quantize-on-write never re-scales resident tokens (see
-    serving/kvquant)."""
-    if cfg.family not in ("dense", "moe", "vlm"):
-        raise NotImplementedError(
-            f"paged KV pool supports attention-cache families only, "
-            f"got {cfg.family!r}")
+    serving/kvquant).
+
+    Mamba sub-slots hold no pages: each keeps ``state_slots`` rows (the
+    engine's batch slots and a scratch row) of recurrent state beside the
+    pages, ``{"conv": (n_groups, state_slots, W-1, d_conv), "state":
+    fp32 (n_groups, state_slots, H, P, N)}``."""
+    _check_paged(cfg, "the paged pool")
+    if has_state(cfg):
+        if kv_bits is not None:
+            raise NotImplementedError(
+                "quantized K/V pages beside recurrent state are not built")
+        if state_slots < 1:
+            raise ValueError("a model with Mamba layers needs state_slots")
     hd = cfg.resolved_head_dim
     K = cfg.num_kv_heads
     P = period_of(cfg)
@@ -600,7 +747,7 @@ def pool_specs(cfg, num_pages: int, page_size: int, kv_bits=None):
     def kv_spec(b):
         if b == 16:
             return jax.ShapeDtypeStruct(
-                (n_groups, num_pages, K, page_size, hd), jnp.bfloat16)
+                (n_groups, num_pages, K, page_size, hd), cfg.dtype)
         hd_store = hd if b == 8 else hd // 2
         return {
             "q": jax.ShapeDtypeStruct(
@@ -609,7 +756,13 @@ def pool_specs(cfg, num_pages: int, page_size: int, kv_bits=None):
                 (n_groups, num_pages, K, page_size), jnp.float32),
         }
 
-    return {f"sub{j}": {"k": kv_spec(bits[j]), "v": kv_spec(bits[j])}
+    def state_spec():
+        one = ssm_lib.mamba_cache_spec(cfg, state_slots)
+        return {k: jax.ShapeDtypeStruct((n_groups,) + v.shape, v.dtype)
+                for k, v in one.items()}
+
+    return {f"sub{j}": state_spec() if cfg.mixer(j) == "mamba"
+            else {"k": kv_spec(bits[j]), "v": kv_spec(bits[j])}
             for j in range(P)}
 
 
@@ -669,6 +822,12 @@ def cache_specs(cfg, batch: int, seq_len: int):
     n_groups = cfg.num_layers // P
     out = {}
     for j in range(P):
+        if kinds[j]["mixer"] == "mamba":
+            out[f"sub{j}"] = jax.tree.map(
+                lambda s: jax.ShapeDtypeStruct((n_groups,) + s.shape,
+                                               s.dtype),
+                ssm_lib.mamba_cache_spec(cfg, batch))
+            continue
         T = attn.cache_len_for(kinds[j]["attn"], cfg, seq_len)
         out[f"sub{j}"] = kv(T, (n_groups,))
     return out
@@ -691,4 +850,5 @@ def cache_axes(cfg):
     if cfg.family == "hybrid":
         return {"mamba": mamba_ax, "shared": dict(kv_ax)}
     P = period_of(cfg)
-    return {f"sub{j}": dict(kv_ax) for j in range(P)}
+    return {f"sub{j}": dict(mamba_ax) if cfg.mixer(j) == "mamba"
+            else dict(kv_ax) for j in range(P)}

@@ -36,6 +36,18 @@ so stale KV from a previous owner stays behind the mask. The legacy
 worst-case policy — ``ceil((prompt + max_new) / page_size)`` pages reserved
 at admission, no preemption — remains available as ``reserve_upfront``.
 
+Recurrent state beside the pages
+--------------------------------
+A model with Mamba-2 layers (granite-4.0-h: ``layer_types``) keeps no pages
+for them. Each Mamba sub-slot of the pool holds ``max_batch + 1`` rows of
+per-sequence state instead — the fp32 SSM state and the conv tail — and a
+sequence's state lives in its batch slot's row; idle decode rows update
+the last, scratch row. A sequence's first prompt chunk, at position 0,
+starts from zero state, so a reused slot and a preempted, recomputed
+sequence start clean; only a chunk's real tokens advance the state. The
+state is served on one device, with chunked prefill and a bf16 K/V pool;
+admission reserves every slot's state before it sizes the pages.
+
 Chunked-prefill lifecycle
 -------------------------
 A sequence's prompt enters the pool in ``policy.prefill_chunk``-token

@@ -81,12 +81,44 @@ def kv_bytes_per_token(cfg, kv_bits=None) -> int:
     K, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     total = 0
     for i in range(cfg.num_layers):
+        if cfg.mixer(i) != "attention":
+            continue                         # Mamba layers cache no K/V
         b = _kv_bits_for_layer(kv_bits, i)
         per = 2 * K * (hd * b // 8)
         if b < 16:
             per += 2 * K * 4                 # fp32 scale tiles
         total += per
     return total
+
+
+def state_bytes_per_seq(cfg) -> int:
+    """Recurrent state one sequence holds whatever its length: per Mamba
+    layer the fp32 SSM state (heads x head_dim x d_state) and the bf16
+    conv tail (conv_width - 1 inputs of the conv's channels). 0 for a
+    model without Mamba layers."""
+    if not cfg.ssm:
+        return 0
+    s = cfg.ssm
+    per = cfg.d_inner * s.d_state * 4 \
+        + (s.conv_width - 1) * (cfg.d_inner + 2 * s.n_groups * s.d_state) * 2
+    return per * sum(cfg.mixer(i) == "mamba" for i in range(cfg.num_layers))
+
+
+def _mamba_latency(cfg, batch: int, q_len: int, hw, tp: int,
+                   w_bits) -> float:
+    """One Mamba-2 mixer: the in/out projections, the SSD scan over the
+    call's tokens, and every sequence's state read and written once."""
+    d, s = cfg.d_model, cfg.ssm
+    di, N = cfg.d_inner, s.d_state
+    tokens = batch * q_len
+    t = float(hwm.linear_cost(tokens, d, 2 * di + 2 * s.n_groups * N
+                              + cfg.ssm_heads, tp=tp)
+              .latency(hw, w_bits=w_bits))
+    t += float(hwm.linear_cost(tokens, di, d, tp=tp)
+               .latency(hw, w_bits=w_bits))
+    t += float(hwm.ssd_cost(batch, q_len, di, N, min(s.chunk, q_len))
+               .latency(hw))
+    return t + float(hwm.ssm_state_cost(batch, di, N).latency(hw))
 
 
 def _ffn_terms(cfg, i: int, tokens: int, hw, tp: int, w_bits):
@@ -99,9 +131,14 @@ def _ffn_terms(cfg, i: int, tokens: int, hw, tp: int, w_bits):
     if cfg.is_moe_layer(i):
         m = cfg.moe
         mc = hwm.moe_cost(tokens, cfg.d_model, m.d_ff_expert,
-                          m.num_experts, m.experts_per_token)
-        return 0.0, float(mc.latency(hw, w_bits=w_bits)), \
-            float(mc.weight_bytes) * w_bits / 16.0
+                          m.held, m.experts_per_token)
+        lat = float(mc.latency(hw, w_bits=w_bits))
+        wb = float(mc.weight_bytes)
+        if m.d_ff_shared:
+            sh = hwm.linear_cost(tokens, cfg.d_model, m.d_ff_shared, tp=tp)
+            lat += 3.0 * float(sh.latency(hw, w_bits=w_bits))
+            wb += 3.0 * float(sh.weight_bytes)
+        return 0.0, lat, wb * w_bits / 16.0
     lin = hwm.linear_cost(tokens, cfg.d_model, cfg.d_ff, tp=tp)
     lat = float(lin.latency(hw, w_bits=w_bits))
     return 2.0 * lat, lat, float(lin.weight_bytes) * w_bits / 16.0
@@ -136,6 +173,10 @@ def step_latency(cfg, batch: int, q_len: int, ctx: int, hw: hwm.Hardware,
     decode = q_len == 1
     t = 0.0
     for i in range(cfg.num_layers):
+        if cfg.mixer(i) == "mamba":     # one device only: no mesh terms
+            t += _mamba_latency(cfg, batch, q_len, hw, tp, w_bits)
+            t += sum(_ffn_terms(cfg, i, tokens, hw, tp, w_bits)[:2])
+            continue
         kind = cfg.attn_pattern[i % len(cfg.attn_pattern)]
         window = cfg.window_size if kind == "local" else 0
         split = float(hwm.linear_cost(tokens, d, (H + 2 * K) * hd, tp=tp)
@@ -268,7 +309,8 @@ def derive_policy(cfg, hw: hwm.Hardware, *, max_model_len: int,
     if mesh_model < 1 or mesh_data < 1:
         raise ValueError(f"mesh axes must be >= 1, got "
                          f"model={mesh_model} data={mesh_data}")
-    if cfg.is_encdec or cfg.family not in ("dense", "moe", "vlm"):
+    if cfg.is_encdec or cfg.family not in ("dense", "moe", "vlm",
+                                           "hybrid_moe"):
         raise NotImplementedError(
             f"admission policy sizes attention KV pools; {cfg.name} "
             f"(family={cfg.family!r}) is an open item (ROADMAP)")
@@ -280,7 +322,8 @@ def derive_policy(cfg, hw: hwm.Hardware, *, max_model_len: int,
     # replicates over data and splits kv_heads over model.
     hbm_total = hw.hbm_bytes * hw.chips * hbm_util
     per_tok = kv_bytes_per_token(cfg, kv_bits)
-    one_seq_kv = per_tok * max_model_len / mesh_model
+    state_seq = state_bytes_per_seq(cfg)
+    one_seq_kv = per_tok * max_model_len / mesh_model + state_seq
 
     # HAQ escalation: shrink weights until weights + one sequence fit.
     quant_bits = 16
@@ -297,15 +340,23 @@ def derive_policy(cfg, hw: hwm.Hardware, *, max_model_len: int,
     kv_budget = hbm_total - param_bytes * quant_bits / 16.0 / devices
     page_bytes = page_size * per_tok / mesh_model   # per-shard page slice
     pages_per_seq = -(-max_model_len // page_size)
+    # expected (not worst-case) footprint: lazy page growth + preemption
+    # absorb the tail where every sequence runs to max_model_len at once.
+    pages_expected = max(
+        -(-int(expected_occupancy * max_model_len) // page_size), 1)
+    if state_seq:
+        # every batch slot, and the scratch row, holds its recurrent state
+        # whatever its length: reserve the slots the budget allows at the
+        # expected footprint (at most the cap) before sizing the pages
+        slots = (kv_budget - state_seq) \
+            // (state_seq + pages_expected * page_bytes)
+        kv_budget -= state_seq * (min(max(int(slots), 1), max_batch_cap)
+                                  + 1)
     # floor at one full sequence: the quant check above guarantees weights +
     # one_seq_kv fit, but page-granular rounding could otherwise leave the
     # pool a partial page short of a max-length request, which the scheduler
     # would wait on forever. Overshoot is < 2 pages (incl. scratch page 0).
     num_pages = max(int(kv_budget // page_bytes), pages_per_seq) + 1
-    # expected (not worst-case) footprint: lazy page growth + preemption
-    # absorb the tail where every sequence runs to max_model_len at once.
-    pages_expected = max(
-        -(-int(expected_occupancy * max_model_len) // page_size), 1)
     mem_batch = max((num_pages - 1) // pages_expected, 1)
 
     # Decode-latency roofline: largest batch meeting the SLO (monotonic).

@@ -72,7 +72,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.transformer import normalize_kv_bits, sublayer_kinds
+from repro.models.transformer import (PAGED_FAMILIES, has_state,
+                                      normalize_kv_bits, sublayer_kinds)
 from repro.serving.engine.admission import AdmissionPolicy, \
     RooflinePredictor
 from repro.serving.engine.pool import JitLRU, PagedKVPool, quiet_donation
@@ -100,12 +101,21 @@ class Engine:
                  telemetry: Optional[Telemetry] = None,
                  roofline_scales=None):
         cfg = model.cfg
-        if cfg.is_encdec or cfg.family not in ("dense", "moe") \
-                or cfg.frontend != "none":
+        if cfg.is_encdec or cfg.family not in PAGED_FAMILIES \
+                or cfg.family == "vlm" or cfg.frontend != "none":
             raise NotImplementedError(
                 f"engine serves decoder-only attention-cache LMs; "
                 f"{cfg.name} (family={cfg.family!r}, "
                 f"frontend={cfg.frontend!r}) is an open item (ROADMAP)")
+        # Mamba layers keep per-sequence state in slot rows beside the
+        # pages (batch slot s in row s, idle decode rows in the scratch row
+        # max_batch); only the chunked path carries it
+        self._stateful = has_state(cfg)
+        if self._stateful and (not chunked_prefill or mesh is not None
+                               or policy.kv_bits is not None):
+            raise NotImplementedError(
+                f"{cfg.name} keeps recurrent state: served on one device "
+                f"with chunked prefill and a bf16 K/V pool only")
         self.model = model
         self.policy = policy
         self.temperature = temperature
@@ -157,8 +167,10 @@ class Engine:
             spmd = sharded.SpmdEngine(model, mesh, kv_bits=self.kv_bits,
                                       kernel=paged_kernel, dot=dot)
             params = self.params = spmd.shard_params(params)
-        self.kv = PagedKVPool(model, num_pages, policy.page_size,
-                              kv_bits=self.kv_bits, spmd=spmd)
+        self.kv = PagedKVPool(
+            model, num_pages, policy.page_size, kv_bits=self.kv_bits,
+            spmd=spmd, state_slots=policy.max_batch + 1 if self._stateful
+            else 0)
         self.scheduler = Scheduler(self.kv.allocator, policy.max_batch,
                                    policy.max_model_len,
                                    reserve_upfront=reserve_upfront,
@@ -196,17 +208,31 @@ class Engine:
         # every bucket's trace alive for the engine's lifetime).
         self._prefill_jits = JitLRU(self.PREFILL_JIT_CAP)
         self.chunked = chunked_prefill
-        if spmd is None:
+        if spmd is None and self._stateful:
+            # the state slot of each row, and the chunk's real tokens
+            self._decode = jax.jit(
+                lambda p, pool, pt, tok, pos, rows: model.decode_step_paged(
+                    p, pool, pt, tok, pos, rows=rows, dot=dot,
+                    kernel=paged_kernel),
+                donate_argnums=(1,))
+            self._chunk_prefill = jax.jit(
+                lambda p, pool, pt, toks, pos, rows, n:
+                model.prefill_chunk_paged(p, pool, pt, toks, pos, rows=rows,
+                                          lengths=n, dot=dot,
+                                          kernel=paged_kernel),
+                donate_argnums=(1,))
+        elif spmd is None:
             self._decode = jax.jit(
                 lambda p, pool, pt, tok, pos: model.decode_step_paged(
                     p, pool, pt, tok, pos, dot=dot, kernel=paged_kernel),
                 donate_argnums=(1,))
-            self._make_prefill = lambda: jax.jit(
-                lambda p, t, i: prefill_body(p, t, i, dot))
             self._chunk_prefill = jax.jit(
                 lambda p, pool, pt, toks, pos: model.prefill_chunk_paged(
                     p, pool, pt, toks, pos, dot=dot, kernel=paged_kernel),
                 donate_argnums=(1,))
+        if spmd is None:
+            self._make_prefill = lambda: jax.jit(
+                lambda p, t, i: prefill_body(p, t, i, dot))
             self._unembed_row = jax.jit(
                 lambda p, h, idx: model.unembed(
                     p, jnp.take_along_axis(h, idx.reshape(1, 1, 1), axis=1),
@@ -460,11 +486,15 @@ class Engine:
                 maxp = self.policy.pages_per_seq
                 pt = np.zeros((1, maxp), np.int32)
                 pt[0, :len(seq.pages)] = seq.pages
+                state = (jnp.asarray([seq.slot], jnp.int32),
+                         jnp.asarray([end - start], jnp.int32)
+                         ) if self._stateful else ()
             t_start = time.monotonic()
             with span("engine.chunk.dispatch"), quiet_donation():
                 hidden, self.kv.pool = self._chunk_prefill(
                     self.params, self.kv.pool, jnp.asarray(pt),
-                    jnp.asarray(toks), jnp.asarray([start], jnp.int32))
+                    jnp.asarray(toks), jnp.asarray([start], jnp.int32),
+                    *state)
             # sync before the step's stall timer stops: dispatch is async,
             # and an unblocked intermediate chunk would bill its compute to
             # the decode tick instead of the stall it actually causes.
@@ -510,15 +540,18 @@ class Engine:
                 positions = np.full((B,), min(s.pos for s in ready),
                                     np.int32)
                 pt = np.zeros((B, maxp), np.int32)       # 0 -> scratch page
+                rows = np.full((B,), B, np.int32)        # B -> scratch row
                 for seq in ready:
                     tokens[seq.slot, 0] = seq.last_token
                     positions[seq.slot] = seq.pos
                     pt[seq.slot, :len(seq.pages)] = seq.pages
+                    rows[seq.slot] = seq.slot
+                state = (jnp.asarray(rows),) if self._stateful else ()
             t_start = time.monotonic()
             with span("engine.decode.dispatch"), quiet_donation():
                 logits, self.kv.pool = self._decode(
                     self.params, self.kv.pool, jnp.asarray(pt),
-                    jnp.asarray(tokens), jnp.asarray(positions))
+                    jnp.asarray(tokens), jnp.asarray(positions), *state)
             # fence before the host transfer so the tick's measured
             # duration is dispatch + compute, not whenever the async
             # stream drains

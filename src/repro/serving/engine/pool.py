@@ -119,6 +119,10 @@ class JitLRU:
 class PagedKVPool:
     """Device pool arrays + the allocator that tracks their occupancy.
 
+    For models with Mamba layers the pool also holds ``state_slots`` rows
+    of recurrent state per Mamba layer (transformer.pool_specs); rows are
+    owned by batch slots, not by the allocator.
+
     ``kv_bits`` (already normalized — see transformer.normalize_kv_bits)
     selects the HAQ KV-quantized pool layout per sub-layer slot
     (serving/kvquant): quantized slots store int8/int4 codes plus
@@ -128,11 +132,12 @@ class PagedKVPool:
     WRITE_JIT_CAP = 8   # LRU cap on per-(n_pages, cache_len) writer jits
 
     def __init__(self, model, num_pages: int, page_size: int, *,
-                 kv_bits=None, spmd=None):
+                 kv_bits=None, spmd=None, state_slots: int = 0):
         self.allocator = PageAllocator(num_pages, page_size)
         self.page_size = page_size
         self.kv_bits = kv_bits
-        self.pool = model.init_pool(num_pages, page_size, kv_bits=kv_bits)
+        self.pool = model.init_pool(num_pages, page_size, kv_bits=kv_bits,
+                                    state_slots=state_slots)
         # SPMD serving (engine/sharded.py): the pool lives sharded on
         # kv_heads over the mesh's model axis (every device holds a
         # 1/N-head slice of every page) and the span writer becomes its

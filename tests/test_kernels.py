@@ -207,3 +207,31 @@ def test_quant_dot_hook_end_to_end():
     want = dot_f(x, w, "site")
     rel = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
     assert rel < 1e-3, rel
+
+
+@pytest.mark.parametrize("H,P,N,G,B", [(8, 32, 16, 1, 3), (64, 64, 128, 2, 5)])
+def test_ssm_decode_kernel_matches_ref(H, P, N, G, B):
+    """The Mamba-2 decode state update (interpret mode) against its jnp
+    twin: live rows' y and states to float32 rounding; idle rows that
+    share the scratch slot leave every other slot as it was."""
+    from repro.kernels import ssm_decode as sd
+    R = B + 2
+    k = jax.random.split(jax.random.PRNGKey(0), 7)
+    state = jax.random.normal(k[0], (R, H, P, N))
+    x = jax.random.normal(k[1], (B, H, P))
+    dt = jax.nn.softplus(jax.random.normal(k[2], (B, H)))
+    a = -jnp.exp(jax.random.normal(k[3], (H,)))
+    Bm = jax.random.normal(k[4], (B, G, N))
+    Cm = jax.random.normal(k[5], (B, G, N))
+    D = jax.random.normal(k[6], (H,))
+    scratch = R - 1
+    rows = jnp.asarray([2, scratch] + list(range(3, B + 1)), jnp.int32)[:B]
+    live = jnp.asarray([i for i in range(B) if int(rows[i]) != scratch])
+    y, got = sd.ssm_decode_fwd(state, rows, x, dt, a, Bm, Cm, D,
+                               interpret=True)
+    y_ref, want = ref.ssm_decode_ref(state, rows, x, dt, a, Bm, Cm, D)
+    assert jnp.allclose(y[live], y_ref[live], rtol=1e-5, atol=1e-4)
+    assert jnp.allclose(got[:scratch], want[:scratch], rtol=1e-5, atol=1e-5)
+    untouched = jnp.asarray([r for r in range(scratch)
+                             if r not in set(rows.tolist())])
+    assert jnp.array_equal(got[untouched], state[untouched])

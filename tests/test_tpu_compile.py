@@ -135,6 +135,31 @@ def test_paged_kernel_hlo_names(one_chip, name):
         calls[0].startswith(f"%{name} "), calls[0][:120]
 
 
+def test_ssm_decode_kernel_compiles_with_its_name(one_chip):
+    """The Mamba-2 decode state update at granite-4.0-h-small widths (128
+    heads of 64, d_state 128) over 128 live rows and a scratch slot, in a
+    jitted step of another name: it compiles, keeps its pinned HLO name
+    for the device trace, and updates the state pool in place."""
+    from repro.kernels import ssm_decode as sd
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    B, H, P, N = 128, 128, 64, 128
+    args = (f32(B + 1, H, P, N),
+            jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip),
+            f32(B, H, P), f32(B, H), f32(H), f32(B, 1, N), f32(B, 1, N),
+            f32(H))
+
+    def serving_step(state, *a):
+        y, state = sd.ssm_decode_fwd(state, *a)
+        return y * 2, state
+
+    c = jax.jit(serving_step, donate_argnums=(0,)).lower(*args).compile()
+    calls = [ln.strip() for ln in c.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    assert calls[0].startswith("%ssm_decode_fwd"), calls[0][:120]
+    assert c.memory_analysis().alias_size_in_bytes >= 4 * (B + 1) * H * P * N
+
+
 @pytest.fixture(scope="module")
 def full_width(one_chip):
     """gemma2-2b's abstract parameters and page pool on one described
@@ -174,6 +199,51 @@ def test_full_width_serving_step_compiles_and_fits(full_width, monkeypatch,
         *args).compile()
     assert "tpu_custom_call" in c.as_text()
     m = c.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert used <= V5E_EDGE.hbm_bytes, used
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk_prefill"])
+def test_full_width_hybrid_step_compiles_and_fits(one_chip, monkeypatch,
+                                                  step):
+    """granite-4.0-h-small's benchmark cut (one period of 10 layers, 9 of
+    72 experts held) at 128 in-flight sequences: the engine's decode step
+    (the paged-attention and SSM-decode kernels in it) and its 512-token
+    chunk step compile for one v5e and fit its HBM beside the state rows
+    of every batch slot and the page pool."""
+    from repro.serving.engine.admission import state_bytes_per_seq
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    base = get_config("granite-4.0-h-small")
+    cfg = base.replace(num_layers=10,
+                       moe=dataclasses.replace(base.moe, num_held=9))
+    model = build_model(cfg)
+    B, maxp = 128, 4096 // 16
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip)
+    params = jax.tree.map(place, model.abstract_params())
+    pool = jax.tree.map(place, model.pool_specs(B * maxp + 1, 16,
+                                                state_slots=B + 1))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    if step == "decode":
+        fn = lambda p, pool, pt, t, pos, rows: model.decode_step_paged(
+            p, pool, pt, t, pos, rows=rows)
+        args = (params, pool, i32(B, maxp), i32(B, 1), i32(B), i32(B))
+        kernels = {"paged_attention_fwd", "ssm_decode_fwd"}
+    else:
+        fn = lambda p, pool, pt, t, pos, rows, n: model.prefill_chunk_paged(
+            p, pool, pt, t, pos, rows=rows, lengths=n)
+        args = (params, pool, i32(1, maxp), i32(1, 512), i32(1), i32(1),
+                i32(1))
+        kernels = {"paged_prefill_fwd"}
+    c = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text = c.as_text()
+    found = {ln.strip()[1:].split(" ")[0].rsplit(".", 1)[0]
+             for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln}
+    assert found == kernels, found
+    m = c.memory_analysis()
+    assert m.alias_size_in_bytes >= (B + 1) * state_bytes_per_seq(cfg)
     used = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert used <= V5E_EDGE.hbm_bytes, used
