@@ -87,16 +87,15 @@ class Model:
         return transformer.unembed(params, hidden, self.cfg, dot=dot)
 
     def decode_step_paged(self, params, pool, page_table, token, positions,
-                          *, rows=None, ac=None, dot=None, kernel="auto"):
+                          *, rows=None, dot=None, kernel="auto"):
         """Continuous-batching decode: per-sequence positions, KV walked
         page-by-page through the page table (see serving/engine). ``kernel``
         picks the paged-attention path: "auto" (Pallas on TPU, pure-JAX
         block walk elsewhere), "pallas", or "ref". ``rows``: each batch
         row's recurrent-state slot, for models with Mamba layers."""
-        ac = ac or transformer._identity_ac
         return transformer.decode_step_paged(params, pool, page_table, token,
                                              positions, self.cfg, rows=rows,
-                                             ac=ac, dot=dot, kernel=kernel)
+                                             dot=dot, kernel=kernel)
 
     def prefill_chunk_paged(self, params, pool, page_table, tokens,
                             positions, *, rows=None, lengths=None, dot=None,

@@ -197,9 +197,68 @@ def attention_decode(p, x, cache_k, cache_v, pos, kind: str, cfg, *,
     return out, cache_k, cache_v
 
 
+def write_pages(pool_k, pool_v, k_new, v_new, page_table, positions):
+    """Write each row's ``Sq`` new tokens into a paged pool, whole pages at
+    a time, and return (pool_k, pool_v).
+
+    k_new/v_new (B, Sq, K, hd)  roped K/V of the tokens at absolute
+                positions ``positions[b] .. positions[b] + Sq - 1``
+    pool_k/v    (P, K, page, hd), or the quantized ``{"q", "scale"}``
+                dicts (see attention_decode_paged): the new rows are
+                quantized first and codes and scales written alike
+
+    Token t of row b goes to page ``page_table[b, (pos + t) // page]``
+    slot ``(pos + t) % page``. Each row's pages are gathered, its new rows
+    put at their slots (every other slot keeps the page's contents, so a
+    chunk that starts mid-page keeps its prefix) and the pages scattered
+    back on the page axis alone: the pool keeps the layout the kernels
+    read, and the scatter updates the donated pool in place. Blocks past
+    the page table's width (a final chunk's padding) go to the scratch
+    page 0, as do idle decode rows, whose page tables point there.
+
+    Whole-page writes are exact because no two rows of one call write the
+    same live page: a sequence's pages are its own (the engine shares no
+    pages), so only page 0 can be written twice, and it holds nothing.
+    """
+    quantized = isinstance(pool_k, dict)
+    B, Sq = k_new.shape[:2]
+    page = (pool_k["q"] if quantized else pool_k).shape[2]
+    n_blocks = page_table.shape[1]
+    n = (Sq + page - 2) // page + 1             # the most pages Sq rows touch
+    blocks = positions[:, None] // page + jnp.arange(n, dtype=jnp.int32)
+    # clamped: an out-of-range gather fills INT_MIN, which the
+    # promise_in_bounds scatter below would take as undefined behaviour
+    pids = jnp.take_along_axis(page_table, jnp.minimum(blocks, n_blocks - 1),
+                               axis=1)
+    pids = jnp.where(blocks < n_blocks, pids, 0).reshape(B * n)
+    # the chunk row each gathered slot takes, and whether it takes one
+    t = blocks[:, :, None] * page + jnp.arange(page) - positions[:, None, None]
+    mine = ((t >= 0) & (t < Sq))[:, :, None, :]            # (B, n, 1, page)
+    t = jnp.clip(t, 0, Sq - 1).reshape(B, n * page)
+
+    def write(pool, new):                       # new (B, Sq, K, ...)
+        rows = jax.vmap(lambda a, i: a[i])(new, t)
+        rows = jnp.moveaxis(rows.reshape((B, n, page) + new.shape[2:]), 2, 3)
+        old = pool.at[pids].get(mode="promise_in_bounds")
+        old = old.reshape((B, n) + pool.shape[1:])
+        m = mine.reshape(mine.shape + (1,) * (old.ndim - 4))
+        pages = jnp.where(m, rows.astype(pool.dtype), old)
+        return pool.at[pids].set(pages.reshape((B * n,) + pool.shape[1:]),
+                                 mode="promise_in_bounds")
+
+    def put(pool, new):
+        if quantized:
+            bits = kref.kv_bits_of(pool["q"], new.shape[-1])
+            qv, sc = kref.quantize_kv(new, bits)
+            return {"q": write(pool["q"], qv),
+                    "scale": write(pool["scale"], sc)}
+        return write(pool, new)
+
+    return put(pool_k, k_new), put(pool_v, v_new)
+
+
 def attention_decode_paged(p, x, pool_k, pool_v, page_table, positions,
-                           kind: str, cfg, *, dot=None, ac=None,
-                           kernel: str = "auto"):
+                           kind: str, cfg, *, dot=None, kernel: str = "auto"):
     """Slot-indexed one-token decode against a paged KV pool.
 
     x           (B, 1, D)   one new token's activations per sequence
@@ -215,12 +274,13 @@ def attention_decode_paged(p, x, pool_k, pool_v, page_table, positions,
                 layers walk only the window's pages instead of masking a
                 full-length gather.
 
-    The new k/v are scattered into page ``page_table[b, pos // page]`` at
-    slot ``pos % page``; attention then walks the sequence's pages in
-    chronological order, masking columns beyond ``positions[b]`` (and
-    outside the sliding window for local layers). Because RoPE is applied
-    at cache-write time with absolute positions, the page walk matches a
-    dense chronological cache to fp32-accumulation precision.
+    The new k/v land in page ``page_table[b, pos // page]`` at slot
+    ``pos % page``, written by ``write_pages`` (one whole page per row, in
+    place); attention then walks the sequence's pages in chronological
+    order, masking columns beyond ``positions[b]`` (and outside the sliding
+    window for local layers). Because RoPE is applied at cache-write time
+    with absolute positions, the page walk matches a dense chronological
+    cache to fp32-accumulation precision.
 
     Quantized pools (serving/kvquant): ``pool_k``/``pool_v`` may instead be
     ``{"q": int8 pages, "scale": fp32 (P, K, page)}`` dicts — the stored
@@ -230,46 +290,25 @@ def attention_decode_paged(p, x, pool_k, pool_v, page_table, positions,
     prefill writer uses), and attention runs the fused-dequant walk — no
     dense fp KV view is materialized on either path.
 
-    ``ac`` (sequence-parallel decode hints) applies to the dense decode
-    path only; the paged walk ignores it. Sharded paged decode instead
-    rides shard_map (serving/engine/sharded.py): the pool arrives as a
-    local kv-head slice and this function runs unchanged per shard — the
-    walk is embarrassingly parallel over heads, and the ``dot`` hook
-    (sharded.tp_dot) all-gathers the per-head outputs before the
-    out-projection so the contraction keeps its 1-device reduction order.
+    Sharded paged decode rides shard_map (serving/engine/sharded.py): the
+    pool arrives as a local kv-head slice and this function runs unchanged
+    per shard — the walk is embarrassingly parallel over heads, and the
+    ``dot`` hook (sharded.tp_dot) all-gathers the per-head outputs before
+    the out-projection so the contraction keeps its 1-device reduction
+    order.
 
     Returns (out (B,1,D), pool_k, pool_v).
     """
-    quantized = isinstance(pool_k, dict)
-    page = (pool_k["q"] if quantized else pool_k).shape[2]
-    q, k_new, v_new = project(p, x, cfg, positions[:, None],
-                                dot=dot)
-    pids = jnp.take_along_axis(page_table, (positions // page)[:, None],
-                               axis=1)[:, 0]
-    slots = positions % page
+    q, k_new, v_new = project(p, x, cfg, positions[:, None], dot=dot)
+    pool_k, pool_v = write_pages(pool_k, pool_v, k_new, v_new, page_table,
+                                 positions)
     window = cfg.window_size if kind == "local" else 0
-    if quantized:
-        hd = q.shape[-1]
-        bits = kref.kv_bits_of(pool_k["q"], hd)
-
-        def write(pool, new):                        # new: (B, K, hd)
-            qv, sc = kref.quantize_kv(new, bits)
-            return {"q": pool["q"].at[pids, :, slots].set(
-                        qv, mode="promise_in_bounds"),
-                    "scale": pool["scale"].at[pids, :, slots].set(
-                        sc, mode="promise_in_bounds")}
-
-        pool_k = write(pool_k, k_new[:, 0])
-        pool_v = write(pool_v, v_new[:, 0])
+    if isinstance(pool_k, dict):
         o = kops.paged_attention_quant(
             q[:, 0], pool_k["q"], pool_k["scale"], pool_v["q"],
             pool_v["scale"], page_table, positions, window=window,
             cap=cfg.attn_softcap, mode=kernel)[:, None]
     else:
-        pool_k = pool_k.at[pids, :, slots].set(k_new[:, 0],
-                                               mode="promise_in_bounds")
-        pool_v = pool_v.at[pids, :, slots].set(v_new[:, 0],
-                                               mode="promise_in_bounds")
         o = kops.paged_attention(q[:, 0], pool_k, pool_v, page_table,
                                  positions, window=window,
                                  cap=cfg.attn_softcap, mode=kernel)[:, None]
@@ -290,60 +329,35 @@ def attention_prefill_paged(p, x, pool_k, pool_v, page_table, positions,
                 (== the number of prompt tokens already resident in the
                 pool for that sequence)
 
-    The chunk's roped k/v are scattered into their pages first — token t
-    at page ``page_table[b, (pos+t) // page]`` slot ``(pos+t) % page`` —
-    then attention walks the sequence's pages with the chunked-prefill
-    kernel: query t attends causally to every pool slot at
-    ``kpos <= positions[b] + t``, i.e. the resident prompt prefix plus the
-    chunk itself. No dense chronological prompt KV view is materialized on
-    any path, and the final chunk's padding garbage stays behind the
-    causal mask exactly like bucket padding did (overwritten by decode in
-    position order).
+    The chunk's roped k/v are written into their pages first — token t at
+    page ``page_table[b, (pos+t) // page]`` slot ``(pos+t) % page``, by
+    ``write_pages`` (the pages the chunk touches, whole, in place; rows
+    past the page table's width go to the scratch page) — then attention
+    walks the sequence's pages with the chunked-prefill kernel: query t
+    attends causally to every pool slot at ``kpos <= positions[b] + t``,
+    i.e. the resident prompt prefix plus the chunk itself. No dense
+    chronological prompt KV view is materialized on any path, and the
+    final chunk's padding garbage stays behind the causal mask exactly
+    like bucket padding did (overwritten by decode in position order).
 
     Quantized pools quantize the chunk on write (per-token per-head
-    scales, the same mapping as the decode scatter) and run the
+    scales, the same mapping as the decode write) and run the
     fused-dequant prefill walk.
 
     Returns (out (B, Sq, D), pool_k, pool_v).
     """
-    quantized = isinstance(pool_k, dict)
-    page = (pool_k["q"] if quantized else pool_k).shape[2]
-    B, Sq, _ = x.shape
-    n_blocks = page_table.shape[1]
+    Sq = x.shape[1]
     abs_pos = positions[:, None] + jnp.arange(Sq, dtype=jnp.int32)[None, :]
     q, k_new, v_new = project(p, x, cfg, abs_pos, dot=dot)
-    # A final chunk padded past the page-table width routes its overflow
-    # rows to the scratch page explicitly: an unclamped gather fills OOB
-    # indices with INT_MIN, which the promise_in_bounds scatter below
-    # would treat as undefined behaviour.
-    blocks = abs_pos // page                                    # (B, Sq)
-    pids = jnp.take_along_axis(page_table,
-                               jnp.minimum(blocks, n_blocks - 1), axis=1)
-    pids = jnp.where(blocks < n_blocks, pids, 0)
-    slots = abs_pos % page
+    pool_k, pool_v = write_pages(pool_k, pool_v, k_new, v_new, page_table,
+                                 positions)
     window = cfg.window_size if kind == "local" else 0
-    if quantized:
-        hd = q.shape[-1]
-        bits = kref.kv_bits_of(pool_k["q"], hd)
-
-        def write(pool, new):                        # new: (B, Sq, K, hd)
-            qv, sc = kref.quantize_kv(new, bits)
-            return {"q": pool["q"].at[pids, :, slots].set(
-                        qv, mode="promise_in_bounds"),
-                    "scale": pool["scale"].at[pids, :, slots].set(
-                        sc, mode="promise_in_bounds")}
-
-        pool_k = write(pool_k, k_new)
-        pool_v = write(pool_v, v_new)
+    if isinstance(pool_k, dict):
         o = kops.paged_attention_prefill_quant(
             q, pool_k["q"], pool_k["scale"], pool_v["q"], pool_v["scale"],
             page_table, positions, window=window, cap=cfg.attn_softcap,
             mode=kernel)
     else:
-        pool_k = pool_k.at[pids, :, slots].set(k_new,
-                                               mode="promise_in_bounds")
-        pool_v = pool_v.at[pids, :, slots].set(v_new,
-                                               mode="promise_in_bounds")
         o = kops.paged_attention_prefill(q, pool_k, pool_v, page_table,
                                          positions, window=window,
                                          cap=cfg.attn_softcap, mode=kernel)

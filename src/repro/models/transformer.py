@@ -4,7 +4,11 @@ where period is the LCM of the attention pattern (gemma2 local/global), the
 MoE interleave (llama4 dense/MoE) and the mixer pattern (granite-4.0-h: a
 Mamba-2 or an attention mixer per sub-layer) — each sub-layer slot has its
 own stacked parameter pytree so `lax.scan` keeps HLO size and CPU compile
-time bounded for the 88-layer/123B configs.
+time bounded for the 88-layer/123B configs. The scan takes the stacked
+parameters as its input; the paged serving programs keep the page pool,
+flattened over the groups, in its carry, so that each layer writes its own
+pages in place and no pool is sliced out or stacked again
+(``_paged_layers``).
 
 Public surface (used by models/api.py):
   param_defs(cfg)                     -> PDef pytree
@@ -461,27 +465,7 @@ def decode_step(params, cache, token, pos, cfg, *, ac: Ac = _identity_ac,
     return logits, new_cache
 
 
-# ----------------------------------------------------------- paged decode ----
-def _dense_block_decode_paged(p, x, pool_kv, page_table, positions, kind, cfg,
-                              dot=None, ac=None, kernel="auto"):
-    h = _norm(x, p["ln1"], cfg)
-    a, ck, cv = attn.attention_decode_paged(
-        p["attn"], h, pool_kv["k"], pool_kv["v"], page_table, positions,
-        kind["attn"], cfg, dot=dot, ac=ac, kernel=kernel)
-    if cfg.sandwich_norm:
-        a = rms_norm(a, p["ln1_post"], cfg.norm_eps)
-    x = _residual(cfg, x, a)
-    h = _norm(x, p["ln2"], cfg)
-    if kind["moe"]:
-        f = moe_lib.moe_serve(p["moe"], h, cfg.moe, cfg.activation, dot=dot,
-                              x_route=_route_in(p, x, cfg))
-    else:
-        f = ffn_apply(p["ffn"], h, cfg.activation, dot=dot)
-    if cfg.sandwich_norm:
-        f = rms_norm(f, p["ln2_post"], cfg.norm_eps)
-    return _residual(cfg, x, f), {"k": ck, "v": cv}
-
-
+# ------------------------------------------------------ paged programs ----
 PAGED_FAMILIES = ("dense", "moe", "vlm", "hybrid_moe")
 
 
@@ -492,29 +476,41 @@ def _check_paged(cfg, what: str) -> None:
             f"got {cfg.family!r}")
 
 
-def _paged_stateful(params, pool, x, cfg, attend, recur, dot):
-    """Every layer of a model with Mamba sub-slots against its pool.
-    ``attend(p, h, kv, off, kind)`` and ``recur(p, h, st, off)`` run one
-    layer's mixer on that sub-slot's pool leaves, flattened over the
-    groups: group g's pages (or state rows) start at ``off``. The groups
-    are unrolled and the flattened pools carried through them, so every
-    write lands in place and no pool is sliced out or stacked again.
-    Returns (x, new_pool)."""
+def _paged_layers(params, pool, x, cfg, attend, recur, dot):
+    """Every layer of a paged program against its pool.
+
+    Each sub-slot's pool leaves are flattened over the layer groups —
+    group g's pages (or state rows) start at ``g`` times the leaf's second
+    dim — and carried through the groups, so each layer writes its group's
+    part of the pool in place: no pool is sliced out of the stack or
+    stacked again. ``attend(p, h, kv, off, kind)`` and ``recur(p, h, st,
+    off)`` run one layer's mixer on its sub-slot's flattened leaves, its
+    group starting at ``off``. Returns (x, new_pool), the pool in
+    ``pool_specs``' shape.
+
+    The groups are scanned, except in models with Mamba sub-slots, which
+    unroll their few groups: scanned, their chunk step compiles to other
+    roundings (seen on the CPU) and their served tokens would move."""
     P = period_of(cfg)
     kinds = sublayer_kinds(cfg)
     flat = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), pool)
-    for g in range(cfg.num_layers // P):
+
+    def group(carry, xs):
+        x, flat = carry
+        blocks, g = xs
+        flat = dict(flat)
         for j in range(P):
             name = f"sub{j}"
-            p = jax.tree.map(lambda a: a[g], params["blocks"][name])
+            p = blocks[name]
+            off = g * jax.tree.leaves(pool[name])[0].shape[1]
             h = _norm(x, p["ln1"], cfg)
             if kinds[j]["mixer"] == "mamba":
-                off = g * pool[name]["state"].shape[1]
                 a, flat[name] = recur(p["mamba"], h, flat[name], off)
             else:
-                off = g * pool[name]["k"].shape[1]
                 a, flat[name] = attend(p["attn"], h, flat[name], off,
                                        kinds[j])
+            if cfg.sandwich_norm:
+                a = rms_norm(a, p["ln1_post"], cfg.norm_eps)
             x = _residual(cfg, x, a)
             h = _norm(x, p["ln2"], cfg)
             if kinds[j]["moe"]:
@@ -522,13 +518,25 @@ def _paged_stateful(params, pool, x, cfg, attend, recur, dot):
                                       dot=dot, x_route=_route_in(p, x, cfg))
             else:
                 f = ffn_apply(p["ffn"], h, cfg.activation, dot=dot)
+            if cfg.sandwich_norm:
+                f = rms_norm(f, p["ln2_post"], cfg.norm_eps)
             x = _residual(cfg, x, f)
+        return (x, flat), None
+
+    n_groups = cfg.num_layers // P
+    if has_state(cfg):
+        for g in range(n_groups):
+            (x, flat), _ = group((x, flat), (jax.tree.map(
+                lambda a: a[g], params["blocks"]), g))
+    else:
+        (x, flat), _ = jax.lax.scan(
+            group, (x, flat),
+            (params["blocks"], jnp.arange(n_groups, dtype=jnp.int32)))
     return x, jax.tree.map(lambda a, o: a.reshape(o.shape), flat, pool)
 
 
 def decode_step_paged(params, pool, page_table, token, positions, cfg, *,
-                      rows=None, ac: Ac = _identity_ac, dot=None,
-                      kernel="auto"):
+                      rows=None, dot=None, kernel="auto"):
     """Batched slot-indexed decode against a paged KV pool.
 
     token (B,1) int32; positions (B,) int32 per-sequence absolute positions
@@ -538,66 +546,29 @@ def decode_step_paged(params, pool, page_table, token, positions, cfg, *,
     ``kernel`` selects the paged-attention path (see attention_decode_paged)
     — every choice walks pages block-by-block; no layer materializes the
     dense chronological KV view, and local layers trim the walk to their
-    window. With Mamba sub-slots, ``rows`` (B,) int32 names each batch
-    row's state slot (idle rows: the scratch slot). Returns (logits
-    (B,1,V), new_pool).
+    window. Each token's K/V is written into the pool in place
+    (``_paged_layers``, ``attention.write_pages``). With Mamba sub-slots,
+    ``rows`` (B,) int32 names each batch row's state slot (idle rows: the
+    scratch slot). Returns (logits (B,1,V), new_pool).
     """
     _check_paged(cfg, "paged decode")
     x = embed_tokens(params, token, cfg)
-    if has_state(cfg):
-        def attend(p, h, kv, off, kind):
-            a, k, v = attn.attention_decode_paged(
-                p, h, kv["k"], kv["v"], page_table + off, positions,
-                kind["attn"], cfg, dot=dot, kernel=kernel)
-            return a, {"k": k, "v": v}
 
-        def recur(p, h, st, off):
-            a, conv, state = ssm_lib.mamba_decode_rows(
-                p, h, st["conv"], st["state"], rows + off, cfg, dot=dot,
-                kernel=kernel)
-            return a, {"conv": conv, "state": state}
+    def attend(p, h, kv, off, kind):
+        a, k, v = attn.attention_decode_paged(
+            p, h, kv["k"], kv["v"], page_table + off, positions,
+            kind["attn"], cfg, dot=dot, kernel=kernel)
+        return a, {"k": k, "v": v}
 
-        x, new_pool = _paged_stateful(params, pool, x, cfg, attend, recur,
-                                      dot)
-        x = _norm(x, params["final_norm"], cfg)
-        return unembed(params, x, cfg, dot=dot), new_pool
-    P = period_of(cfg)
-    kinds = sublayer_kinds(cfg)
+    def recur(p, h, st, off):
+        a, conv, state = ssm_lib.mamba_decode_rows(
+            p, h, st["conv"], st["state"], rows + off, cfg, dot=dot,
+            kernel=kernel)
+        return a, {"conv": conv, "state": state}
 
-    def group_body(h, xs):
-        blocks, pool_g = xs
-        new_g = {}
-        for j in range(P):
-            h, new_g[f"sub{j}"] = _dense_block_decode_paged(
-                blocks[f"sub{j}"], h, pool_g[f"sub{j}"], page_table,
-                positions, kinds[j], cfg, dot=dot, ac=ac, kernel=kernel)
-        return h, new_g
-
-    x, new_pool = jax.lax.scan(group_body, x, (params["blocks"], pool))
+    x, new_pool = _paged_layers(params, pool, x, cfg, attend, recur, dot)
     x = _norm(x, params["final_norm"], cfg)
-    logits = unembed(params, x, cfg, dot=dot)
-    return logits, new_pool
-
-
-# --------------------------------------------------------- paged prefill ----
-def _dense_block_prefill_paged(p, x, pool_kv, page_table, positions, kind,
-                               cfg, dot=None, kernel="auto"):
-    h = _norm(x, p["ln1"], cfg)
-    a, ck, cv = attn.attention_prefill_paged(
-        p["attn"], h, pool_kv["k"], pool_kv["v"], page_table, positions,
-        kind["attn"], cfg, dot=dot, kernel=kernel)
-    if cfg.sandwich_norm:
-        a = rms_norm(a, p["ln1_post"], cfg.norm_eps)
-    x = _residual(cfg, x, a)
-    h = _norm(x, p["ln2"], cfg)
-    if kind["moe"]:
-        f = moe_lib.moe_serve(p["moe"], h, cfg.moe, cfg.activation, dot=dot,
-                              x_route=_route_in(p, x, cfg))
-    else:
-        f = ffn_apply(p["ffn"], h, cfg.activation, dot=dot)
-    if cfg.sandwich_norm:
-        f = rms_norm(f, p["ln2_post"], cfg.norm_eps)
-    return _residual(cfg, x, f), {"k": ck, "v": cv}
+    return unembed(params, x, cfg, dot=dot), new_pool
 
 
 def prefill_chunk_paged(params, pool, page_table, tokens, positions, cfg, *,
@@ -605,10 +576,11 @@ def prefill_chunk_paged(params, pool, page_table, tokens, positions, cfg, *,
     """One chunked-prefill step: run ``tokens`` (B, Sq) — a contiguous
     prompt chunk whose first token sits at absolute position
     ``positions[b]`` — through every layer, writing each layer's chunk K/V
-    into the paged pool and attending over the pool itself (resident
-    prompt prefix + the chunk, causal within the chunk). The engine calls
-    this once per tick per mid-prefill sequence, so one long prompt costs
-    many small ticks instead of one decode-stalling bucket.
+    into the paged pool in place (the pages the chunk touches, see
+    ``attention.write_pages``) and attending over the pool itself
+    (resident prompt prefix + the chunk, causal within the chunk). The
+    engine calls this once per tick per mid-prefill sequence, so one long
+    prompt costs many small ticks instead of one decode-stalling bucket.
 
     Returns (hidden (B, Sq, D) final-norm hidden states, new_pool) — the
     caller unembeds only the rows it needs (the last real prompt position
@@ -621,43 +593,28 @@ def prefill_chunk_paged(params, pool, page_table, tokens, positions, cfg, *,
     """
     _check_paged(cfg, "paged prefill")
     x = embed_tokens(params, tokens, cfg)
-    if has_state(cfg):
-        def attend(p, h, kv, off, kind):
-            a, k, v = attn.attention_prefill_paged(
-                p, h, kv["k"], kv["v"], page_table + off, positions,
-                kind["attn"], cfg, dot=dot, kernel=kernel)
-            return a, {"k": k, "v": v}
 
-        def recur(p, h, st, off):
-            r = rows + off
-            fresh = positions == 0
-            conv, state = st["conv"], st["state"]
-            cache = {"conv": jnp.where(fresh[:, None, None], 0, conv[r]),
-                     "state": jnp.where(fresh[:, None, None, None], 0.0,
-                                        state[r])}
-            a, new = ssm_lib.mamba_block_fwd(p, h, cfg, dot=dot, cache=cache,
-                                             lengths=lengths)
-            return a, {"conv": conv.at[r].set(new["conv"].astype(conv.dtype),
-                                              mode="promise_in_bounds"),
-                       "state": state.at[r].set(new["state"],
-                                                mode="promise_in_bounds")}
+    def attend(p, h, kv, off, kind):
+        a, k, v = attn.attention_prefill_paged(
+            p, h, kv["k"], kv["v"], page_table + off, positions,
+            kind["attn"], cfg, dot=dot, kernel=kernel)
+        return a, {"k": k, "v": v}
 
-        x, new_pool = _paged_stateful(params, pool, x, cfg, attend, recur,
-                                      dot)
-        return _norm(x, params["final_norm"], cfg), new_pool
-    P = period_of(cfg)
-    kinds = sublayer_kinds(cfg)
+    def recur(p, h, st, off):
+        r = rows + off
+        fresh = positions == 0
+        conv, state = st["conv"], st["state"]
+        cache = {"conv": jnp.where(fresh[:, None, None], 0, conv[r]),
+                 "state": jnp.where(fresh[:, None, None, None], 0.0,
+                                    state[r])}
+        a, new = ssm_lib.mamba_block_fwd(p, h, cfg, dot=dot, cache=cache,
+                                         lengths=lengths)
+        return a, {"conv": conv.at[r].set(new["conv"].astype(conv.dtype),
+                                          mode="promise_in_bounds"),
+                   "state": state.at[r].set(new["state"],
+                                            mode="promise_in_bounds")}
 
-    def group_body(h, xs):
-        blocks, pool_g = xs
-        new_g = {}
-        for j in range(P):
-            h, new_g[f"sub{j}"] = _dense_block_prefill_paged(
-                blocks[f"sub{j}"], h, pool_g[f"sub{j}"], page_table,
-                positions, kinds[j], cfg, dot=dot, kernel=kernel)
-        return h, new_g
-
-    x, new_pool = jax.lax.scan(group_body, x, (params["blocks"], pool))
+    x, new_pool = _paged_layers(params, pool, x, cfg, attend, recur, dot)
     return _norm(x, params["final_norm"], cfg), new_pool
 
 

@@ -11,6 +11,7 @@ time: only one process at a time may load the TPU library, and the test
 workers all import this file.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +61,82 @@ def no_persistent_cache():
 
 def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
+
+
+_INST = re.compile(r"\s*(ROOT )?%(\S+) = (.*?) ([a-z][a-z-]*)\((.*)$")
+_ARRAY = re.compile(r"\[([\d,]*)\]\{([\d,]*)")
+_POOL_ITSELF = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+                "scatter"}
+_STAGING = {"copy-start", "copy-done", "slice-start", "custom-call"}
+
+
+def _pool_copies(text, leaves):
+    """The instructions of a compiled program whose value has the shape of
+    one of the pool ``leaves`` — stacked over the layer groups, one
+    group's, or flattened over them — other than the pool itself
+    (parameters, tuples, bitcasts, the layer loop) and the scatters that
+    write it in place. Each is a copy or a slice of a whole pool leaf.
+
+    A pool small enough for on-chip memory (space ``S(1)``) may be staged
+    there by the compiler, moved in and out in the row-major layout the
+    kernels read; those moves are not counted. A move into any other
+    layout (a relayout) is."""
+    dims = set()
+    for leaf in leaves:
+        d = leaf.shape
+        dims |= {d, d[1:], (d[0] * d[1],) + d[2:]}
+    dims = {",".join(map(str, d)) for d in dims}
+    insts, roots, comp = {}, {}, None
+    for line in text.splitlines():
+        if line.startswith(("%", "ENTRY")):
+            comp = line.split()[line.startswith("ENTRY")].lstrip("%")
+            continue
+        m = _INST.match(line)
+        if m:
+            root, name, shape, op, rest = m.groups()
+            insts[name] = (shape, op, rest)
+            if root:
+                roots[comp] = op
+
+    def own_layout(shape):
+        return all(lay == ",".join(map(str, reversed(range(d.count(",") + 1))))
+                   for d, lay in _ARRAY.findall(shape) if d in dims)
+
+    found = []
+    for name, (shape, op, rest) in insts.items():
+        if not any(d in dims for d, _ in _ARRAY.findall(shape)) \
+                or op in _POOL_ITSELF:
+            continue
+        called = re.search(r"calls=%([\w.\-]+)", rest)
+        if op == "fusion" and called and roots.get(called.group(1)) == \
+                "scatter":
+            continue
+        operands = [insts.get(o, ("",))[0] for o in
+                    re.findall(r"%([\w.\-]+)", rest.split("), ")[0])]
+        staging = op in _STAGING and (op != "custom-call"
+                                      or "ConcatBitcast" in rest)
+        if staging and "S(1)" in shape + "".join(operands) \
+                and all(map(own_layout, [shape] + operands)):
+            continue
+        found.append(f"{op} {name} {shape[:48]}")
+    return found
+
+
+def _assert_in_place(compiled, pool):
+    """Neither a copy nor a slice of a K/V pool leaf in the program, less
+    than one K/V leaf of temporaries, and the whole pool aliased from the
+    donated argument to the output."""
+    kv = [pool[sub][x] for sub in pool if "k" in pool[sub] for x in "kv"]
+    copies = _pool_copies(compiled.as_text(), kv)
+    assert not copies, copies
+    m = compiled.memory_analysis()
+    leaf_bytes = kv[0].size * kv[0].dtype.itemsize // kv[0].shape[0]
+    assert m.temp_size_in_bytes < leaf_bytes, (m.temp_size_in_bytes,
+                                               leaf_bytes)
+    pool_bytes = sum(leaf.size * leaf.dtype.itemsize
+                     for leaf in jax.tree.leaves(pool))
+    assert m.alias_size_in_bytes >= pool_bytes, (m.alias_size_in_bytes,
+                                                 pool_bytes)
 
 
 @pytest.mark.parametrize("page", PAGE_SIZES)
@@ -183,8 +260,9 @@ def test_full_width_serving_step_compiles_and_fits(full_width, monkeypatch,
                                                    step):
     """The engine's jitted decode and chunk-prefill steps at full width
     compile for one v5e with the Pallas kernel in them (the backend here is
-    the CPU, so the kernel dispatch is steered to the TPU lowering), and
-    the program's arguments, outputs and temporaries fit its HBM."""
+    the CPU, so the kernel dispatch is steered to the TPU lowering), the
+    program's arguments, outputs and temporaries fit its HBM, and the page
+    pool is written in place."""
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     model, pol, params, pool, i32 = full_width
     B, maxp = pol.max_batch, pol.pages_per_seq
@@ -202,6 +280,7 @@ def test_full_width_serving_step_compiles_and_fits(full_width, monkeypatch,
     used = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert used <= V5E_EDGE.hbm_bytes, used
+    _assert_in_place(c, pool)
 
 
 @pytest.mark.parametrize("step", ["decode", "chunk_prefill"])
@@ -211,7 +290,7 @@ def test_full_width_hybrid_step_compiles_and_fits(one_chip, monkeypatch,
     72 experts held) at 128 in-flight sequences: the engine's decode step
     (the paged-attention and SSM-decode kernels in it) and its 512-token
     chunk step compile for one v5e and fit its HBM beside the state rows
-    of every batch slot and the page pool."""
+    of every batch slot and the page pool, which they write in place."""
     from repro.serving.engine.admission import state_bytes_per_seq
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     base = get_config("granite-4.0-h-small")
@@ -247,3 +326,29 @@ def test_full_width_hybrid_step_compiles_and_fits(one_chip, monkeypatch,
     used = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert used <= V5E_EDGE.hbm_bytes, used
+    _assert_in_place(c, pool)
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk_prefill"])
+def test_dense_cell_step_writes_pool_in_place(one_chip, monkeypatch, step):
+    """nemotron-4-15b.stage4's widths (42 sequences, 2965 pages of 16, 256
+    page-table slots, 512-token chunks), two layers so that the scan over
+    layer groups carries the pool: the decode and chunk steps neither copy
+    nor slice a pool leaf and hold less than one leaf of temporaries."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    model = build_model(get_config("nemotron-4-15b").replace(num_layers=2))
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip)
+    params = jax.tree.map(place, model.abstract_params())
+    pool = jax.tree.map(place, model.pool_specs(2965, 16))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    if step == "decode":
+        fn = model.decode_step_paged
+        args = (params, pool, i32(42, 256), i32(42, 1), i32(42))
+    else:
+        fn = model.prefill_chunk_paged
+        args = (params, pool, i32(1, 256), i32(1, 512), i32(1))
+    c = jax.jit(lambda *a: fn(*a), donate_argnums=(1,)).lower(
+        *args).compile()
+    assert "tpu_custom_call" in c.as_text()
+    _assert_in_place(c, pool)
