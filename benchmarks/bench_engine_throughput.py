@@ -1,21 +1,16 @@
-"""Engine throughput — continuous batching vs the sequential baseline,
-lazy page allocation + preemption vs upfront reservation, and fp vs
-quantized KV-cache pools at equal HBM budget.
+"""Engine throughput — continuous batching on a mixed trace, lazy page
+allocation + preemption vs upfront reservation, fp vs quantized KV-cache
+pools at equal HBM budget, and prefill stalls under long prompts.
 
 Results are also written to ``BENCH_engine.json`` (see ``--out``) so the
 perf trajectory stays machine-readable across PRs; every trace RNG is
 seeded explicitly (TRACE_SEEDS).
 
-Four traces on the tiny CPU config:
+Traces on the tiny CPU config:
 
   * **mixed** (16 requests, Poisson arrivals, Poisson-ish length mix):
-    served sequentially through `launch.serve.generate` (B=1, one request
-    at a time — the pre-engine path) and through the continuous-batching
-    engine; greedy outputs are asserted token-identical. Both decode
-    through the same paged-attention walk, so the speedup isolates the
-    serving machinery: continuous batching plus the engine's jitted
-    per-bucket prefill (the baseline prefills eagerly per request, as it
-    always has).
+    served through the continuous-batching engine in real time; records
+    the engine's tokens/s.
 
   * **skewed** (long-``max_new`` tail on a page pool sized for the
     *expected*, not worst-case, footprint): served twice through the
@@ -47,16 +42,12 @@ Four traces on the tiny CPU config:
     fewer than 2 devices are visible — the multi-device CI job forces 8.
 
   * **longprompt** (a few short residents decoding for the whole run while
-    long prompts keep a prefill in flight): served twice through the
-    engine — whole-prompt buckets vs chunked prefill at a fixed chunk.
-    Reports decode tok/s, per-decode-tick stall p50/p99 (the seconds a
-    tick's already-ready sequences waited on prefill work,
-    ``Engine.stall_log``), and TTFT p50/p99. Greedy outputs are asserted
-    identical; the chunked mode must cut stall p99 >= 2x at equal decode
-    tok/s (±10%) — the acceptance bar the CI bench-gate re-checks from
-    the JSON.
+    long prompts keep a prefill in flight): served through the engine at a
+    fixed chunk. Reports decode tok/s, per-decode-tick stall p50/p99 (the
+    seconds a tick's already-ready sequences waited on prefill work,
+    ``Engine.stall_log``), and TTFT p50/p99.
 
-The chunked long-prompt engine additionally contributes a ``telemetry``
+The long-prompt engine additionally contributes a ``telemetry``
 section: measured per-decode-tick stall p50/p99 from the telemetry
 record, per-kind tick counts, and the roofline predicted-vs-measured
 calibration (`serving/telemetry/calibrate.py`) — the scale factors and
@@ -67,8 +58,7 @@ CI engine-smoke job uploads it as a workflow artifact.
 
 Engines are warmed on the exact trace shapes and re-timed on the same
 instance, so jit compiles are excluded. Outputs are asserted identical
-between the two admission modes (and to the sequential baseline on the
-mixed trace).
+between the two admission modes.
 
 Run: ``PYTHONPATH=src python -m benchmarks.bench_engine_throughput``.
 CI: the engine-smoke job reruns the default (baseline-size) traces and
@@ -84,14 +74,12 @@ import json
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import row
 from repro.configs import tiny_config
 from repro.core.hardware_model import V5E_EDGE
 from repro.launch.compile_cache import enable_compile_cache
-from repro.launch.serve import generate
 from repro.models.api import build_model
 from repro.serving.engine import Engine, Request, derive_policy
 from repro.serving.engine.admission import kv_bytes_per_token
@@ -152,21 +140,8 @@ def make_skewed_trace(cfg, n, seed=1):
     return reqs
 
 
-def run_sequential(model, params, reqs):
-    outs = {}
-    t0 = time.monotonic()
-    for r in reqs:       # FIFO, honoring arrival offsets
-        wait = r.arrival - (time.monotonic() - t0)
-        if wait > 0:
-            time.sleep(wait)
-        out = generate(model, params, jnp.asarray(r.prompt[None]), r.max_new)
-        outs[r.rid] = np.asarray(jax.block_until_ready(out)[0])
-    return outs, time.monotonic() - t0
-
-
 def build_engine(model, params, *, max_model_len=96, reserve_upfront=False,
-                 num_pages=None, max_batch=MAX_BATCH, prefill_chunk=None,
-                 chunked_prefill=True):
+                 num_pages=None, max_batch=MAX_BATCH, prefill_chunk=None):
     policy = derive_policy(model.cfg, V5E_EDGE,
                            max_model_len=max_model_len,
                            param_bytes=model.param_bytes())
@@ -174,8 +149,7 @@ def build_engine(model, params, *, max_model_len=96, reserve_upfront=False,
         policy, max_batch=max_batch,
         **({"num_pages": num_pages} if num_pages else {}),
         **({"prefill_chunk": prefill_chunk} if prefill_chunk else {}))
-    return Engine(model, params, policy, reserve_upfront=reserve_upfront,
-                  chunked_prefill=chunked_prefill)
+    return Engine(model, params, policy, reserve_upfront=reserve_upfront)
 
 
 def timed_run(engine, reqs, *, realtime):
@@ -190,30 +164,13 @@ def timed_run(engine, reqs, *, realtime):
 def bench_mixed(model, params, cfg, n):
     reqs = make_trace(cfg, n, seed=TRACE_SEEDS["mixed"])
     total_gen = sum(r.max_new for r in reqs)
-    run_sequential(model, params, reqs)          # warm the baseline
-    base_outs, base_dt = run_sequential(model, params, reqs)
     engine = build_engine(model, params)
-    eng_outs, eng_dt, stats = timed_run(engine, reqs, realtime=True)
-
-    for r in reqs:
-        assert np.array_equal(base_outs[r.rid], eng_outs[r.rid]), (
-            f"engine output diverged from sequential baseline for "
-            f"request {r.rid}")
-
-    base_tps = total_gen / base_dt
+    _, eng_dt, stats = timed_run(engine, reqs, realtime=True)
     eng_tps = total_gen / eng_dt
-    speedup = eng_tps / base_tps
-    row("engine/sequential-baseline", base_dt / total_gen * 1e6,
-        f"tok_s={base_tps:.1f}")
     row("engine/continuous-batching", eng_dt / total_gen * 1e6,
         f"tok_s={eng_tps:.1f};ticks={stats['decode_ticks']}")
-    row("engine/speedup", eng_dt * 1e6,
-        f"speedup={speedup:.2f}x;target>=3x;pass={speedup >= 3.0}")
-    print(f"# continuous batching: {eng_tps:.1f} tok/s vs sequential "
-          f"{base_tps:.1f} tok/s -> {speedup:.2f}x (outputs identical)",
-          flush=True)
-    return {"n": n, "sequential_tok_s": base_tps, "engine_tok_s": eng_tps,
-            "speedup": speedup}
+    print(f"# continuous batching: {eng_tps:.1f} tok/s", flush=True)
+    return {"n": n, "engine_tok_s": eng_tps}
 
 
 def bench_skewed(model, params, cfg, n):
@@ -251,9 +208,8 @@ def bench_skewed(model, params, cfg, n):
 def make_long_trace(cfg, n, seed=3):
     """A few short prompts that decode for the whole run (the decode-SLO
     population) plus ``n`` long-prompt short-generation requests that keep
-    a prefill in flight almost continuously. Under whole-prompt prefill
-    every long admission stalls the residents for the full prompt's
-    forward; chunked prefill bounds the per-tick stall at one chunk."""
+    a prefill in flight almost continuously. Chunked prefill bounds the
+    per-tick stall of the residents at one chunk."""
     rng = np.random.default_rng(seed)
     reqs = []
     for i in range(LONG_RESIDENTS):
@@ -272,8 +228,8 @@ def _pct(xs, q):
 
 
 def telemetry_section(engine, n):
-    """The ``telemetry`` block of BENCH_engine.json, read off the chunked
-    long-prompt engine (the trace with all three tick kinds in flight):
+    """The ``telemetry`` block of BENCH_engine.json, read off the
+    long-prompt engine (the trace with both tick kinds in flight):
     measured stall percentiles from the telemetry record and the roofline
     predicted-vs-measured calibration per tick kind — the scale factors
     and relative error `telemetry.calibrate` fits for hardware_model."""
@@ -308,63 +264,32 @@ def telemetry_section(engine, n):
 
 
 def bench_longprompt(model, params, cfg, n):
-    """Whole-prompt vs chunked prefill on the long-prompt trace: decode
-    tok/s, per-decode-tick stall p50/p99 (engine.stall_log), and TTFT.
-
-    Both modes run the same prefill-with-cache forward; "whole" sets the
-    chunk to the model length, so every prompt lands in ONE tick — exactly
-    the pre-chunking stall behaviour — while keeping the code path (and
-    therefore the greedy outputs, asserted token-identical) shared, so the
-    comparison isolates *chunking* rather than kernel numerics. The legacy
-    bucketed forward (``chunked_prefill=False``) stays covered for
-    exactness in tests/test_engine.py and test_chunked_prefill.py."""
+    """Chunked prefill on the long-prompt trace: decode tok/s,
+    per-decode-tick stall p50/p99 (engine.stall_log), and TTFT."""
     reqs = make_long_trace(cfg, n, seed=TRACE_SEEDS["long"])
     out = {"n": n, "prompt_len": LONG_PROMPT_LEN, "chunk": LONG_CHUNK}
-    results = {}
-    chunked_engine = None
-    for mode, chunk in (("whole", LONG_MAX_LEN), ("chunked", LONG_CHUNK)):
-        engine = build_engine(model, params, max_model_len=LONG_MAX_LEN,
-                              max_batch=LONG_RESIDENTS + 1,
-                              prefill_chunk=chunk)
-        if mode == "chunked":
-            chunked_engine = engine
-        outs, dt, stats = timed_run(engine, reqs, realtime=False)
-        stall_ms = [s * 1e3 for s in engine.stall_log]
-        ttft_ms = [t * 1e3 for t in engine.first_token_s.values()]
-        tps = stats["decode_tokens"] / dt
-        rec = {"decode_tok_s": tps,
-               "decode_ticks": stats["decode_ticks"],
-               "prefill_chunks": stats["prefill_chunks"],
-               "stall_p50_ms": _pct(stall_ms, 50),
-               "stall_p99_ms": _pct(stall_ms, 99),
-               "stall_max_ms": max(stall_ms) if stall_ms else 0.0,
-               "ttft_p50_ms": _pct(ttft_ms, 50),
-               "ttft_p99_ms": _pct(ttft_ms, 99)}
-        results[mode] = outs
-        out[mode] = rec
-        row(f"engine/longprompt-{mode}",
-            dt / max(stats["decode_tokens"], 1) * 1e6,
-            f"decode_tok_s={tps:.1f};stall_p99_ms={rec['stall_p99_ms']:.1f};"
-            f"ttft_p50_ms={rec['ttft_p50_ms']:.0f};"
-            f"chunks={stats['prefill_chunks']}")
-    for r in reqs:
-        assert np.array_equal(results["whole"][r.rid],
-                              results["chunked"][r.rid]), (
-            f"chunked prefill diverged from whole-prompt prefill for "
-            f"request {r.rid}")
-    red = out["whole"]["stall_p99_ms"] / max(out["chunked"]["stall_p99_ms"],
-                                             1e-9)
-    ratio = out["chunked"]["decode_tok_s"] / out["whole"]["decode_tok_s"]
-    out["stall_p99_reduction"] = red
-    out["decode_tok_s_ratio"] = ratio
-    row("engine/longprompt-stall-reduction", red,
-        f"reduction={red:.2f}x;tok_s_ratio={ratio:.2f};"
-        f"target>=2x@ratio+-10%;pass={red >= 2.0 and 0.9 <= ratio}")
-    print(f"# chunked prefill: decode-stall p99 "
-          f"{out['chunked']['stall_p99_ms']:.1f}ms vs whole-prompt "
-          f"{out['whole']['stall_p99_ms']:.1f}ms ({red:.2f}x lower) at "
-          f"{ratio:.2f}x decode tok/s (outputs identical)", flush=True)
-    return out, chunked_engine
+    engine = build_engine(model, params, max_model_len=LONG_MAX_LEN,
+                          max_batch=LONG_RESIDENTS + 1,
+                          prefill_chunk=LONG_CHUNK)
+    _, dt, stats = timed_run(engine, reqs, realtime=False)
+    stall_ms = [s * 1e3 for s in engine.stall_log]
+    ttft_ms = [t * 1e3 for t in engine.first_token_s.values()]
+    tps = stats["decode_tokens"] / dt
+    rec = {"decode_tok_s": tps,
+           "decode_ticks": stats["decode_ticks"],
+           "prefill_chunks": stats["prefill_chunks"],
+           "stall_p50_ms": _pct(stall_ms, 50),
+           "stall_p99_ms": _pct(stall_ms, 99),
+           "stall_max_ms": max(stall_ms) if stall_ms else 0.0,
+           "ttft_p50_ms": _pct(ttft_ms, 50),
+           "ttft_p99_ms": _pct(ttft_ms, 99)}
+    out["chunked"] = rec
+    row("engine/longprompt-chunked",
+        dt / max(stats["decode_tokens"], 1) * 1e6,
+        f"decode_tok_s={tps:.1f};stall_p99_ms={rec['stall_p99_ms']:.1f};"
+        f"ttft_p50_ms={rec['ttft_p50_ms']:.0f};"
+        f"chunks={stats['prefill_chunks']}")
+    return out, engine
 
 
 def _equal_budget_pages(cfg, kv_bits, page_size=16):
@@ -586,7 +511,7 @@ def main():
     ap.add_argument("--out", default="BENCH_engine.json",
                     help="machine-readable results file ('' disables)")
     ap.add_argument("--trace-out", default="",
-                    help="write the chunked long-prompt engine's telemetry "
+                    help="write the long-prompt engine's telemetry "
                          "as Chrome trace-event JSON to this path (open in "
                          "Perfetto; '' disables)")
     # parse_known_args: benchmarks/run.py invokes main() with its own tag
@@ -615,13 +540,13 @@ def main():
     if args.kv_requests:
         results["kv"] = bench_kv(model, params, cfg, args.kv_requests)
     if args.long_requests:
-        longprompt, chunked_engine = bench_longprompt(model, params, cfg,
-                                                      args.long_requests)
+        longprompt, long_engine = bench_longprompt(model, params, cfg,
+                                                   args.long_requests)
         results["longprompt"] = longprompt
-        results["telemetry"] = telemetry_section(chunked_engine,
+        results["telemetry"] = telemetry_section(long_engine,
                                                  args.long_requests)
         if args.trace_out:
-            write_chrome_trace(chunked_engine.telemetry, args.trace_out)
+            write_chrome_trace(long_engine.telemetry, args.trace_out)
             print(f"# wrote Chrome trace {args.trace_out} "
                   f"(open in https://ui.perfetto.dev)", flush=True)
     if args.sharded_requests:

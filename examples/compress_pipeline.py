@@ -7,7 +7,7 @@ budget, and serve with the quantized Pallas kernels.
 import sys
 
 import jax
-import jax.numpy as jnp
+import numpy as np
 
 sys.path.insert(0, "src")
 
@@ -16,7 +16,7 @@ from repro.core import amc, haq
 from repro.core.hardware_model import V5E_EDGE
 from repro.core.quantization import make_quant_dot
 from repro.configs import get_config
-from repro.launch.serve import generate
+from repro.serving.engine import Engine, Request, derive_policy
 
 
 def main():
@@ -51,9 +51,11 @@ def main():
 
     print("=== stage 3: serve the compressed model (Pallas int kernels) ===")
     dot = make_quant_dot({k: v for k, v in pol.items()}, use_kernel=True)
-    prompt = jnp.ones((1, 16), jnp.int32)
-    toks = generate(model, pruned, prompt, gen_len=8, dot=dot)
-    print("served tokens:", jax.device_get(toks[0, 16:]))
+    policy = derive_policy(model.cfg, V5E_EDGE, max_model_len=32,
+                           param_bytes=model.param_bytes())
+    req = Request(rid=0, prompt=np.ones(16, np.int32), max_new=8)
+    toks = Engine(model, pruned, policy, dot=dot).run([req])[0]
+    print("served tokens:", toks[16:])
     print("pipeline complete: prune -> quantize -> serve")
 
 

@@ -6,15 +6,15 @@ synthetic pipeline, checkpoint, restore, and serve a few tokens.
 import argparse
 import sys
 
-import jax
-import jax.numpy as jnp
+import numpy as np
 
 sys.path.insert(0, "src")
 
 from repro.configs import get_config, tiny_config
 from repro.configs.base import OptimConfig, ShapeConfig, TrainConfig
-from repro.launch.serve import generate
+from repro.core.hardware_model import V5E_EDGE
 from repro.models.api import build_model
+from repro.serving.engine import Engine, Request, derive_policy
 from repro.training.loop import train
 
 
@@ -39,9 +39,11 @@ def main():
           f"{out['history'][-1]['loss']}")
 
     params = out["state"]["params"]
-    prompt = jnp.ones((2, 16), jnp.int32)
-    toks = generate(model, params, prompt, gen_len=8)
-    print("generated:", jax.device_get(toks[0, 16:]))
+    policy = derive_policy(cfg, V5E_EDGE, max_model_len=32,
+                           param_bytes=model.param_bytes())
+    req = Request(rid=0, prompt=np.ones(16, np.int32), max_new=8)
+    toks = Engine(model, params, policy).run([req])[0]
+    print("generated:", toks[16:])
 
 
 if __name__ == "__main__":

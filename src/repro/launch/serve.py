@@ -1,9 +1,8 @@
 """Serving launcher — a thin CLI over the continuous-batching engine
-(serving/engine), with the sequential batched generate kept as the
-reference baseline for equivalence tests and throughput comparisons.
+(serving/engine): builds a model, derives or loads the admission policy,
+serves a seeded request trace and prints the throughput and counters.
 
 ``python -m repro.launch.serve --arch gemma2-2b --tiny --requests 8``
-``python -m repro.launch.serve --arch gemma2-2b --tiny --sequential``
 ``python -m repro.launch.serve --arch gemma2-2b --tiny --kv-bits 8``
 ``python -m repro.launch.serve --arch gemma2-2b --tiny --kv-policy haq``
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
@@ -18,132 +17,16 @@ from __future__ import annotations
 import argparse
 import json
 import time
-from typing import Dict, Tuple
+from typing import Dict
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, tiny_config
 from repro.core.hardware_model import HARDWARES, hardware_for_device
-from repro.core.quantization import make_quant_dot
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
-from repro.models.transformer import to_page_layout
 from repro.serving.engine import Engine, Request, derive_policy
-from repro.serving.engine.pool import quiet_donation
-
-# decode closures are cached per (cfg, dot) so repeated generate() calls —
-# one per request in the sequential baseline — reuse one jitted function
-# instead of retracing every call. Values hold the dot hook alive so id()
-# keys can't be recycled.
-_DECODE_JIT: Dict[Tuple, Tuple] = {}
-
-
-def _decode_fn(model, dot, kernel="auto"):
-    paged = model.cfg.family in ("dense", "moe", "vlm") \
-        and not model.cfg.is_encdec
-    key = (model.cfg, None if dot is None else id(dot), paged, kernel)
-    ent = _DECODE_JIT.get(key)
-    if ent is None:
-        if paged:
-            fn = jax.jit(lambda p, pool, pt, t, pos: model.decode_step_paged(
-                p, pool, pt, t, pos, dot=dot, kernel=kernel),
-                donate_argnums=(1,))
-        else:
-            fn = jax.jit(lambda p, c, t, pos: model.decode_step(p, c, t, pos,
-                                                                dot=dot))
-        ent = (fn, dot)
-        _DECODE_JIT[key] = ent
-    return ent[0], paged
-
-
-def _identity_paged_pool(cache, B: int, max_len: int, page: int):
-    """Scatter a full-layout prefill cache into a fresh identity-mapped page
-    pool: sequence b's logical block i lives at physical page 1 + b*ppseq
-    + i (page 0 stays the scratch page, as in the engine)."""
-    ppseq = -(-max_len // page)
-    span = ppseq * page
-    pt = np.arange(B * ppseq, dtype=np.int32).reshape(B, ppseq) + 1
-
-    def to_pages(c):                     # (G, B, S, K, hd) full layout
-        pad = [(0, 0)] * c.ndim
-        pad[2] = (0, span - c.shape[2])
-        c = jnp.pad(c, pad)
-        c = to_page_layout(c.reshape(c.shape[0], B * span, *c.shape[3:]),
-                           page)
-        pool = jnp.zeros((c.shape[0], B * ppseq + 1) + c.shape[2:], c.dtype)
-        return pool.at[:, 1:].set(c)
-
-    return jax.tree.map(to_pages, cache), jnp.asarray(pt)
-
-
-def generate(model, params, prompt_tokens, gen_len: int, *, temperature=0.0,
-             dot=None, key=None, page_size: int = 16, kernel: str = "auto"):
-    """prompt (B, S) -> (B, S+gen_len).
-
-    Sequential baseline: one fixed batch, no admission — the engine's
-    continuous batching supersedes this for traffic; kept as the exactness
-    reference. Decode runs the same paged-attention walk as the engine over
-    an identity page table (block i of sequence b at page 1 + b*ppseq + i,
-    ``page_size`` matching the default admission policy), so the reduction
-    order — and therefore every greedy token — is bit-comparable with the
-    engine regardless of batch composition, growth, or preemption. The
-    paged walk itself is validated against the dense oracle in
-    tests/test_kernels.py; dense ring-buffer decode stays covered by
-    tests/test_decode_equivalence.py.
-
-    Families the engine does not serve (ssm / hybrid / encdec) fall back to
-    the dense-cache ``decode_step`` path."""
-    B, S = prompt_tokens.shape
-    max_len = S + gen_len
-    decode, paged = _decode_fn(model, dot, kernel)
-
-    logits, cache = model.prefill(params, {"tokens": prompt_tokens}, dot=dot,
-                                  cache_layout="full")
-    if paged:
-        pool, pt = _identity_paged_pool(cache, B, max_len, page_size)
-    else:
-        cache = _grow_cache(model, cache, S, max_len)
-
-    out = [prompt_tokens]
-    tok = _sample(logits, temperature, key)
-    for i in range(gen_len):
-        out.append(tok)
-        if i == gen_len - 1:
-            break
-        if paged:
-            positions = jnp.full((B,), S + i, jnp.int32)
-            with quiet_donation():
-                logits, pool = decode(params, pool, pt, tok, positions)
-        else:
-            logits, cache = decode(params, cache, tok,
-                                   jnp.asarray(S + i, jnp.int32))
-        if key is not None:
-            key = jax.random.fold_in(key, i)
-        tok = _sample(logits, temperature, key)
-    return jnp.concatenate(out, axis=1)
-
-
-def _grow_cache(model, cache, cur: int, max_len: int):
-    """Pad dense KV caches from prefill length to max_len (the non-paged
-    family fallback)."""
-    def grow(path, a):
-        ks = jax.tree_util.keystr(path)
-        if a.ndim == 5 and "mamba" not in ks and a.shape[2] == cur:
-            pad = [(0, 0)] * 5
-            pad[2] = (0, max_len - cur)
-            return jnp.pad(a, pad)
-        return a
-    return jax.tree_util.tree_map_with_path(grow, cache)
-
-
-def _sample(logits, temperature, key):
-    logits = logits[:, -1]
-    if temperature <= 0.0 or key is None:
-        return jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-    return jax.random.categorical(key, logits / temperature)[:, None] \
-        .astype(jnp.int32)
 
 
 def _parse_mesh(spec: str) -> Dict[str, int]:
@@ -179,16 +62,14 @@ def main():
                          "(default: the attached device, looked up by "
                          "its device kind)")
     ap.add_argument("--requests", type=int, default=8,
-                    help="engine mode: number of requests in the trace")
-    ap.add_argument("--batch", type=int, default=4,
-                    help="sequential mode: fixed batch size")
+                    help="number of requests in the trace")
     ap.add_argument("--max-batch", type=int, default=0,
                     help="override the policy's max in-flight batch")
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--page-size", type=int, default=16,
-                    help="KV pool page size in tokens (both modes)")
+                    help="KV pool page size in tokens")
     ap.add_argument("--paged-kernel", default="auto",
                     choices=("auto", "pallas", "ref"),
                     help="paged-attention path: Pallas page-walk kernel, "
@@ -198,54 +79,43 @@ def main():
                          "prompt+max_new at admission instead of growing "
                          "lazily with preemption")
     ap.add_argument("--prefill-chunk", type=int, default=0,
-                    help="engine mode: override the policy's roofline-"
+                    help="override the policy's roofline-"
                          "derived prompt chunk (tokens per prefill tick; "
                          "0 keeps the derived value)")
-    ap.add_argument("--no-chunked-prefill", action="store_true",
-                    help="engine mode: prefill whole prompts into padding "
-                         "buckets in one forward (the pre-chunking "
-                         "behaviour — one long prompt stalls every "
-                         "resident decode for its full prefill latency)")
     ap.add_argument("--expected-occupancy", type=float, default=None,
                     help="fraction of max_model_len the admission policy "
                          "assumes a typical sequence occupies (default "
                          "0.5, or 1.0 with --reserve-upfront: worst-case "
                          "reservation can never fill slots an expected-"
                          "footprint batch was sized for)")
-    ap.add_argument("--sequential", action="store_true",
-                    help="legacy fixed-batch loop instead of the engine")
-    ap.add_argument("--quant-policy", default="",
-                    help="json file: {site: [w_bits, a_bits]} "
-                         "(sequential mode only)")
     ap.add_argument("--kv-bits", type=int, default=16,
                     choices=(4, 8, 16),
-                    help="engine mode: stored KV-cache bits for the paged "
-                         "pool, uniform across layers (16 = bf16 exact "
-                         "baseline; 8/4 = serving/kvquant int pages with "
-                         "per-token per-head scales, dequant fused into "
-                         "the paged-attention walk)")
+                    help="stored KV-cache bits for the paged pool, "
+                         "uniform across layers (16 = bf16; 8/4 = "
+                         "serving/kvquant int pages with per-token "
+                         "per-head scales, dequant fused into the "
+                         "paged-attention walk)")
     ap.add_argument("--mesh", default="",
-                    help="engine mode: SPMD serving over a device mesh, "
+                    help="SPMD serving over a device mesh, "
                          "e.g. 'model=2' or 'model=2,data=4' — the paged "
                          "pool shards kv_heads over the model axis, params "
-                         "spread at rest over the whole mesh, outputs stay "
-                         "token-identical to the 1-device engine (off-TPU "
+                         "spread at rest over the whole mesh (off-TPU "
                          "set XLA_FLAGS=--xla_force_host_platform_device_"
                          "count=N first)")
     ap.add_argument("--trace-out", default="",
-                    help="engine mode: write the telemetry tick trace + "
+                    help="write the telemetry tick trace + "
                          "request spans as Chrome trace-event JSON to this "
                          "path (open in Perfetto / chrome://tracing) and "
                          "print the telemetry summary")
     ap.add_argument("--serving-config", default="",
-                    help="engine mode: load a searched per-hardware "
+                    help="load a searched per-hardware "
                          "serving config JSON (serving/autotune, written "
                          "by --autotune-out or the bench's "
                          "--autotune-config-out) instead of hand-picking "
                          "knobs; owns page size, prefill chunk, occupancy, "
                          "KV policy, mesh split, and the batch cap")
     ap.add_argument("--autotune", type=int, default=0, metavar="BUDGET",
-                    help="engine mode: autotune the serving config before "
+                    help="autotune the serving config before "
                          "serving — calibrate the admission roofline on a "
                          "warmup run, search the config space "
                          "(DDPG + evolution, serving/autotune) with this "
@@ -257,7 +127,7 @@ def main():
                          "config JSON here for --serving-config to load "
                          "back ('' disables)")
     ap.add_argument("--kv-policy", default="",
-                    help="engine mode: per-layer KV bit policy — 'haq' "
+                    help="per-layer KV bit policy — 'haq' "
                          "runs the HAQ search over KV sites "
                          "(serving/kvquant/policy.py: roofline feedback, "
                          "sensitivity-gated int4), or a json file mapping "
@@ -268,21 +138,6 @@ def main():
     enable_compile_cache()
     if args.prompt_len < 1:
         ap.error("--prompt-len must be >= 1")
-    if args.quant_policy and not args.sequential:
-        ap.error("--quant-policy applies to --sequential mode only; the "
-                 "engine derives its quantization from the admission policy")
-    if args.sequential and (args.kv_policy or args.kv_bits != 16):
-        ap.error("--kv-bits/--kv-policy apply to engine mode only; the "
-                 "sequential baseline is the fp exactness reference")
-    if args.sequential and args.mesh:
-        ap.error("--mesh applies to engine mode only; the sequential "
-                 "baseline is the single-device exactness reference")
-    if args.sequential and args.trace_out:
-        ap.error("--trace-out applies to engine mode only; the sequential "
-                 "baseline has no telemetry recorder")
-    if args.sequential and (args.autotune or args.serving_config):
-        ap.error("--autotune/--serving-config apply to engine mode only; "
-                 "the sequential baseline has no admission policy to tune")
     if args.autotune and args.serving_config:
         ap.error("--serving-config loads a finished search; drop it or "
                  "drop --autotune")
@@ -297,31 +152,6 @@ def main():
     cfg = tiny_config(args.arch) if args.tiny else get_config(args.arch)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-
-    if args.sequential:
-        dot = None
-        if args.quant_policy:
-            policy = {k: tuple(v) for k, v in
-                      json.load(open(args.quant_policy)).items()}
-            dot = make_quant_dot(policy)
-            print(f"serving with quantization policy over "
-                  f"{len(policy)} sites")
-        prompt = jnp.asarray(
-            np.random.default_rng(0).integers(
-                2, cfg.vocab_size, (args.batch, args.prompt_len)), jnp.int32)
-        t0 = time.time()
-        out = generate(model, params, prompt, args.gen,
-                       temperature=args.temperature, dot=dot,
-                       page_size=args.page_size, kernel=args.paged_kernel,
-                       key=jax.random.PRNGKey(1)
-                       if args.temperature > 0 else None)
-        dt = time.time() - t0
-        print(f"{cfg.name}: generated {args.gen} tokens x batch "
-              f"{args.batch} in {dt:.2f}s "
-              f"({args.gen * args.batch / dt:.1f} tok/s)")
-        print("sample:",
-              np.asarray(out[0, args.prompt_len:args.prompt_len + 16]))
-        return
 
     hw = HARDWARES[args.hw] if args.hw \
         else hardware_for_device(jax.devices()[0])
@@ -422,7 +252,6 @@ def main():
             policy = dataclasses.replace(policy, **over)
     print(f"admission[{hw.name}]: max_batch={policy.max_batch} "
           f"prefill_chunk={policy.prefill_chunk} "
-          f"chunked={not args.no_chunked_prefill} "
           f"quant={policy.quant_bits}b "
           f"kv={policy.kv_bits or 'bf16'} pages={policy.num_pages} "
           f"page_size={policy.page_size} "
@@ -430,9 +259,7 @@ def main():
           f"(est decode {policy.est_decode_s * 1e3:.2f}ms/step)")
     engine = Engine(model, params, policy, temperature=args.temperature,
                     paged_kernel=args.paged_kernel,
-                    reserve_upfront=args.reserve_upfront,
-                    chunked_prefill=not args.no_chunked_prefill,
-                    mesh=mesh)
+                    reserve_upfront=args.reserve_upfront, mesh=mesh)
     t0 = time.time()
     outs = engine.run(reqs)
     dt = time.time() - t0

@@ -4,7 +4,7 @@ This is the deployed counterpart of the paper's hardware-in-the-loop search:
 the same roofline simulator (`core/hardware_model.py`) that scores NAS/HAQ
 candidates at *search* time sizes the runtime at *serve* time — KV pool
 capacity from the target's HBM, max in-flight batch from the decode-latency
-roofline, prompt padding buckets from the prefill roofline, and a HAQ bit
+roofline, the prompt chunk from the prefill roofline, and a HAQ bit
 policy (via `serving/quant.py`) when the memory roofline demands it.
 
 Page-table layout
@@ -29,9 +29,9 @@ slot) needs, then grows page-by-page as decode crosses block boundaries.
 On pool exhaustion the youngest active sequence is preempted — its pages
 are freed and it is requeued at the FIFO front with its generated tokens
 folded into the prompt, so its next admission re-prefills the extension
-(recompute) and greedy outputs are unchanged. Freed pages are recycled
+(recompute). Freed pages are recycled
 without clearing: a new owner only ever reads slots at ``j <= pos`` that it
-has itself written (prefill spans, then decode writes in position order),
+has itself written (prompt chunks, then decode writes in position order),
 so stale KV from a previous owner stays behind the mask. The legacy
 worst-case policy — ``ceil((prompt + max_new) / page_size)`` pages reserved
 at admission, no preemption — remains available as ``reserve_upfront``.
@@ -45,7 +45,7 @@ sequence's state lives in its batch slot's row; idle decode rows update
 the last, scratch row. A sequence's first prompt chunk, at position 0,
 starts from zero state, so a reused slot and a preempted, recomputed
 sequence start clean; only a chunk's real tokens advance the state. The
-state is served on one device, with chunked prefill and a bf16 K/V pool;
+state is served on one device, with a bf16 K/V pool;
 admission reserves every slot's state before it sizes the pages.
 
 Chunked-prefill lifecycle
@@ -67,20 +67,14 @@ jaxpr). Chunk states per sequence:
               is unembedded, the first token sampled)
            -> finished / preempted (a mid-prefill victim is requeued at
               its chunk boundary and simply restarts the prompt at
-              re-admission — prefill is deterministic, so resumption is
-              token-identical)
+              re-admission)
 
 ``prefill_stall_factor`` is therefore a **per-tick** stall budget: the
 admission policy sizes ``prefill_chunk`` as the largest chunk whose
 prefill-with-cache latency (priced at worst-case resident context) stays
 within ``prefill_stall_factor * decode_slo_s``, so a long prompt costs
-more ticks — never a longer stall of resident decodes. Whole-prompt
-bucketed prefill (``chunked_prefill=False``, one forward padded to the
-chunk quantum) is kept as the pre-chunking baseline; greedy outputs are
-identical either way (asserted across chunk sizes, page sizes, GQA,
-windows, and quantized pools in tests/test_chunked_prefill.py, with the
-stall win measured by the long-prompt bench and enforced by the CI
-bench-gate).
+more ticks — never a longer stall of resident decodes. The chunk
+program is the only way a prompt enters the pool.
 
 The scheduler packs active sequences into a fixed-width batch; a decode
 tick calls ``Model.decode_step_paged`` with:
@@ -97,22 +91,25 @@ Pallas paged-attention kernel (kernels/paged_attention.py) on TPU, its
 pure-JAX block-walk twin (kernels/ref.py) elsewhere — with local-window
 layers trimming the walk to their window; the dense chronological
 (B, max_pages*page_size, K, hd) KV view is never materialized. RoPE is
-applied at cache-write time with absolute positions, and the sequential
-`launch.serve.generate` baseline decodes through the same walk over an
-identity page table, so the engine's greedy outputs — across batching,
-growth, and preemption — are token-identical to it (asserted in
-tests/test_engine.py; the walk itself is validated against the dense
-oracle in tests/test_kernels.py).
+applied at cache-write time with absolute positions. The walk itself is
+validated against the dense oracle in tests/test_kernels.py. The engine
+tests judge every served token against one teacher-forced dense
+``Model.forward`` at batch 1 (no pages, no chunks): across batching,
+chunk sizes, growth and preemption, each served token's logit there lies
+within a stated gap of the best (``served_gaps`` in tests/conftest.py).
+bfloat16 near-ties mean the engine's greedy tokens need not equal that
+forward's argmax exactly. The float32 reference of the chip benchmark
+(bench/reference.py) judges the numerics at published widths.
 
 KV-cache quantization (serving/kvquant): ``AdmissionPolicy.kv_bits``
 selects a HAQ-searched per-sub-layer bit policy for the pool itself —
 pages stored int8/int4 (packed along head_dim) with per-page-slot per-head
-fp32 scale tiles, quantize-on-write in both writers, and dequantization
-fused into the paged-attention block walk. ``kv_bytes_per_token`` and page
-sizing are bit-policy-aware, so the same HBM budget holds 2-4x the pages
-and admission fits correspondingly more resident sequences; the fp pool
-remains the token-exact baseline (quantized drift is bounded and measured,
-see kvquant.drift).
+fp32 scale tiles, quantize-on-write in both writers (the chunk and decode
+programs), and dequantization fused into the paged-attention block walk.
+``kv_bytes_per_token`` and page sizing are bit-policy-aware, so the same
+HBM budget holds 2-4x the pages and admission fits correspondingly more
+resident sequences; quantized drift against the fp pool is bounded and
+measured (kvquant.drift).
 
 On models whose every attention layer is local (sliding-window), pages
 wholly behind the window are released back to the allocator as decode
@@ -122,8 +119,8 @@ table as scratch-page placeholders the walk never reads).
 SPMD serving (``Engine(mesh=...)``, serving/engine/sharded.py)
 --------------------------------------------------------------
 The engine runs over a ("data", "model") device mesh with every jitted
-tick (decode, chunk prefill, whole-prompt prefill, pool span-writer)
-shard_map'd. Per-device layout:
+tick (decode, chunk prefill, the first token's unembedding) shard_map'd.
+Per-device layout:
 
     sharded over ``model`` (size N):
         pool["sub{j}"]["k"|"v"]      (G, num_pages, K/N, page, hd)
@@ -146,13 +143,15 @@ shard_map'd. Per-device layout:
         covers every shard's kv-head slice of that page
 
 The ``data`` axis is at-rest param FSDP only (batch-sharding the decode
-tick is the async-host-loop follow-on). Exactness contract: kv_heads must
-divide the model axis (page slots stay whole so the online softmax keeps
-its 1-device reduction order), and greedy outputs on any mesh are
-bit-identical to the 1-device engine across fp/int8/HAQ-mixed pools,
-chunked prefill, GQA, windows, and forced preemption — asserted in
-tests/test_sharded_engine.py and gated in CI (multi-device job +
-scripts/check_bench_regression.py sharded floors).
+tick is the async-host-loop follow-on). kv_heads must divide the model
+axis, so page slots stay whole and the online softmax keeps its 1-device
+reduction order. tests/test_sharded_engine.py checks that greedy tokens
+on forced host devices equal the 1-device engine's across fp/int8/
+HAQ-mixed pools, GQA, windows and forced preemption (the multi-device CI
+job, with the sharded floors of scripts/check_bench_regression.py). On
+the chip nothing holds the tokens equal: ``chip_smoke.py --chips 4``
+bounds the sharded logits against the 1-device engine's and prints token
+identity per request.
 
 Observability (serving/telemetry)
 ---------------------------------
@@ -160,8 +159,8 @@ Every engine owns a `Telemetry` recorder (in-memory and jax-free — it
 costs a few dataclass appends per tick, and greedy outputs are
 untouched). Two event streams, and phase spans:
 
-**Tick events** — one per jitted dispatch, ``kind`` in {``prefill``,
-``chunk``, ``decode``}::
+**Tick events** — one per jitted dispatch, ``kind`` in {``chunk``,
+``decode``}::
 
     TickEvent(kind, step, t_start, measured_s, predicted_s,
               batch, padded_batch, q_len, tokens, rids, admitted,
@@ -193,8 +192,6 @@ near no-op. The tree, with the benchmark metric that reads it::
 
     engine.step                   Engine.step, whole     engine.schedule_ms
       engine.admit                scheduler.admit and the admission loop
-        engine.prefill            whole-prompt prefill (unchunked mode)
-          engine.prefill.dispatch / engine.prefill.fence
       engine.chunk                one per prompt chunk
         engine.chunk.prepare      host token / page-table arrays
         engine.chunk.dispatch     input transfer + the jit call returning
@@ -221,8 +218,9 @@ The metrics registry (``engine.telemetry.metrics``) rolls the streams
 into counters/gauges/histograms: ``ticks.*``, ``tokens.*``,
 ``pool.free`` (min = low-water mark), ``pool.occupancy`` (fragmentation
 is 1 - occupancy), ``pool.min_free``, ``preemptions``,
-``jit.*.hits/misses/cache_size`` (steady-state decode must not
-retrace), ``tick.*.measured_s`` and ``stall.measured_s`` histograms.
+``jit.decode.cache_size`` / ``jit.chunk.cache_size`` (steady-state
+serving must not retrace), ``tick.*.measured_s`` and
+``stall.measured_s`` histograms.
 
 Exports: ``telemetry.write_chrome_trace(engine.telemetry, path)`` emits
 Chrome trace-event JSON — open it at https://ui.perfetto.dev (or
@@ -270,8 +268,7 @@ config's measured decode tok/s never falls below 0.95x the default
 (scripts/check_bench_regression.py, ``autotune`` floors); nightly runs
 the full budget.
 
-Modules: `pool` (page allocator + device pool + bounded jit caches +
-span-capable prefill writer), `scheduler` (FIFO admission / growth /
+Modules: `pool` (page allocator + device pool), `scheduler` (FIFO admission / growth /
 preemption / eviction / window-trim / prefill-progress bookkeeping),
 `admission` (roofline-derived policy, expected-footprint batch sizing,
 KV-bit-aware page sizing, per-tick chunk sizing, mesh-aware per-shard

@@ -12,8 +12,7 @@ must not answer by guessing:
                       whose prefill-with-cache forward keeps the
                       *per-tick* decode stall within the stall budget
                       (``prefill_stall_factor`` SLOs) — long prompts cost
-                      more ticks, never a bigger stall. Whole-prompt mode
-                      reuses it as the padding-bucket quantum.
+                      more ticks, never a bigger stall.
   * ``quant_bits``  — 16 (bf16) unless weights + one sequence of KV exceed
                       the HBM budget, in which case the HAQ default bit
                       policy (serving/quant.py) is applied: 8, then 4
@@ -216,8 +215,7 @@ class RooflinePredictor:
     (idle decode slots ride along) at worst-case resident context, with
     the policy's weight bits, KV bit policy (decode only, matching
     `step_latency`), and mesh split. The memo makes the per-tick cost a
-    dict lookup: decode always hits one key, chunk prefill one more, and
-    whole-prompt prefill one per padding bucket.
+    dict lookup: decode always hits one key, and a prompt chunk one more.
 
     Hand-built policies (tests) may name a hardware target that is not in
     ``HARDWARES``; prediction is then 0.0 — "no prediction" — which

@@ -4,9 +4,7 @@ loop of interleaved prefill and decode ticks.
 One ``step()``:
   1. admission — backfill free batch slots from the FIFO queue (page-
      and slot-gated, see scheduler.py). Admitted sequences owe their
-     prompt to the pool: in chunked mode (default) nothing runs yet; with
-     ``chunked_prefill=False`` the whole prompt runs here, padded to the
-     policy's bucket, and is scattered into the request's pages;
+     prompt to the pool; nothing runs yet;
   2. chunked prefill — every mid-prefill sequence advances by at most ONE
      ``policy.prefill_chunk``-token chunk (prefill-with-cache forward:
      the chunk's K/V are written into the sequence's pages and its
@@ -29,22 +27,19 @@ One ``step()``:
 
 The decode closure is jitted ONCE per engine (fixed shapes: the policy's
 max_batch and page-table width), and so is the chunk-prefill closure
-(fixed (1, chunk) tokens against the full-width page table, pool donated);
-whole-prompt prefill and pool-writer jits are compiled per padding bucket
-and held in small LRU caches so long-running engines with many bucket
-shapes don't grow retrace caches without limit. When the policy's memory
-roofline demanded it, weights are HAQ-quantized (serving/quant.py) and the
-dequantizing ``dot`` is threaded through both paths. ``policy.kv_bits``
-additionally selects the HAQ KV-quantized pool (serving/kvquant): pages
-stored int8/int4 with per-token per-head scales, quantize-on-write in all
-three writers (bucketed prefill, chunk forward, decode scatter), fused
-dequant inside the paged-attention walk — the fp pool stays the exactness
-baseline. On all-local-attention models, pages wholly behind the sliding
-window are released back to the allocator each tick
-(scheduler.trim_window).
+(fixed (1, chunk) tokens against the full-width page table, pool donated),
+so every prompt length runs the same two executables. When the policy's
+memory roofline demanded it, weights are HAQ-quantized (serving/quant.py)
+and the dequantizing ``dot`` is threaded through both paths.
+``policy.kv_bits`` additionally selects the HAQ KV-quantized pool
+(serving/kvquant): pages stored int8/int4 with per-token per-head scales,
+quantize-on-write in both writers (the chunk forward and the decode
+scatter), fused dequant inside the paged-attention walk. On
+all-local-attention models, pages wholly behind the sliding window are
+released back to the allocator each tick (scheduler.trim_window).
 
-Observability (serving/telemetry): every jitted dispatch — whole-prompt
-prefill, prompt chunk, batched decode — emits a typed ``TickEvent``
+Observability (serving/telemetry): every jitted dispatch — prompt chunk
+or batched decode — emits a typed ``TickEvent``
 into the engine's ``Telemetry`` recorder, carrying the *measured* wall
 clock (fenced: the engine blocks on the dispatch's outputs before the
 timer stops, so async jit dispatch is never billed as compute) next to
@@ -76,15 +71,15 @@ from repro.models.transformer import (PAGED_FAMILIES, has_state,
                                       normalize_kv_bits, sublayer_kinds)
 from repro.serving.engine.admission import AdmissionPolicy, \
     RooflinePredictor
-from repro.serving.engine.pool import JitLRU, PagedKVPool, quiet_donation
+from repro.serving.engine.pool import PagedKVPool, quiet_donation
 from repro.serving.engine.scheduler import ActiveSeq, Request, Scheduler
 from repro.serving.telemetry import Telemetry, TickEvent
 from repro.serving import quant as squant
 
 
 def sample_token(logits_row, temperature: float, key) -> int:
-    """One token from a (V,) f32 logits row (host array on the greedy path —
-    np.argmax ties break first-max, same as the baseline's jnp.argmax)."""
+    """One token from a (V,) f32 logits row (host array on the greedy path;
+    np.argmax ties break first-max)."""
     if temperature <= 0.0 or key is None:
         return int(np.argmax(logits_row))
     return int(jax.random.categorical(key, jnp.asarray(logits_row)
@@ -92,13 +87,10 @@ def sample_token(logits_row, temperature: float, key) -> int:
 
 
 class Engine:
-    PREFILL_JIT_CAP = 8   # LRU cap on per-bucket prefill jits
-
     def __init__(self, model, params, policy: AdmissionPolicy, *,
                  temperature: float = 0.0, seed: int = 0, dot=None,
                  paged_kernel: str = "auto", reserve_upfront: bool = False,
-                 chunked_prefill: bool = True, mesh=None,
-                 telemetry: Optional[Telemetry] = None,
+                 mesh=None, telemetry: Optional[Telemetry] = None,
                  roofline_scales=None):
         cfg = model.cfg
         if cfg.is_encdec or cfg.family not in PAGED_FAMILIES \
@@ -109,13 +101,12 @@ class Engine:
                 f"frontend={cfg.frontend!r}) is an open item (ROADMAP)")
         # Mamba layers keep per-sequence state in slot rows beside the
         # pages (batch slot s in row s, idle decode rows in the scratch row
-        # max_batch); only the chunked path carries it
+        # max_batch)
         self._stateful = has_state(cfg)
-        if self._stateful and (not chunked_prefill or mesh is not None
-                               or policy.kv_bits is not None):
+        if self._stateful and (mesh is not None or policy.kv_bits is not None):
             raise NotImplementedError(
                 f"{cfg.name} keeps recurrent state: served on one device "
-                f"with chunked prefill and a bf16 K/V pool only")
+                f"with a bf16 K/V pool only")
         self.model = model
         self.policy = policy
         self.temperature = temperature
@@ -156,10 +147,8 @@ class Engine:
                         policy.pages_per_seq + 1)
         self.kv_bits = normalize_kv_bits(cfg, policy.kv_bits)
         # SPMD serving (serving/engine/sharded.py): params and the paged
-        # pool sharded over the mesh, decode/prefill/writer jits shard_map'd,
-        # the host-side scheduler/page-table state untouched. The unsharded
-        # engine stays the token-exact baseline the sharded one is asserted
-        # bit-identical to.
+        # pool sharded over the mesh, decode/chunk jits shard_map'd, the
+        # host-side scheduler/page-table state untouched.
         self.mesh = mesh
         spmd = None
         if mesh is not None:
@@ -187,27 +176,11 @@ class Engine:
             not reserve_upfront and kinds
             and all(k["attn"] == "local" for k in kinds)) else None
 
-        # jit once: fixed (max_batch, pages_per_seq) shapes for decode;
-        # prefill compiles per padding bucket (LRU below). The pool is
-        # donated so decode ticks update it in place instead of double-
-        # buffering it. Under a mesh every closure is the shard_map'd twin
-        # with the identical signature, so the host loop never branches.
-        def prefill_body(p, toks, last_idx, dot_):
-            # unembed only the last real prompt position — the prompt is
-            # padded to the bucket, so a full (B, Sp, V) unembed would be
-            # bucket/1 overcompute per admission.
-            hidden, cache, _, _ = model.forward(
-                p, {"tokens": toks}, want_cache=True, unembed_mode="none",
-                cache_layout="full", dot=dot_)
-            h = jnp.take_along_axis(hidden, last_idx.reshape(1, 1, 1),
-                                    axis=1)
-            return model.unembed(p, h, dot=dot_), cache
-
-        # one jit instance per padding bucket, bounded: evicting an entry
-        # drops its compiled executable (a single shared jax.jit would keep
-        # every bucket's trace alive for the engine's lifetime).
-        self._prefill_jits = JitLRU(self.PREFILL_JIT_CAP)
-        self.chunked = chunked_prefill
+        # jit once: fixed (max_batch, pages_per_seq) shapes for decode and
+        # (1, prefill_chunk) for a prompt chunk. The pool is donated so
+        # ticks update it in place instead of double-buffering it. Under a
+        # mesh every closure is the shard_map'd twin with the identical
+        # signature, so the host loop never branches.
         if spmd is None and self._stateful:
             # the state slot of each row, and the chunk's real tokens
             self._decode = jax.jit(
@@ -231,17 +204,12 @@ class Engine:
                     p, pool, pt, toks, pos, dot=dot, kernel=paged_kernel),
                 donate_argnums=(1,))
         if spmd is None:
-            self._make_prefill = lambda: jax.jit(
-                lambda p, t, i: prefill_body(p, t, i, dot))
             self._unembed_row = jax.jit(
                 lambda p, h, idx: model.unembed(
                     p, jnp.take_along_axis(h, idx.reshape(1, 1, 1), axis=1),
                     dot=dot))
         else:
             self._decode = spmd.jit_decode()
-            self._make_prefill = lambda: spmd.make_prefill(
-                lambda p, t, i: prefill_body(spmd.gathered(p), t, i,
-                                             spmd.dot))
             self._chunk_prefill = spmd.jit_prefill_chunk()
             self._unembed_row = spmd.jit_unembed_row()
         self.stats = {"decode_ticks": 0, "decode_tokens": 0,
@@ -301,14 +269,12 @@ class Engine:
 
     # --------------------------------------------------------------- step --
     def step(self, now: float = float("inf")) -> List[int]:
-        """One scheduler tick: admit, run prefill work (the whole prompt in
-        one bucketed forward, or — chunked mode, the default — at most ONE
-        prompt chunk per mid-prefill sequence), then one batched decode
-        over the prefill-complete sequences. Returns the rids that
-        finished during this step. Finished sequences are released the
-        moment they finish — before the decode tick's growth phase — so
-        their pages backfill growth instead of tempting the preemption
-        picker."""
+        """One scheduler tick: admit, run at most ONE prompt chunk per
+        mid-prefill sequence, then one batched decode over the
+        prefill-complete sequences. Returns the rids that finished during
+        this step. Finished sequences are released the moment they finish
+        — before the decode tick's growth phase — so their pages backfill
+        growth instead of tempting the preemption picker."""
         with self.telemetry.span("engine.step"):
             self.telemetry.start_clock()
             self._step_idx += 1
@@ -321,15 +287,10 @@ class Engine:
                 for seq in self.scheduler.admit(now):
                     self.stats["admitted"] += 1
                     self._step_admitted += 1
-                    if not self.chunked:
-                        stall_pred += self._run_prefill(seq)
-                        if seq.is_done():
-                            out.append(self._finish(seq))
-            if self.chunked:
-                for seq in self.scheduler.prefill_pending():
-                    stall_pred += self._run_prefill_chunk(seq)
-                    if seq.prefill_done and seq.is_done():
-                        out.append(self._finish(seq))
+            for seq in self.scheduler.prefill_pending():
+                stall_pred += self._run_prefill_chunk(seq)
+                if seq.prefill_done and seq.is_done():
+                    out.append(self._finish(seq))
             t_prefill = time.monotonic() - t_prefill
             live = self.scheduler.decode_ready()
             if live:
@@ -383,8 +344,8 @@ class Engine:
         """Per-step gauges that aren't per-tick deltas: pool occupancy
         (token-granular — allocated pages may be mostly empty while
         sequences are young; fragmentation is 1 - occupancy) and the
-        jit-cache hit/miss counters (satellite: JitLRU observability —
-        steady-state decode must not retrace). O(active sequences)."""
+        jitted closures' cache sizes (steady-state serving must not
+        retrace). O(active sequences)."""
         with self.telemetry.span("engine.gauges"):
             m = self.telemetry.metrics
             a = self.kv.allocator
@@ -397,10 +358,6 @@ class Engine:
             cap = a.num_allocated * page
             m.gauge("pool.occupancy").set(used / cap if cap else 0.0)
             m.gauge("pool.min_free").set(a.min_free)
-            m.gauge("jit.prefill.hits").set(self._prefill_jits.hits)
-            m.gauge("jit.prefill.misses").set(self._prefill_jits.misses)
-            m.gauge("jit.pool_writer.hits").set(self.kv._write_jit.hits)
-            m.gauge("jit.pool_writer.misses").set(self.kv._write_jit.misses)
             # the once-jitted closures: retrace count straight from jax (a
             # steady-state engine holds these at 1)
             for name, fn in (("decode", self._decode),
@@ -430,40 +387,6 @@ class Engine:
         # emits another first_token edge, but TTFT views take the FIRST
         # edge — the request's first token was already served.
         self.telemetry.seq_event(seq.req.rid, "first_token", token=tok)
-
-    def _run_prefill(self, seq: ActiveSeq) -> float:
-        """Whole-prompt prefill (chunked_prefill=False): one forward over
-        the prompt padded to the policy's bucket, scattered into the
-        sequence's pages afterwards. One long prompt stalls every resident
-        decode for its full prefill latency — kept as the pre-chunking
-        baseline the bench compares against. Returns the roofline's
-        predicted seconds for the dispatch (the step's stall budget)."""
-        span = self.telemetry.span
-        with span("engine.prefill"):
-            prompt = np.asarray(seq.req.prompt, np.int32)
-            S = len(prompt)
-            chunk = self.policy.prefill_chunk
-            Sp = -(-S // chunk) * chunk
-            toks = np.zeros((1, Sp), np.int32)
-            toks[0, :S] = prompt
-            t_start = time.monotonic()
-            with span("engine.prefill.dispatch"):
-                prefill = self._prefill_jits.get(Sp, self._make_prefill)
-                logits, cache = prefill(self.params, jnp.asarray(toks),
-                                        jnp.asarray(S - 1, jnp.int32))
-                self.kv.write_prefill(cache, seq.pages)
-            # fence: the writer donated the pool, so blocking on (logits,
-            # pool) covers the whole admission dispatch before the timer
-            # stops
-            with span("engine.prefill.fence"):
-                jax.block_until_ready((logits, self.kv.pool))
-            pred = self._predict("prefill", 1, Sp)
-            self._emit_tick("prefill", t_start, time.monotonic() - t_start,
-                            pred, batch=1, padded_batch=1, q_len=Sp, tokens=S,
-                            rids=(seq.req.rid,))
-            seq.prefill_progress = S
-            self._first_token(seq, np.asarray(logits[0, 0]))
-        return pred
 
     def _run_prefill_chunk(self, seq: ActiveSeq) -> float:
         """One prompt chunk through the prefill-with-cache forward: the

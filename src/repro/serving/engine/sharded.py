@@ -2,12 +2,12 @@
 over a device mesh, keeping every host-side decision (admission, growth,
 preemption, window-trim, chunk accounting) untouched.
 
-Design: **exactness-first tensor parallelism**. The acceptance bar for the
-sharded engine is that greedy outputs on an N-device mesh are *bit-identical*
-to the 1-device engine (across fp and quantized pools, chunked prefill, GQA,
-windows, and forced preemption), so the partitioning only ever splits
-computations along *batch-like* dimensions — never along a floating-point
-reduction:
+Design: **exactness-first tensor parallelism**. The aim is greedy outputs
+on an N-device mesh that match the 1-device engine bit for bit (across fp
+and quantized pools, GQA, windows, and forced preemption;
+tests/test_sharded_engine.py checks token identity on forced host
+devices), so the partitioning only ever splits computations along
+*batch-like* dimensions — never along a floating-point reduction:
 
   * q/k/v projections contract over the replicated ``embed`` dim and are
     sharded on their **output** head dims (``heads``/``kv_heads`` on the
@@ -69,12 +69,6 @@ MODEL_AXIS = "model"
 # all-gathered at use inside the shard_map body.
 _LOCAL_KEYS = ("'wq'", "'wk'", "'wv'", "'w_in'", "'w_gate'")
 _LOCAL_AXES = ("heads", "kv_heads", "d_ff")
-
-# Full-layout prefill caches (G, B, Sp, K, hd): sharded on kv_heads only,
-# like the pool itself (transformer.pool_axes explains why cache_seq's
-# fall-through never applies to paged serving).
-_CACHE_KV_AXES = ("layer", None, None, "kv_heads", "head_dim")
-
 
 def _axes_tuple(a):
     return a if isinstance(a, tuple) else (a,)
@@ -183,8 +177,8 @@ def tp_dot(axis: str = MODEL_AXIS):
 
 class SpmdEngine:
     """Sharding context the Engine holds when built with a mesh: param /
-    pool placement plus the shard_map'd decode, chunk-prefill, whole-prompt
-    prefill, pool-writer, and unembed closures.
+    pool placement plus the shard_map'd decode, chunk-prefill and unembed
+    closures.
 
     All jits share one contract: page table / tokens / positions replicated,
     params per ``specs_for`` (gathered at use where a contraction would
@@ -229,13 +223,6 @@ class SpmdEngine:
     def gathered(self, params):
         return gather_at_use(params, self._plans)
 
-    def _cache_pspecs(self, cache):
-        """Full-layout prefill caches: (G, B, Sp, K, hd) sharded on K."""
-        return partition_specs(
-            jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
-                         if hasattr(a, "shape") else a, cache),
-            jax.tree.map(lambda a: _CACHE_KV_AXES, cache), self.mesh)
-
     # ---------------------------------------------------------------- jits --
     def jit_decode(self):
         model, dot, kernel = self.model, self.dot, self.kernel
@@ -274,32 +261,6 @@ class SpmdEngine:
         return jax.jit(jax.shard_map(
             body, mesh=self.mesh, in_specs=(self.param_pspecs, P(), P()),
             out_specs=P(), check_vma=False))
-
-    def make_prefill(self, prefill_fn):
-        """Whole-prompt (non-chunked) bucketed prefill: logits replicated,
-        cache K-sharded so the pool writer scatters shard-locally. One jit
-        per padding bucket, held in the engine's JitLRU like the unsharded
-        path."""
-        cache = self.model.cache_specs(1, 2)
-        cspecs = self._cache_pspecs(
-            {k: v for k, v in cache.items() if k.startswith("sub")})
-        return jax.jit(jax.shard_map(
-            prefill_fn, mesh=self.mesh,
-            in_specs=(self.param_pspecs, P(), P()),
-            out_specs=(P(), cspecs), check_vma=False))
-
-    def jit_pool_writer(self, write_fn, cache):
-        """shard_map'd span writer for one (n_pages, cache_len) shape:
-        ``write_fn(pool, cache, idx) -> pool`` with the full-layout cache
-        and the pool both sharded on kv_heads; the scatter at replicated
-        page ids is purely local. Donation rides the engine's JitLRU entry
-        exactly like the unsharded writer."""
-        cspecs = self._cache_pspecs(cache)
-        return jax.jit(jax.shard_map(
-            write_fn, mesh=self.mesh,
-            in_specs=(self.pool_pspecs, cspecs, P()),
-            out_specs=self.pool_pspecs, check_vma=False),
-            donate_argnums=(0,))
 
     # ------------------------------------------------------------ describe --
     def event_tags(self) -> dict:

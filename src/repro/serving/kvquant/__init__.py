@@ -61,8 +61,8 @@ effective context bounds the quantization-noise accumulation. The searched
 policy is a per-sub-layer tuple that threads through
 ``AdmissionPolicy.kv_bits`` -> ``Engine`` -> ``Model.init_pool``.
 
-The fp pool remains the exactness baseline; int8 greedy drift against it is
-bounded and asserted in tests/test_kvquant.py, and
+The fp pool is the baseline of the drift measurement: int8 greedy drift
+against it is bounded and asserted in tests/test_kvquant.py, and
 benchmarks/bench_engine_throughput.py headlines fp vs int8 vs HAQ-mixed
 decode throughput at equal HBM budget (BENCH_engine.json).
 """

@@ -26,8 +26,7 @@ from repro.serving.kvquant.quantize import quantize_pool
 
 def _identity_pool(cache, max_len: int, page: int):
     """B=1 identity-mapped page pool from a full-layout prefill cache:
-    logical block i at physical page 1 + i (page 0 stays scratch), the same
-    layout launch.serve's sequential baseline decodes through."""
+    logical block i at physical page 1 + i (page 0 stays scratch)."""
     ppseq = -(-max_len // page)
     span = ppseq * page
     pt = np.arange(1, ppseq + 1, dtype=np.int32)[None]

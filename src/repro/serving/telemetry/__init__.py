@@ -11,8 +11,8 @@ that: `admission.step_latency` *predicted* every tick, the engine
 *measured* nothing but two bare lists, and no code path ever compared
 the two. Telemetry closes the loop:
 
-* **Tick trace** — every jitted dispatch (whole-prompt prefill, prompt
-  chunk, batched decode) emits a typed `TickEvent` with fenced
+* **Tick trace** — every jitted dispatch (prompt chunk, batched
+  decode) emits a typed `TickEvent` with fenced
   wall-clock duration (the engine blocks on the dispatch's outputs
   before stopping the timer, so async jit dispatch is never billed as
   compute) next to the roofline prediction for the same shape, plus
@@ -24,8 +24,9 @@ the two. Telemetry closes the loop:
   ``Engine.stall_log`` / ``Engine.first_token_s`` survive as thin views
   over this record, so pre-telemetry tests and benches run unchanged.
 * **Metrics registry** — counters/gauges/histograms (pool occupancy,
-  free-page low-water mark, preemptions, JitLRU hit/miss, per-kind tick
-  latency): plain Python ints and lists, cheap enough to leave on.
+  free-page low-water mark, preemptions, the jitted closures' cache
+  sizes, per-kind tick latency): plain Python ints and lists, cheap
+  enough to leave on.
 * **Phase spans** — `Telemetry.span(name)` opens a named span of the
   engine's host loop (``engine.step``, ``engine.decode.sample``, ...; the
   tree is in serving/engine/__init__.py). By default it is a

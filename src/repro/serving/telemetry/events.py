@@ -1,8 +1,8 @@
 """Typed telemetry events: per-tick traces and per-sequence lifecycle
 spans.
 
-A **tick event** is one jitted engine dispatch — a whole-prompt prefill,
-one prompt chunk, or one batched decode step — carrying the measured
+A **tick event** is one jitted engine dispatch — one prompt chunk or one
+batched decode step — carrying the measured
 wall-clock duration (fenced: the engine blocks on the dispatch's outputs
 before stopping the timer, so async jit dispatch is never mistaken for
 compute) *next to* the roofline-predicted duration for the same shape.
@@ -25,7 +25,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Tuple
 
-TICK_KINDS = ("prefill", "chunk", "decode")
+TICK_KINDS = ("chunk", "decode")
 
 # sequence-span edge kinds, in lifecycle order (preempt/requeue may cycle)
 SEQ_EVENTS = ("enqueue", "admit", "chunk", "first_token", "preempt",
@@ -48,7 +48,7 @@ class TickEvent:
     allocations land on the step's first event and growth/trim/preempt
     frees land on the decode event that caused them.
     """
-    kind: str                 # "prefill" | "chunk" | "decode"
+    kind: str                 # "chunk" | "decode"
     step: int                 # engine step() index
     t_start: float            # absolute monotonic seconds
     measured_s: float

@@ -95,11 +95,10 @@ class MetricsRegistry:
 
     Naming convention (dotted, grep-able): ``ticks.decode``,
     ``tokens.decode``, ``preemptions``, ``pool.free`` (min = low-water
-    mark), ``pool.occupancy``, ``pool.min_free``, ``jit.prefill.hits`` /
-    ``.misses``, ``jit.pool_writer.hits`` / ``.misses``,
-    ``jit.decode.cache_size``, ``stall.measured_s`` (histogram),
-    ``tick.decode.measured_s`` (histogram), and the chunk / prefill twins
-    of the tick instruments."""
+    mark), ``pool.occupancy``, ``pool.min_free``,
+    ``jit.decode.cache_size`` / ``jit.chunk.cache_size``,
+    ``stall.measured_s`` (histogram), ``tick.decode.measured_s``
+    (histogram), and the chunk twins of the tick instruments."""
 
     def __init__(self):
         self.counters: Dict[str, Counter] = {}

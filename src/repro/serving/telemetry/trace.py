@@ -6,7 +6,7 @@ https://ui.perfetto.dev load directly — see launch/serve.py
 ``--trace-out``). Layout:
 
 * pid 1 ("engine ticks"): one complete ("ph":"X") slice per jitted
-  dispatch on a thread per tick kind (prefill / chunk / decode), with
+  dispatch on a thread per tick kind (chunk / decode), with
   measured vs predicted ms, batch composition, page deltas, and mesh
   tags in ``args`` — click a slice in Perfetto to read them;
 * pid 1, counter tracks ("ph":"C"): pool free pages and queue depth
@@ -26,9 +26,10 @@ import json
 from typing import Dict, List
 
 from repro.serving.telemetry.calibrate import calibrate
+from repro.serving.telemetry.events import TICK_KINDS
 from repro.serving.telemetry.recorder import Telemetry
 
-_TICK_TID = {"prefill": 1, "chunk": 2, "decode": 3}
+_TICK_TID = {kind: i + 1 for i, kind in enumerate(TICK_KINDS)}
 
 
 def _base_time(tel: Telemetry) -> float:
@@ -100,7 +101,7 @@ def write_chrome_trace(tel: Telemetry, path: str) -> None:
 
 def summarize(tel: Telemetry) -> str:
     """Plain-text rollup: tick counts, decode tok/s, stall / TTFT / queue
-    percentiles, pool watermarks, jit cache hit rates, and the roofline
+    percentiles, pool watermarks, jit cache sizes, and the roofline
     calibration table."""
     m = tel.metrics
 
@@ -108,7 +109,7 @@ def summarize(tel: Telemetry) -> str:
         return m.histogram(h).percentile(q) * 1e3
 
     lines = ["telemetry summary:"]
-    for kind in ("prefill", "chunk", "decode"):
+    for kind in TICK_KINDS:
         n = m.counter(f"ticks.{kind}").value
         if not n:
             continue
@@ -145,14 +146,10 @@ def summarize(tel: Telemetry) -> str:
         lines.append(f"  pool occupancy={occ:.2f} "
                      f"fragmentation={frag:.2f}")
     jit_bits = []
-    for name in ("prefill", "pool_writer"):
-        hits = m.gauge(f"jit.{name}.hits")
-        if hits.value is not None:
-            jit_bits.append(f"{name} {hits.value:.0f}h/"
-                            f"{m.gauge(f'jit.{name}.misses').value:.0f}m")
-    cache = m.gauge("jit.decode.cache_size")
-    if cache.value is not None and cache.value >= 0:
-        jit_bits.append(f"decode cache={cache.value:.0f}")
+    for name in ("chunk", "decode"):
+        cache = m.gauge(f"jit.{name}.cache_size")
+        if cache.value is not None and cache.value >= 0:
+            jit_bits.append(f"{name} cache={cache.value:.0f}")
     if jit_bits:
         lines.append("  jit: " + "  ".join(jit_bits))
     if tel.ticks:

@@ -91,23 +91,6 @@ def test_compare_only_reads_tok_s_leaves(gate):
 
 
 # ----------------------------------------------------- fresh-only checks --
-def test_check_longprompt_floors(gate):
-    ok = _doc(longprompt={"n": 6, "stall_p99_reduction": 4.0,
-                          "decode_tok_s_ratio": 1.05})
-    rows, failures = gate.check_longprompt(ok)
-    assert failures == [] and all(r[4] == "OK" for r in rows)
-
-    bad = _doc(longprompt={"n": 6, "stall_p99_reduction": 1.5,
-                           "decode_tok_s_ratio": 0.5})
-    _, failures = gate.check_longprompt(bad)
-    assert len(failures) == 2
-
-    # missing section / missing keys -> SKIP, not crash
-    assert gate.check_longprompt(_doc()) == ([], [])
-    rows, failures = gate.check_longprompt(_doc(longprompt={"n": 6}))
-    assert failures == [] and all("SKIP" in r[4] for r in rows)
-
-
 def test_check_sharded_floors(gate):
     ok = _doc(sharded={"outputs_identical": True,
                        "capacity": {"pages_scaling_2x": 2.0}})

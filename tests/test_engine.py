@@ -1,15 +1,15 @@
 """Continuous-batching engine: scheduler admission/eviction/backfill,
-roofline admission policy, paged-pool bookkeeping, and greedy equivalence
-with the sequential baseline."""
+roofline admission policy, paged-pool bookkeeping, and served tokens
+judged by their logit gap under the dense forward (conftest.served_gaps)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import GAP_TOL, assert_served, served_gaps, _dense_logits
 
 from repro.configs import get_config, tiny_config
 from repro.core.hardware_model import V5E_EDGE, V5E_POD
-from repro.launch.serve import generate
 from repro.models.api import build_model
 from repro.serving.engine import (AdmissionPolicy, Engine, PageAllocator,
                                   Request, Scheduler, derive_policy)
@@ -195,8 +195,9 @@ def gemma_tiny():
 
 
 def test_engine_matches_sequential_greedy(gemma_tiny):
-    """Mixed-length continuous batching is token-identical to serving each
-    request alone through the dense sequential baseline."""
+    """Mixed-length continuous batching serves every request the greedy
+    tokens of serving it alone: each within GAP_TOL of the best logit of
+    an unbatched dense forward over that request."""
     model, params = gemma_tiny
     engine = Engine(model, params, _policy())
     rng = np.random.default_rng(0)
@@ -211,11 +212,26 @@ def test_engine_matches_sequential_greedy(gemma_tiny):
     # batched: strictly fewer decode ticks than total decoded tokens
     assert engine.stats["decode_ticks"] < engine.stats["decode_tokens"]
     for r in reqs:
-        want = np.asarray(generate(model, params,
-                                   jnp.asarray(r.prompt[None]), r.max_new)[0])
-        got = outs[r.rid]
-        assert got.shape == (len(r.prompt) + r.max_new,)
-        assert np.array_equal(want, got), r.rid
+        assert outs[r.rid].shape == (len(r.prompt) + r.max_new,)
+    assert_served(model, params, reqs, outs)
+
+
+def test_served_gaps_has_teeth(gemma_tiny):
+    """A served token swapped for the token the dense forward ranks at the
+    vocabulary's median sits far above GAP_TOL: the oracle tells a wrong
+    token from a bfloat16 near-tie."""
+    model, params = gemma_tiny
+    r = _req(0, 20, 8)
+    served = Engine(model, params, _policy()).run([r])[0][20:]
+    assert served_gaps(model, params, r.prompt, served).max() <= GAP_TOL
+    V = model.cfg.vocab_size
+    rows = _dense_logits(model, params,
+                         np.concatenate([r.prompt, served[:-1]]))[19:]
+    for i, row in enumerate(rows):
+        wrong = served.copy()
+        wrong[i] = np.argsort(row[:V])[V // 2]
+        gaps = served_gaps(model, params, r.prompt, wrong)
+        assert gaps[i] > 20 * GAP_TOL, (i, float(gaps[i]))
 
 
 def test_engine_backfills_mid_flight(gemma_tiny):
@@ -253,11 +269,7 @@ def test_engine_moe_routing_smoke():
     params = model.init(jax.random.PRNGKey(0))
     engine = Engine(model, params, _policy(max_batch=2))
     reqs = [_req(i, 10, 4, vocab=cfg.vocab_size) for i in range(3)]
-    outs = engine.run(reqs)
-    for r in reqs:
-        want = np.asarray(generate(model, params,
-                                   jnp.asarray(r.prompt[None]), r.max_new)[0])
-        assert np.array_equal(want, outs[r.rid]), r.rid
+    assert_served(model, params, reqs, engine.run(reqs))
 
 
 def test_engine_rejects_non_attention_families():
@@ -280,25 +292,20 @@ def test_engine_quantized_weights_path(gemma_tiny):
 
 def test_engine_pallas_kernel_path(gemma_tiny):
     """The Pallas paged-attention kernel (interpret mode on CPU) serves the
-    same trace the block-walk path does: outputs token-identical to the
-    sequential baseline. Kept tiny — interpret mode runs the kernel body
-    per grid program in Python."""
+    same trace the block-walk path does, every token within GAP_TOL of
+    the dense forward's best. Kept tiny — interpret mode runs the kernel
+    body per grid program in Python."""
     model, params = gemma_tiny
     engine = Engine(model, params, _policy(max_batch=2),
                     paged_kernel="pallas")
     reqs = [_req(0, 8, 4), _req(1, 11, 3)]
-    outs = engine.run(reqs)
-    for r in reqs:
-        want = np.asarray(generate(model, params,
-                                   jnp.asarray(r.prompt[None]),
-                                   r.max_new)[0])
-        assert np.array_equal(want, outs[r.rid]), r.rid
+    assert_served(model, params, reqs, engine.run(reqs))
 
 
 def test_engine_preemption_roundtrip_exact(gemma_tiny):
     """A pool too small for both sequences' full lifetimes forces at least
     one preemption (free pages + requeue as prompt-extension + re-prefill);
-    greedy outputs stay token-identical to the sequential baseline."""
+    every served token stays within GAP_TOL of the dense forward's best."""
     model, params = gemma_tiny
     # pages_per_seq=4 (64/16); 6 usable pages; both requests grow to 4
     # pages (12 + 44 = 56 tokens), so one must be preempted mid-flight.
@@ -308,11 +315,7 @@ def test_engine_preemption_roundtrip_exact(gemma_tiny):
     assert engine.stats["preemptions"] >= 1
     assert engine.stats["grown_pages"] >= 3      # lazy growth really ran
     assert engine.scheduler.num_preempted == engine.stats["preemptions"]
-    for r in reqs:
-        want = np.asarray(generate(model, params,
-                                   jnp.asarray(r.prompt[None]),
-                                   44)[0])
-        assert np.array_equal(want, outs[r.rid]), r.rid
+    assert_served(model, params, reqs, outs)
     # all pages returned after drain
     assert engine.kv.allocator.num_allocated == 0
 
@@ -328,10 +331,7 @@ def test_engine_lazy_beats_upfront_admission(gemma_tiny):
                         reserve_upfront=upfront)
         outs = engine.run([_req(i, 12, 44) for i in range(2)])
         ticks[upfront] = engine.stats["decode_ticks"]
-        for r in reqs:
-            want = np.asarray(generate(model, params,
-                                       jnp.asarray(r.prompt[None]), 44)[0])
-            assert np.array_equal(want, outs[r.rid]), (upfront, r.rid)
+        assert_served(model, params, reqs, outs)
     # upfront: 4+4 pages never fit 6 -> strictly serial -> ~2x the ticks
     assert ticks[False] < ticks[True]
 
@@ -381,40 +381,24 @@ def test_paged_decode_never_builds_dense_kv(gemma_tiny):
     assert hits, "aval scan lost its teeth"
 
 
-def test_jit_lru_caches_are_bounded(gemma_tiny):
-    """Per-shape jit caches (pool writer, prefill buckets) evict LRU past
-    their cap instead of growing with every new bucket shape."""
-    from repro.serving.engine.pool import JitLRU
-    lru = JitLRU(cap=2)
-    calls = []
-    for key in ["a", "b", "a", "c", "b"]:
-        lru.get(key, lambda k=key: calls.append(k) or k)
-    # "a" was fresh when "c" evicted "b"; "b" recompiles
-    assert calls == ["a", "b", "c", "b"]
-    assert len(lru) == 2 and lru.hits == 1 and lru.misses == 4
-
+def test_one_chunk_executable_for_every_prompt_length(gemma_tiny):
+    """Every prompt length rides the one fixed-shape chunk program: after
+    prompts of five lengths the chunk and decode closures each hold one
+    compiled executable."""
     model, params = gemma_tiny
-    engine = Engine(model, params, _policy(prefill_chunk=4),
-                    chunked_prefill=False)
-    # 5 distinct prompt lengths -> 5 distinct padding buckets
-    engine.run([_req(i, 4 * (i + 1), 2) for i in range(5)])
-    assert len(engine._prefill_jits) <= Engine.PREFILL_JIT_CAP
-    assert len(engine.kv._write_jit) <= engine.kv.WRITE_JIT_CAP
-    assert engine._prefill_jits.misses == 5
-
-    # the chunked engine needs no padding-bucket jits at all: every
-    # prompt length rides the single fixed-shape chunk closure
     engine = Engine(model, params, _policy(prefill_chunk=4))
-    engine.run([_req(i, 4 * (i + 1), 2) for i in range(5)])
-    assert len(engine._prefill_jits) == 0
-    assert len(engine.kv._write_jit) == 0
+    engine.run([_req(i, 4 * (i + 1) - i % 2, 2) for i in range(5)])
+    assert engine.stats["prefill_chunks"] == sum(i + 1 for i in range(5))
+    m = engine.telemetry.metrics
+    assert m.gauge("jit.chunk.cache_size").value == 1.0
+    assert m.gauge("jit.decode.cache_size").value == 1.0
 
 
 @pytest.mark.slow
 def test_engine_smoke_long_trace(gemma_tiny):
     """CI smoke: a 12-request trace with long tails on a constrained pool —
     exercises admission, growth, preemption, backfill, and eviction in one
-    run and checks every output against the sequential baseline."""
+    run and checks every output against the dense forward."""
     model, params = gemma_tiny
     engine = Engine(model, params, _policy(max_batch=3, num_pages=9))
     rng = np.random.default_rng(7)
@@ -425,9 +409,5 @@ def test_engine_smoke_long_trace(gemma_tiny):
         reqs.append(Request(rid=i, prompt=rng.integers(
             2, model.cfg.vocab_size, S).astype(np.int32), max_new=gen))
     outs = engine.run(reqs)
-    for r in reqs:
-        want = np.asarray(generate(model, params,
-                                   jnp.asarray(r.prompt[None]),
-                                   r.max_new)[0])
-        assert np.array_equal(want, outs[r.rid]), r.rid
+    assert_served(model, params, reqs, outs)
     assert engine.kv.allocator.num_allocated == 0

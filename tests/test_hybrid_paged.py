@@ -58,13 +58,18 @@ def test_engine_serves_hybrid_chunked(hybrid_tiny):
     assert "k" in engine.kv.pool["sub1"]
 
 
-@pytest.mark.parametrize("kw", [dict(chunked_prefill=False),
+@pytest.mark.parametrize("kw", [dict(mesh="model=1"),
                                 dict(policy=dict(kv_bits=(8,)))],
-                         ids=["whole-prompt", "kv-bits"])
+                         ids=["mesh", "kv-bits"])
 def test_engine_refuses_hybrid_off_the_chunked_path(hybrid_tiny, kw):
+    """The state rows are served on one device beside a bf16 K/V pool:
+    a mesh (even of one device) or a quantized pool is refused."""
+    from repro.launch.mesh import make_serving_mesh
     model, params = hybrid_tiny
     kw = dict(kw)
     pol = _policy(**kw.pop("policy", {}))
+    if "mesh" in kw:
+        kw["mesh"] = make_serving_mesh(model=1, data=1)
     with pytest.raises(NotImplementedError):
         Engine(model, params, pol, **kw)
 

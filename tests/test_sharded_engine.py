@@ -106,15 +106,6 @@ def test_sharded_preemption_roundtrip_exact(gemma_tiny):
 
 
 @needs2
-def test_sharded_whole_prompt_prefill(gemma_tiny):
-    """chunked_prefill=False rides the sharded bucketed prefill + the
-    shard_map'd pool span-writer; outputs stay bit-identical."""
-    model, params = gemma_tiny
-    _assert_identical(model, params, _policy(), _reqs(model.cfg),
-                      make_serving_mesh(model=2), chunked_prefill=False)
-
-
-@needs2
 def test_sharded_moe_smoke():
     """MoE decode under a mesh (expert weights gathered at use)."""
     cfg = tiny_config("granite-moe-3b")

@@ -1,7 +1,7 @@
 """Engine telemetry (serving/telemetry): metrics instruments, the
 recorder's tick/span/stall record, the engine's phase span tree,
 roofline calibration, Chrome trace export, back-compat views (stall_log
-/ first_token_s), and the engine integration — including the JitLRU
+/ first_token_s), and the engine integration — including the
 no-retrace steady-state guarantee and per-shard mesh tags."""
 
 import contextlib
@@ -306,9 +306,9 @@ def test_engine_preemption_span_and_ttft(gemma_tiny):
 
 
 def test_engine_steady_state_decode_never_retraces(gemma_tiny):
-    """Satellite guarantee: after warmup, decode ticks reuse ONE compiled
-    executable — the jit cache-size gauge stays at 1 and the per-shape
-    LRUs see no new misses across a second identical run."""
+    """After warmup, decode ticks and prompt chunks reuse ONE compiled
+    executable each — the jit cache-size gauges stay at 1 across a second
+    identical run."""
     model, params = gemma_tiny
     engine = Engine(model, params, _policy(max_batch=2))
     reqs = [_req(0, 20, 6), _req(1, 8, 4)]
@@ -320,18 +320,13 @@ def test_engine_steady_state_decode_never_retraces(gemma_tiny):
         assert decode_cache == 1.0
     if chunk_cache >= 0:
         assert chunk_cache == 1.0
-    misses_before = engine._prefill_jits.misses
-    writer_misses_before = engine.kv._write_jit.misses
 
     engine.reset_stats()
     engine.run(reqs)                      # steady state: same shapes
     if decode_cache >= 0:
         assert m.gauge("jit.decode.cache_size").value == 1.0
-    assert engine._prefill_jits.misses == misses_before
-    assert engine.kv._write_jit.misses == writer_misses_before
-    # chunked mode: no padding-bucket jits at all, so misses stay 0 and
-    # the hit/miss gauges report the same
-    assert m.gauge("jit.prefill.misses").value == 0.0
+    if chunk_cache >= 0:
+        assert m.gauge("jit.chunk.cache_size").value == 1.0
 
 
 def test_engine_mesh_tags_on_ticks(gemma_tiny):
@@ -385,9 +380,6 @@ def test_engine_roofline_prediction_on_known_hw(gemma_tiny):
 SPAN_PARENTS = {
     "engine.step": {None},
     "engine.admit": {"engine.step"},
-    "engine.prefill": {"engine.admit"},
-    "engine.prefill.dispatch": {"engine.prefill"},
-    "engine.prefill.fence": {"engine.prefill"},
     "engine.chunk": {"engine.step"},
     "engine.chunk.prepare": {"engine.chunk"},
     "engine.chunk.dispatch": {"engine.chunk"},
@@ -399,7 +391,7 @@ SPAN_PARENTS = {
     "engine.decode.dispatch": {"engine.decode"},
     "engine.decode.fence": {"engine.decode"},
     "engine.decode.sample": {"engine.decode"},
-    "engine.finish": {"engine.step", "engine.admit"},
+    "engine.finish": {"engine.step"},
     "engine.gauges": {"engine.step"},
 }
 
@@ -431,15 +423,12 @@ def _spans_per_step(engine, tree):
     return steps
 
 
-@pytest.mark.parametrize("chunked", [True, False])
-def test_engine_span_tree(gemma_tiny, chunked):
+def test_engine_span_tree(gemma_tiny):
     """Every span the engine opens has a name of the tree and opens inside
-    its parent; chunked mode opens every chunk and decode phase, and the
-    whole-prompt mode its prefill phases inside admission."""
+    its parent, and a run opens every chunk and decode phase."""
     model, params = gemma_tiny
     tree = SpanTree()
     engine = Engine(model, params, _policy(max_batch=2),
-                    chunked_prefill=chunked,
                     telemetry=Telemetry(span_factory=tree))
     engine.run([_req(0, 20, 4), _req(1, 8, 1)])
     assert tree.opened and not tree._stack
@@ -452,16 +441,10 @@ def test_engine_span_tree(gemma_tiny, chunked):
     assert sum(n == "engine.finish" for n, _ in tree.opened) == 2
     decode = {n for n in SPAN_PARENTS if n.startswith("engine.decode")}
     assert decode <= names
-    if chunked:
-        chunk = {n for n in SPAN_PARENTS if n.startswith("engine.chunk")}
-        assert chunk <= names
-        assert not {n for n in names if n.startswith("engine.prefill")}
-        assert sum(n == "engine.chunk.dispatch" for n, _ in tree.opened) \
-            == engine.stats["prefill_chunks"]
-    else:
-        prefill = {n for n in SPAN_PARENTS if n.startswith("engine.prefill")}
-        assert prefill <= names
-        assert ("engine.finish", "engine.admit") in tree.opened
+    chunk = {n for n in SPAN_PARENTS if n.startswith("engine.chunk")}
+    assert chunk <= names
+    assert sum(n == "engine.chunk.dispatch" for n, _ in tree.opened) \
+        == engine.stats["prefill_chunks"]
     assert sum(n == "engine.decode.sample" for n, _ in tree.opened) \
         == engine.stats["decode_ticks"]
 
