@@ -32,10 +32,12 @@ def _req(rid, S, gen, *, vocab=512, seed=None):
 
 
 # ------------------------------------------------------ kernel parity ------
-def _prefill_case(B, H, K, hd, page, n_blocks, Sq, *, num_pages=11, seed=0):
+def _prefill_case(B, H, K, hd, page, n_blocks, Sq, *, num_pages=11, seed=0,
+                  positions=None):
     """Random pool + ragged chunk-start positions: each sequence's chunk
-    begins at a different resident-prefix length, pages shuffled, unused
-    page-table tails on the poisoned scratch page 0."""
+    begins at a different resident-prefix length (``positions``, drawn
+    when not given), pages shuffled, unused page-table tails on the
+    poisoned scratch page 0."""
     rng = np.random.default_rng(seed)
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     pool_k = jax.random.normal(ks[0], (num_pages, K, page, hd), jnp.float32)
@@ -43,8 +45,10 @@ def _prefill_case(B, H, K, hd, page, n_blocks, Sq, *, num_pages=11, seed=0):
     pool_k = pool_k.at[0].set(37.0)          # a masking bug reads these
     pool_v = pool_v.at[0].set(-53.0)
     q = jax.random.normal(ks[2], (B, Sq, H, hd), jnp.float32)
-    positions = rng.integers(0, n_blocks * page - Sq, B).astype(np.int32)
-    positions[0] = 0                          # empty-prefix edge case
+    if positions is None:
+        positions = rng.integers(0, n_blocks * page - Sq, B).astype(np.int32)
+        positions[0] = 0                      # empty-prefix edge case
+    positions = np.asarray(positions, np.int32)
     pt = np.zeros((B, n_blocks), np.int32)
     for b in range(B):
         need = (positions[b] + Sq - 1) // page + 1
@@ -72,6 +76,46 @@ def test_prefill_kernel_parity(page, n_blocks, Sq, window, cap, H, K):
                                   cap=cap)
     assert float(jnp.max(jnp.abs(got_k - want))) < 1e-5
     assert float(jnp.max(jnp.abs(got_r - want))) < 1e-5
+
+
+# One case per edge of the bf16 chunk walk (128 tokens, 128 // page pages,
+# per grid step): 68 pages of 16 fill 8 steps and a ninth of 4; every case
+# has a chunk at position 0 and one whose context ends on the table's last
+# slot (1087 of 1088 tokens); the others end mid-step, and under a window
+# their first live page sits mid-step. Chunks of more than 4096 rows
+# (queries x G) span two query tiles; tiles of 2048 and 3072 rows take
+# their rows 1024 at a time.
+WALK_CASES = {
+    # name: (H, K, page, n_blocks, Sq, positions, window, cap)
+    "tail_step_G4": (8, 2, 16, 68, 512, [0, 576, 300], 0, 0.0),
+    "tail_step_G6_softcap": (12, 2, 16, 68, 40, [0, 1048, 300], 0, 30.0),
+    "window_mid_step_G4": (8, 2, 16, 68, 24, [0, 1064, 700], 200, 0.0),
+    "window_mid_step_G6": (12, 2, 16, 68, 56, [0, 1032, 517], 300, 0.0),
+    "page32_G4": (8, 2, 32, 34, 40, [0, 1048, 500], 0, 0.0),
+    "page32_window_softcap": (8, 2, 32, 34, 40, [0, 1048, 590], 100, 30.0),
+    "page64_G6": (12, 2, 64, 17, 40, [0, 1048, 620], 0, 0.0),
+    "page64_window": (12, 2, 64, 17, 40, [0, 1048, 620], 150, 0.0),
+    "two_query_tiles_G4_window": (4, 1, 16, 68, 1056, [0, 32], 300, 0.0),
+    "two_query_tiles_G6_softcap": (6, 1, 16, 68, 1024, [0, 64], 0, 30.0),
+}
+
+
+@pytest.mark.parametrize("case", list(WALK_CASES))
+def test_prefill_walk_parity(case):
+    """The bf16-pool chunk walk (interpret) matches the dense oracle where
+    a step is cut: a table that ends mid-step, contexts that end mid-step
+    or on the last slot, windows whose first live page is mid-step, pages
+    of 32 and 64, softcaps, chunks at position 0 and chunks over two
+    query tiles."""
+    H, K, page, n_blocks, Sq, positions, window, cap = WALK_CASES[case]
+    q, pk, pv, pt, pos = _prefill_case(len(positions), H, K, 32, page,
+                                       n_blocks, Sq, num_pages=n_blocks + 9,
+                                       positions=positions)
+    want = ref.paged_prefill_dense_ref(q, pk, pv, pt, pos,
+                                       window=window, cap=cap)
+    got = pa.paged_prefill_fwd(q, pk, pv, pt, pos, window=window, cap=cap,
+                               interpret=True)
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-5
 
 
 @pytest.mark.parametrize("bits", [8, 4])

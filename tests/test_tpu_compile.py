@@ -178,6 +178,24 @@ def test_decode_kernel_compiles_at_cell_shapes(one_chip, B, H, num_pages):
     assert "tpu_custom_call" in c.as_text()
 
 
+@pytest.mark.parametrize("Sq", [512, 1024])
+@pytest.mark.parametrize("H,num_pages", [(48, 2965), (32, 2655)],
+                         ids=["nemotron-4-15b", "granite-3-8b"])
+def test_prefill_kernel_compiles_at_cell_shapes(one_chip, H, num_pages, Sq):
+    """The bf16 chunk kernel at the benchmark cells' attention shapes (8
+    kv heads of 128, pages of 16, 256 page-table slots) at the cells'
+    chunk and at twice it, inside the default scoped VMEM: the query tile,
+    not the chunk, sets what the kernel holds."""
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    kv = sd((num_pages, 8, 16, 128), jnp.bfloat16)
+    text = _compile(pa.paged_prefill_fwd, sd((1, Sq, H, 128), jnp.bfloat16),
+                    kv, kv, sd((1, 256), jnp.int32),
+                    sd((1,), jnp.int32)).as_text()
+    used = [int(n) for n in re.findall(
+        r'"used_scoped_memory_configs":\[\{[^]]*"size":"(\d+)"', text)]
+    assert used and max(used) <= 16 << 20, used
+
+
 @pytest.mark.parametrize("name", ["paged_attention_fwd",
                                   "paged_attention_quant_fwd",
                                   "paged_prefill_fwd",
